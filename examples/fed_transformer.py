@@ -18,17 +18,12 @@ composition converging, not the corpus.
 
 from __future__ import annotations
 
-import os
+import argparse
 import sys
 from functools import partial
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-
-if os.environ.get("PYGRID_TPU_FORCE_CPU"):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 
@@ -44,20 +39,26 @@ ROUNDS = 30
 
 
 def main() -> int:
-    on_cpu = jax.devices()[0].platform == "cpu"
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument(
+        "--interpret", action="store_true",
+        help="run the Pallas kernel in interpret mode and the matmuls in "
+        "f32 — the way to run this example without a TPU "
+        "(JAX_PLATFORMS=cpu); the default Mosaic-compiles the kernel "
+        "and needs the chip",
+    )
+    interpret = parser.parse_args().interpret
     cfg = transformer.TransformerConfig(
         vocab=32, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_len=L
     )
     loss_fn = partial(
         transformer.loss_and_acc,
         cfg=cfg,
-        # the flash kernel Mosaic-compiles on TPU; interpret mode runs the
-        # same kernel on CPU
-        attn_fn=partial(flash_attention, interpret=on_cpu),
+        attn_fn=partial(flash_attention, interpret=interpret),
         # mixed precision (and the bf16 CE backward) earn their keep on
-        # the MXU; on CPU they just slow the interpreter down
-        compute_dtype=None if on_cpu else "bfloat16",
-        ce_grad_dtype=None if on_cpu else "bfloat16",
+        # the MXU; interpreted on a CPU they just slow the run down
+        compute_dtype=None if interpret else "bfloat16",
+        ce_grad_dtype=None if interpret else "bfloat16",
     )
 
     # task: one base corpus, each client holding ITS OWN token shift of
@@ -74,7 +75,8 @@ def main() -> int:
     first, last = float(losses[0]), float(losses[-1])
     print(
         f"federated transformer: {K} clients × {ROUNDS} rounds "
-        f"(flash attention, {'cpu interpret' if on_cpu else 'bf16 on TPU'}) — "
+        f"(flash attention, {'interpreted, f32' if interpret else 'compiled, bf16'}"
+        f" on {jax.devices()[0].platform}) — "
         f"loss {first:.3f} → {last:.3f}, acc {float(accs[-1]):.2f}"
     )
     if not last < first - 0.3:

@@ -8,6 +8,7 @@ notebooks back-to-back against the compose grid."""
 
 from __future__ import annotations
 
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -31,10 +32,16 @@ def main() -> int:
     wait_for(node_url, args.wait)
 
     base = [sys.executable, "-u"]
+    # the children trace plans and train MNIST steps with JAX. They are
+    # FL clients, not the node: a chip belongs to one process at a time
+    # and the node (this process under --spawn, or a neighbour on this
+    # host) is the one that holds it — so the children are pinned to
+    # the CPU instead of racing it for the device.
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     host = subprocess.run(
         [*base, str(HERE / "model_centric" / "01_create_plan.py"),
          "--node", node_url],
-        timeout=600,
+        timeout=600, env=env,
     )
     if host.returncode:
         return host.returncode
@@ -42,7 +49,7 @@ def main() -> int:
         [*base, str(HERE / "model_centric" / "02_execute_plan.py"),
          "--node", node_url, "--workers", str(args.workers),
          "--cycles", str(args.cycles)],
-        timeout=600,
+        timeout=600, env=env,
     )
     return execute.returncode
 

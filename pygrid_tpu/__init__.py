@@ -11,10 +11,12 @@ Top-level layout (see SURVEY.md for the reference layer map this covers):
 - ``serde``      wire serialization (msgpack-based, typed registry)
 - ``plans``      Plan/State/PlaceHolder — traced, exported, portable programs
 - ``runtime``    virtual party runtime (object store, pointers, message router)
-- ``smpc``       fixed-precision ring-2^64 additive secret sharing, Beaver matmul
-- ``parallel``   mesh construction, FedAvg collectives, shard_map helpers
-- ``models``     model families (MLP, CNN, transformer)
-- ``ops``        Pallas TPU kernels (ring matmul, ring attention)
+- ``smpc``       fixed-precision ring-2^64 additive secret sharing, Beaver matmul,
+                 the Pallas uint64 ring-matmul kernel
+- ``parallel``   mesh construction, FedAvg collectives, shard_map helpers,
+                 the Pallas flash-attention kernel
+- ``models``     model families (MLP, CNN, transformer) + KV-cache decode
+- ``serving``    continuous-batching generation engine (paged KV, fused decode)
 - ``storage``    sqlite-backed Warehouse + object persistence
 - ``federated``  model-centric FL coordination (cycles, controllers, managers)
 - ``node``       the Node app (aiohttp HTTP + WS server)

@@ -21,8 +21,9 @@ class _DaemonPool:
     """Elastic daemon worker threads (instead of a fresh thread per
     trigger — the report path triggers a readiness check per diff, and
     thread spawn costs more than the check itself). Daemon matters: a
-    task wedged on a dead device tunnel must not block interpreter exit
-    the way concurrent.futures' atexit join would. Elastic matters: when
+    task wedged on a device call that never returns must not block
+    interpreter exit the way concurrent.futures' atexit join would.
+    Elastic matters: when
     every worker is busy (or wedged), a new submission grows the pool up
     to MAX_WORKERS so slow tasks cannot starve every other FL process's
     readiness checks."""

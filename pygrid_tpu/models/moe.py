@@ -23,10 +23,8 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from pygrid_tpu.parallel.compat import shard_map
 
 
 def init(

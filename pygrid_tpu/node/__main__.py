@@ -64,9 +64,19 @@ def main(argv=None) -> None:
     from aiohttp import web
 
     from pygrid_tpu.node import create_app
+    from pygrid_tpu.utils import jaxenv
 
     args = parse_args(argv)
     logging.basicConfig(level=logging.INFO)
+    # the node is the ONE process of its host that holds the chip: bring
+    # the backend up here, before any socket opens, so a chip that
+    # cannot be reached stops the node instead of leaving it serving
+    # from the host CPU — and say what it runs on
+    cache_dir = jaxenv.configure_compile_cache()
+    device = jaxenv.device_info()
+    logger.info(
+        "node %s runs on %s", args.id, jaxenv.describe(device, cache_dir)
+    )
     database_url = (
         f"node_{args.id}.db" if args.start_local_db
         else os.environ.get("DATABASE_URL", ":memory:")

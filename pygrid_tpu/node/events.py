@@ -766,8 +766,9 @@ def run_generation(ctx: NodeContext, message: dict, conn: Connection) -> dict:
     the result future while the engine's dedicated thread drives the
     device — concurrent requests share one persistent batched program
     instead of serializing whole-generation XLA calls, and a full queue
-    answers a typed busy error instead of piling up. Greedy results are
-    bit-identical to the direct ``decode.generate`` path;
+    answers a typed busy error instead of piling up. Greedy results
+    equal the direct ``decode.generate`` path (bit for bit on the CPU
+    at f32; up to rounding ties on the TPU — docs/SERVING.md);
     ``PYGRID_SERVING=off`` restores the legacy per-request programs."""
     _authenticated(conn)
     import numpy as np

@@ -22,10 +22,8 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from pygrid_tpu.parallel.compat import lax_pcast, shard_map
 
 
 def _client_update(
@@ -106,8 +104,8 @@ def make_sharded_round(
         # aggregate every client's gradient into each local step. pcast
         # keeps local training local; only the explicit pmean below crosses
         # devices.
-        params_v = [lax_pcast(p, axis, to="varying") for p in params]
-        lr_v = lax_pcast(lr, axis, to="varying")
+        params_v = [lax.pcast(p, axis, to="varying") for p in params]
+        lr_v = lax.pcast(lr, axis, to="varying")
 
         def one_client(X, y):
             new_p, loss, acc = _client_update(

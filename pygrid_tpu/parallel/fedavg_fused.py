@@ -4,10 +4,10 @@
 the client update as an opaque ``training_step`` — under ``vmap`` every
 weight-gradient dot becomes a K-batched matmul with only ``batch_size``
 rows per client, and the K per-client results must materialize in HBM
-before the mean. On a v5e that program runs at ~35% MFU while the same
-FLOPs folded run at ~89% (BASELINE.md): the MXU sees 64-row matmuls and
-the bandwidth sees K·|params| of traffic that the *algorithm* does not
-require.
+before the mean. On a v5e that program ran at 34.3% MFU while the same
+FLOPs through this builder ran at 89.9% (BENCH_r05.json): the MXU sees
+64-row matmuls and the bandwidth sees K·|params| of traffic that the
+*algorithm* does not require.
 
 This module rebuilds the round from the model's **loss function** instead
 of its opaque update step, which exposes the one reassociation the opaque
@@ -46,10 +46,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from pygrid_tpu.parallel.compat import lax_pcast, shard_map
 
 
 def _sgd_steps(
@@ -227,8 +225,8 @@ def make_sharded_fused_round(
     def shard_fn(params, client_X, client_y, lr):
         # pcast keeps local training local under shard_map's
         # replication-aware autodiff (see make_sharded_round's note)
-        params_v = [lax_pcast(p, axis, to="varying") for p in params]
-        lr_v = lax_pcast(lr, axis, to="varying")
+        params_v = [lax.pcast(p, axis, to="varying") for p in params]
+        lr_v = lax.pcast(lr, axis, to="varying")
 
         if local_steps > 1:
 

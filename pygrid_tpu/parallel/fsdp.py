@@ -40,10 +40,8 @@ from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from pygrid_tpu.parallel.compat import lax_pcast, shard_map
 
 
 def _flat_padded(leaf: jax.Array, n: int) -> jax.Array:
@@ -157,8 +155,8 @@ def make_fsdp_training_step(
         # shards/moments arrive as [1, shard_len] blocks; lr/count are
         # replicated — pcast marks them device-varying so the local
         # update math stays local (see make_sharded_round's note)
-        lr_v = lax_pcast(lr, axis, to="varying")
-        count_v = lax_pcast(count + 1, axis, to="varying")
+        lr_v = lax.pcast(lr, axis, to="varying")
+        count_v = lax.pcast(count + 1, axis, to="varying")
 
         full = [
             lax.all_gather(s[0], axis, tiled=True)[:size].reshape(shape)

@@ -41,11 +41,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pygrid_tpu.parallel.compat import tpu_compiler_params, typeof_vma
-
-_CompilerParams = tpu_compiler_params()
-
-
 #: defaults from an on-chip sweep (v5e, L=4096 D=128 causal): 128×128
 #: blocks ran at 15 TF/s — the per-step dots were too small to feed the
 #: MXU; 512×1024 ran 6.9× faster and beats the XLA path ~3× (wall-clock,
@@ -132,8 +127,7 @@ def _flash_kernel(
 def _struct(shape, dtype, vma):
     """out_shape struct carrying the inputs' varying mesh axes: under
     shard_map the outputs inherit the inputs' vma, and check_vma rejects
-    a pallas_call whose out_shape doesn't declare it. The getattr guard
-    on the caller side exists because the vma API is still in flux."""
+    a pallas_call whose out_shape doesn't declare it."""
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -188,7 +182,7 @@ def _fwd_impl(
         memory_space=pltpu.VMEM,
     )
 
-    vma = typeof_vma(qf)
+    vma = jax.typeof(qf).vma
     struct = partial(_struct, vma=vma)
 
     out, lse = pl.pallas_call(
@@ -209,7 +203,7 @@ def _fwd_impl(
             pltpu.VMEM((block_q, MIN_D), jnp.float32),
             pltpu.VMEM((block_q, MIN_D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -459,7 +453,7 @@ def _flash_bwd(
         jnp.broadcast_to(delta[:, None, :], (B * H, 8, Lq)), Lqp, 2
     )
 
-    vma = typeof_vma(qf)
+    vma = jax.typeof(qf).vma
     struct = partial(_struct, vma=vma)
 
     def kv_specs(index):
@@ -506,7 +500,7 @@ def _flash_bwd(
             pltpu.VMEM((bk, Dp), jnp.float32),
             pltpu.VMEM((bk, Dp), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -540,7 +534,7 @@ def _flash_bwd(
         ),
         out_shape=struct((B * H, Lqp, Dp), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, Dp), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -26,10 +26,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from pygrid_tpu.parallel.compat import lax_pcast, shard_map
 
 
 def stage_specs(stacked_params, axis: str = "stage"):
@@ -67,8 +65,8 @@ def pipeline_apply(
         is_first, is_last = s == 0, s == p_sz - 1
         # fresh carries are replication-typed; mark them device-varying so
         # the scan carry matches the ppermute-varying activations
-        act0 = lax_pcast(jnp.zeros_like(x_micro[0]), axis, to="varying")
-        outs0 = lax_pcast(jnp.zeros_like(x_micro), axis, to="varying")
+        act0 = lax.pcast(jnp.zeros_like(x_micro[0]), axis, to="varying")
+        outs0 = lax.pcast(jnp.zeros_like(x_micro), axis, to="varying")
 
         def tick(carry, t):
             act, outs = carry

@@ -25,10 +25,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from pygrid_tpu.parallel.compat import lax_pcast, shard_map
 
 _NEG = -1e30  # finite "-inf": keeps fully-masked blocks NaN-free in exp()
 
@@ -151,11 +149,11 @@ def ring_attention(
         # fresh accumulators are replication-typed; mark them device-varying
         # so the fori_loop carry matches the ppermute-varying K/V blocks
         # running stats in f32 regardless of q.dtype (see _block_accumulate)
-        o = lax_pcast(
+        o = lax.pcast(
             jnp.zeros((B, H, Lq, D), jnp.float32), axis, to="varying"
         )
-        l = lax_pcast(jnp.zeros((B, H, Lq), jnp.float32), axis, to="varying")
-        m = lax_pcast(
+        l = lax.pcast(jnp.zeros((B, H, Lq), jnp.float32), axis, to="varying")
+        m = lax.pcast(
             jnp.full((B, H, Lq), _NEG, jnp.float32), axis, to="varying"
         )
         # p_sz-1 rotate steps in the loop; the last block needs no ppermute

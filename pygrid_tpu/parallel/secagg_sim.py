@@ -25,12 +25,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.8 top-level name; the experimental path is deprecated
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
 
 
 def _pair_key(key: jax.Array, i: jax.Array, j: jax.Array) -> jax.Array:

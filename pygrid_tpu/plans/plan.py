@@ -42,14 +42,9 @@ def _export_platforms() -> tuple[str, ...]:
 
 
 def _export(fn: Callable, example_args: Sequence[Any]) -> jax_export.Exported:
-    import inspect
-
-    jitted = jax.jit(fn)
-    # probe the signature for the kwarg name (renamed across jax versions);
-    # a try/except TypeError here would mask TypeErrors from tracing fn itself
-    params = inspect.signature(jax_export.export).parameters
-    kw = "platforms" if "platforms" in params else "lowering_platforms"
-    return jax_export.export(jitted, **{kw: _export_platforms()})(*example_args)
+    return jax_export.export(jax.jit(fn), platforms=_export_platforms())(
+        *example_args
+    )
 
 
 @register_serde(name="pygrid.Plan")

@@ -55,9 +55,14 @@ to the PR-3 contiguous slot cache — the operational escape hatch and
 the bench baseline for capacity-per-GB comparisons.
 
 Greedy results are bit-identical to single-request
-:func:`pygrid_tpu.models.decode.generate` (tested); sampling is
-reproducible per (seed, row) and distribution-identical to the
-single-request path. The worker thread is the ONLY thread that touches
+:func:`pygrid_tpu.models.decode.generate` on the CPU at f32 (tested).
+On the TPU they are the same tokens except at rounding ties: the same
+arithmetic runs in programs of different batch width, and a top-1/top-2
+logit margin below ~0.02 can change hands (measured on a v5e: 134 of
+160 requests identical, every divergence a tie of ≤ 0.018 in float32
+reference logits — PERF.md, PR 21). Sampling is reproducible per
+(seed, row) and distribution-identical to the single-request path. The
+worker thread is the ONLY thread that touches
 the device loop — WS/HTTP handler threads just enqueue and wait on a
 future, so heavy generation cannot starve FL report handlers on the
 shared executor.
@@ -347,6 +352,24 @@ class GenerationEngine:
         #: (chaos_hold_blocks) — never visible to admission, always
         #: accounted for by ledger() so a forgotten hold reads as a leak
         self._chaos_blocks: list[int] = []
+        from pygrid_tpu.utils import jaxenv
+
+        device = jaxenv.device_info()
+        #: what the engine's programs run on and in — stamped on every
+        #: stats() row so a reader asserts the device and the dtypes
+        #: instead of assuming them
+        self._runs_on = {
+            "platform": device["platform"],
+            "device_kind": device["device_kind"],
+            "device_count": device["count"],
+            "kv_dtype": np.dtype(self._kv_dtype).name,
+            # no cast configured = matmuls run in the params' dtype
+            "compute_dtype": np.dtype(
+                self.config.compute_dtype
+                if self.config.compute_dtype is not None
+                else params[0].dtype
+            ).name,
+        }
 
     # ── client surface (any thread) ─────────────────────────────────────
 
@@ -504,6 +527,7 @@ class GenerationEngine:
                 "paged": self._paged,
                 "fused": self._fused,
                 "spec": self._spec,
+                **self._runs_on,
             }
             if self._fused:
                 out.update(

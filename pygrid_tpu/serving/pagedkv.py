@@ -125,11 +125,7 @@ def default_cache_dtype() -> Any:
     import jax
     import jax.numpy as jnp
 
-    try:
-        backend = jax.default_backend()
-    except Exception:  # noqa: BLE001 — no backend is a valid state
-        backend = ""
-    return jnp.bfloat16 if backend == "tpu" else jnp.float32
+    return jnp.bfloat16 if jax.default_backend() == "tpu" else jnp.float32
 
 
 def parse_budget_bytes(raw: str | None) -> int | None:

@@ -85,21 +85,19 @@ class ProgramSet:
         """Actual jit cache entries across every program — catches
         silent retraces (shape/dtype drift in engine call sites) that
         the builder-level counter cannot see. Equals
-        :meth:`compile_count` when the no-recompile contract holds;
-        falls back to the builder count where jax lacks the hook."""
-        total = 0
-        for fn in [
-            *self._prefill.values(),
-            *self._decode.values(),
-            *self._paged_prefill.values(),
-            *self._paged_decode.values(),
-            *self._paged_fused.values(),
-            *self._spec_prefill.values(),
-            *self._spec_verify.values(),
-        ]:
-            size = getattr(fn, "_cache_size", None)
-            total += size() if callable(size) else 1
-        return total
+        :meth:`compile_count` when the no-recompile contract holds."""
+        return sum(
+            fn._cache_size()
+            for fn in [
+                *self._prefill.values(),
+                *self._decode.values(),
+                *self._paged_prefill.values(),
+                *self._paged_decode.values(),
+                *self._paged_fused.values(),
+                *self._spec_prefill.values(),
+                *self._spec_verify.values(),
+            ]
+        )
 
     def _count(self, kind: str) -> None:
         self._compiles += 1

@@ -5,7 +5,7 @@ numpy-facing surface. These functions are its pure-XLA core: everything is a
 function of stacked ring arrays, jit-compiled once and ``vmap``-ed over a
 batch axis so one chip runs B independent SMPC instances (B×P virtual
 parties) per launch — the TPU-native answer to the reference's
-one-process-per-party grid (SURVEY.md §2.5, BASELINE.md north star).
+one-process-per-party grid (SURVEY.md §2.5, BASELINE.json north star).
 
 Layouts: shares are ``Ring64`` with leading axes ``[B?, P, ...]`` where P is
 the party axis. "Opening" a masked value is a sum over P — the mesh-sharded

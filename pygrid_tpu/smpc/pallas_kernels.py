@@ -34,11 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from pygrid_tpu.parallel.compat import tpu_compiler_params
-
-_CompilerParams = tpu_compiler_params()
-
-
 from pygrid_tpu.smpc.ring import Ring64
 
 TILE_M = 128
@@ -169,7 +164,7 @@ def pallas_ring_matmul(a: Ring64, b: Ring64, interpret: bool = False) -> Ring64:
         in_specs=[a_spec, a_spec, b_spec, b_spec],
         out_specs=[o_spec, o_spec],
         out_shape=[out_shape, out_shape],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

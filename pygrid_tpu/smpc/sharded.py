@@ -28,12 +28,11 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from pygrid_tpu.smpc import ring as R
 from pygrid_tpu.smpc.kernels import share_kernel
-
-from pygrid_tpu.parallel.compat import shard_map
 
 
 def party_sharding(mesh: Mesh, axis: str = "parties") -> NamedSharding:

@@ -91,6 +91,12 @@ def main(argv=None) -> int:
             print(f"{name:10s} {doc}")
         return 0
 
+    # the storm hosts its grid in THIS process, so its engines' compiled
+    # programs share the cache the node and trainer entry points use
+    from pygrid_tpu.utils import jaxenv
+
+    jaxenv.configure_compile_cache()
+
     if args.replay:
         from pygrid_tpu.storm.replay import replay
 

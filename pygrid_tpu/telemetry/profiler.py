@@ -104,7 +104,7 @@ class JitSiteProfiler:
                     "traces": 0,
                 },
             )
-        cache_size = getattr(fn, "_cache_size", None)
+        cache_size = fn._cache_size
         # per-WRAPPER trace watermark (not the shared entry's): a
         # re-hosted model rebuilds its programs under the same key, and
         # the fresh jit cache must still classify its first calls as
@@ -120,14 +120,10 @@ class JitSiteProfiler:
             t0 = time.perf_counter()
             out = fn(*args, **kwargs)
             dt = time.perf_counter() - t0
-            traces = cache_size() if callable(cache_size) else None
+            traces = cache_size()
             with self._lock:
-                if traces is not None:
-                    compiled = traces > seen["traces"]
-                    seen["traces"] = max(seen["traces"], traces)
-                else:
-                    # no cache hook: attribute the first call to compile
-                    compiled = seen["calls"] == 0
+                compiled = traces > seen["traces"]
+                seen["traces"] = max(seen["traces"], traces)
                 seen["calls"] += 1
                 if compiled:
                     entry["compiles"] += 1
@@ -142,8 +138,7 @@ class JitSiteProfiler:
                 bus.observe("profiler_execute_seconds", dt, kind=kind)
             return out
 
-        if callable(cache_size):
-            wrapped._cache_size = cache_size  # keep trace_count() honest
+        wrapped._cache_size = cache_size  # keep trace_count() honest
         wrapped.__wrapped__ = fn
         return wrapped
 
@@ -302,26 +297,21 @@ class DeviceMemorySampler:
     @staticmethod
     def sample_once() -> list[dict]:
         """One synchronous read of every local device's memory stats.
-        Devices without the hook (CPU) contribute nothing; a failing
-        backend yields an empty sample rather than an exception."""
-        try:
-            import jax
+        Devices that report none (CPU answers ``None``) contribute
+        nothing. A backend that cannot initialise raises: the node's
+        entry point initialises it before the sampler starts, so a
+        failure here is a defect, not a state to sample around."""
+        import jax
 
-            devices = jax.local_devices()
-        except Exception:  # noqa: BLE001 — no backend is a valid state
-            return []
         out = []
-        for d in devices:
-            try:
-                stats = d.memory_stats()
-            except Exception:  # noqa: BLE001 — per-device hook optional
-                stats = None
+        for d in jax.local_devices():
+            stats = d.memory_stats()
             if not stats:
                 continue
             out.append(
                 {
-                    "device": str(getattr(d, "id", len(out))),
-                    "platform": getattr(d, "platform", "unknown"),
+                    "device": str(d.id),
+                    "platform": d.platform,
                     "bytes_in_use": stats.get("bytes_in_use"),
                     "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
                     "bytes_limit": stats.get("bytes_limit"),

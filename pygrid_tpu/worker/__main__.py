@@ -96,8 +96,18 @@ def main(argv=None) -> int:
             parser.error("--compress fraction must be in (0, 1]")
         compression = {"name": "topk", "fraction": fraction}
 
+    from pygrid_tpu.utils import jaxenv
     from pygrid_tpu.worker import run_worker
 
+    # a worker trains with JAX. A chip belongs to one process at a time:
+    # started next to a node on one host it must be pinned to the CPU
+    # (JAX_PLATFORMS=cpu) — the start-up line says what it got
+    cache_dir = jaxenv.configure_compile_cache()
+    device = jaxenv.device_info()
+    print(
+        f"worker runs on {jaxenv.describe(device, cache_dir)}",
+        file=sys.stderr,
+    )
     result = run_worker(
         args.node,
         args.model_name,

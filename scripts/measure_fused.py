@@ -12,7 +12,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import jax
 import jax.numpy as jnp
 
-from bench import BATCH, K, LR, PEAK_TFLOPS, SIZES, _flops_per_round
+from bench import BATCH, K, LR, SIZES, _flops_per_round, peak_tflops
 from pygrid_tpu.models import mlp
 from pygrid_tpu.parallel import make_fused_rounds, make_scanned_rounds
 
@@ -69,7 +69,7 @@ def main():
             out = f(params, X, y, lr)
             _ = float(out[1][-1])
         dt = measure(fns, params, X, y, lr, n_s, n_l)
-        mfu = flops_per_round(steps) / dt / (PEAK_TFLOPS * 1e12)
+        mfu = flops_per_round(steps) / dt / (peak_tflops() * 1e12)
         print(
             f"{name}: {dt*1e3:.3f} ms/round  MFU {mfu*100:.1f}%",
             file=sys.stderr,

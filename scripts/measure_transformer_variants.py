@@ -13,7 +13,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import jax
 import jax.numpy as jnp
 
-from bench import PEAK_TFLOPS
+from bench import peak_tflops
 from pygrid_tpu.models import transformer
 from pygrid_tpu.parallel import make_fused_rounds, make_scanned_rounds
 from pygrid_tpu.parallel.pallas_attention import flash_attention
@@ -49,7 +49,7 @@ def measure(mk, params, X, y, lr, small, large, trials=5):
 
 
 def report(name, per, fl, tokens):
-    mfu = fl / per / (PEAK_TFLOPS * 1e12)
+    mfu = fl / per / (peak_tflops() * 1e12)
     print(
         f"{name}: {per*1e3:.2f} ms/round, {tokens/per:,.0f} tok/s, "
         f"MFU {mfu*100:.1f}%",

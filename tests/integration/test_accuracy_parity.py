@@ -1,6 +1,6 @@
 """Accuracy parity: the fused on-device kernel and the real socket protocol
 train to the same final accuracy — the "iso final accuracy" leg of the
-north-star claim (BASELINE.md; reference workload
+north-star claim (BASELINE.json; reference workload
 ``/root/reference/examples/model-centric/01-Create-plan.ipynb`` cell 10).
 
 Same data partition, same rounds, same lr through (a) ``make_scanned_rounds``

@@ -17,7 +17,7 @@ EXAMPLES = Path(__file__).resolve().parents[2] / "examples"
 
 def _run(script: str, *args: str) -> subprocess.CompletedProcess:
     env = dict(os.environ)
-    env["PYGRID_TPU_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(EXAMPLES.parent)
     return subprocess.run(
         [sys.executable, str(EXAMPLES / script), *args],
@@ -85,6 +85,6 @@ def test_async_fl_example():
 
 
 def test_fed_transformer_example():
-    result = _run("fed_transformer.py")
+    result = _run("fed_transformer.py", "--interpret")
     assert result.returncode == 0, result.stderr[-2000:]
     assert "federated transformer" in result.stdout

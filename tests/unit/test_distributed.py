@@ -63,9 +63,7 @@ def test_data_sharding_psum_over_dcn_axis():
     def agg(x):
         return jax.lax.psum(x, "hosts")
 
-    from pygrid_tpu.parallel.compat import shard_map
-
-    out = shard_map(
+    out = jax.shard_map(
         agg, mesh=mesh, in_specs=P("hosts", "clients"),
         out_specs=P(None, "clients"),
     )(x)

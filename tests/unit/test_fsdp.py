@@ -97,9 +97,9 @@ def test_fsdp_matches_unsharded(optimizer):
     np.testing.assert_allclose(fsdp_losses, ref_losses, rtol=2e-5)
     got = unshard_params(state["shards"], params)
     for g, r in zip(got, ref_params):
-        # pre-varying-type jax (no lax.pcast — compat shim path) compiles
-        # the sharded program with different reduction associativity;
-        # adam's rsqrt amplifies the reassociation noise to ~5e-5 relative
+        # the sharded program reduces in a different order than the
+        # reference; adam's rsqrt amplifies the reassociation noise to
+        # ~5e-5 relative
         np.testing.assert_allclose(
             np.asarray(g), np.asarray(r), atol=1e-5, rtol=1e-4
         )

@@ -159,7 +159,7 @@ def test_checked_in_stacks_match_builders():
 
 def test_cli_dry_run_flag(tmp_path, capsys):
     """`pygrid-tpu deploy --provider gcp --app node --dry-run` writes the
-    terraform configs without applying (VERDICT item #6)."""
+    terraform configs without applying."""
     rc = cli_main([
         "deploy", "--dry-run", "--provider", "gcp", "--app", "node",
         "--id", "alice", "--root-dir", str(tmp_path),

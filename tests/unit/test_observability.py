@@ -80,16 +80,6 @@ def test_wrap_preserves_cache_size_hook_and_result():
     assert fn._cache_size() == 1  # trace_count() keeps working
 
 
-def test_wrap_without_cache_hook_attributes_first_call_to_compile():
-    fn = profiler.wrap(lambda x: x, kind="decode", bucket=1, model_id="nh")
-    fn(1)
-    fn(2)
-    (row,) = [
-        r for r in profiler.programs_snapshot() if r["model"] == "nh"
-    ]
-    assert row["compiles"] == 1 and row["hits"] == 1
-
-
 def test_wrap_disabled_is_identity(monkeypatch):
     monkeypatch.setenv("PYGRID_PROFILER", "off")
     fn = lambda x: x  # noqa: E731

@@ -163,8 +163,8 @@ def test_oplist_dialect_executes_training_plan(training_plan):
 def test_oplist_numpy_backend_runs_training_plan(training_plan):
     """A client with ONLY numpy — no jax, no XLA — can execute the hosted
     grad-traced training plan from the wire dialect and match the compiled
-    output (VERDICT item #7: the tfjs-analog portable variant must be
-    executable, reference plan_manager.py:119-149)."""
+    output (the tfjs-analog portable variant must be executable,
+    reference plan_manager.py:119-149)."""
     oplist = serde.deserialize(serde.serialize(translate_plan(training_plan, "list")))
     params = _mlp_params()
     X = np.random.RandomState(3).randn(8, 784).astype(np.float32)

@@ -139,8 +139,8 @@ def test_mismatched_parties_rejected(provider):
 
 def test_default_truncation_never_opens_secret(provider, monkeypatch):
     """The default rescale path is mask-and-open: no code path may hand the
-    dealer a reconstructed product (VERDICT: dealer-sees-all truncation was
-    the weakest crypto link; reference-exact behavior stays opt-in behind
+    dealer a reconstructed product (dealer-sees-all truncation was the
+    weakest crypto link; reference-exact behavior stays opt-in behind
     trusted_dealer=True)."""
 
     def boom(self, *a, **k):
