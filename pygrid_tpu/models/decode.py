@@ -110,8 +110,9 @@ def _block(h, layer_params, c, attn):
     x = c(_ln(h, ln1_s, ln1_b))
     a = attn(x, c(wq), c(wk), c(wv))
     h = h + c(a) @ c(wo)
-    x = c(_ln(h, ln2_s, ln2_b))
-    return h + c(jax.nn.gelu(x @ c(w1) + c(b1))) @ c(w2) + c(b2)
+    with jax.named_scope("mlp"):
+        x = c(_ln(h, ln2_s, ln2_b))
+        return h + c(jax.nn.gelu(x @ c(w1) + c(b1))) @ c(w2) + c(b2)
 
 
 def _decode_attention(q, k_cache, v_cache, n_valid):
@@ -545,30 +546,34 @@ def paged_prefill_chunk(
             # bit-identical greedy requires prefill to see the same
             k = (x @ wk).reshape(Pb, cfg.n_heads, dh).astype(new_k.dtype)
             v = (x @ wv).reshape(Pb, cfg.n_heads, dh).astype(new_v.dtype)
-            new_k = new_k.at[layer, blk, off].set(k)
-            new_v = new_v.at[layer, blk, off].set(v)
-            k_rows = new_k[layer, row].reshape(rows, cfg.n_heads, dh)
-            v_rows = new_v[layer, row].reshape(rows, cfg.n_heads, dh)
-            s = jnp.einsum(
-                "phd,lhd->hpl", q, k_rows,
-                preferred_element_type=jnp.float32,
-            ) * scale
-            s = jnp.where(mask[None, :, :], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum(
-                "hpl,lhd->phd", p.astype(v_rows.dtype), v_rows,
-                preferred_element_type=jnp.float32,
-            ).reshape(Pb, cfg.d_model)
+            with jax.named_scope("kv_write"):
+                new_k = new_k.at[layer, blk, off].set(k)
+                new_v = new_v.at[layer, blk, off].set(v)
+            with jax.named_scope("kv_gather"):
+                k_rows = new_k[layer, row].reshape(rows, cfg.n_heads, dh)
+                v_rows = new_v[layer, row].reshape(rows, cfg.n_heads, dh)
+            with jax.named_scope("paged_attention"):
+                s = jnp.einsum(
+                    "phd,lhd->hpl", q, k_rows,
+                    preferred_element_type=jnp.float32,
+                ) * scale
+                s = jnp.where(mask[None, :, :], s, -1e30)
+                p = jax.nn.softmax(s, axis=-1)
+                return jnp.einsum(
+                    "hpl,lhd->phd", p.astype(v_rows.dtype), v_rows,
+                    preferred_element_type=jnp.float32,
+                ).reshape(Pb, cfg.d_model)
 
         h = _block(h, params[idx : idx + PARAMS_PER_LAYER], c, attn)
         idx += PARAMS_PER_LAYER
     h_last = lax.dynamic_index_in_dim(
         h, length - 1 - start, axis=0, keepdims=False
     )
-    h_last = _ln(h_last, params[idx], params[idx + 1])
-    logits = jnp.dot(
-        c(h_last), c(embed).T, preferred_element_type=jnp.float32
-    )
+    with jax.named_scope("lm_head"):
+        h_last = _ln(h_last, params[idx], params[idx + 1])
+        logits = jnp.dot(
+            c(h_last), c(embed).T, preferred_element_type=jnp.float32
+        )
     return logits, PagedKVCache(
         k=new_k, v=new_v, pos=cache.pos.at[slot].set(length)
     )
@@ -629,27 +634,31 @@ def paged_decode_step(
             q = (x @ wq).reshape(w, cfg.n_heads, dh)
             k = (x @ wk).reshape(w, cfg.n_heads, dh)
             v = (x @ wv).reshape(w, cfg.n_heads, dh)
-            new_k = new_k.at[layer, blk, off].set(k.astype(new_k.dtype))
-            new_v = new_v.at[layer, blk, off].set(v.astype(new_v.dtype))
-            k_rows = new_k[layer][tw].reshape(w, rows, cfg.n_heads, dh)
-            v_rows = new_v[layer][tw].reshape(w, rows, cfg.n_heads, dh)
-            s = jnp.einsum(
-                "whd,wlhd->whl", q, k_rows,
-                preferred_element_type=jnp.float32,
-            ) * scale
-            s = jnp.where(mask[:, None, :], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum(
-                "whl,wlhd->whd", p.astype(v_rows.dtype), v_rows,
-                preferred_element_type=jnp.float32,
-            ).reshape(w, cfg.d_model)
+            with jax.named_scope("kv_write"):
+                new_k = new_k.at[layer, blk, off].set(k.astype(new_k.dtype))
+                new_v = new_v.at[layer, blk, off].set(v.astype(new_v.dtype))
+            with jax.named_scope("kv_gather"):
+                k_rows = new_k[layer][tw].reshape(w, rows, cfg.n_heads, dh)
+                v_rows = new_v[layer][tw].reshape(w, rows, cfg.n_heads, dh)
+            with jax.named_scope("paged_attention"):
+                s = jnp.einsum(
+                    "whd,wlhd->whl", q, k_rows,
+                    preferred_element_type=jnp.float32,
+                ) * scale
+                s = jnp.where(mask[:, None, :], s, -1e30)
+                p = jax.nn.softmax(s, axis=-1)
+                return jnp.einsum(
+                    "whl,wlhd->whd", p.astype(v_rows.dtype), v_rows,
+                    preferred_element_type=jnp.float32,
+                ).reshape(w, cfg.d_model)
 
         h = _block(h, params[idx : idx + PARAMS_PER_LAYER], c, attn)
         idx += PARAMS_PER_LAYER
-    h = _ln(h, params[idx], params[idx + 1])
-    logits = jnp.dot(
-        c(h), c(embed).T, preferred_element_type=jnp.float32
-    )
+    with jax.named_scope("lm_head"):
+        h = _ln(h, params[idx], params[idx + 1])
+        logits = jnp.dot(
+            c(h), c(embed).T, preferred_element_type=jnp.float32
+        )
     advance = (
         active.astype(jnp.int32) if active is not None
         else jnp.ones((w,), jnp.int32)

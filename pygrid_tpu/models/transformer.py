@@ -149,14 +149,16 @@ def features(
         (ln1_s, ln1_b, wq, wk, wv, wo, ln2_s, ln2_b, w1, b1, w2, b2) = (
             layer_params
         )
-        x = c(_ln(h, ln1_s, ln1_b))
-        q = (x @ c(wq)).reshape(B, L, cfg.n_heads, dh)
-        k = (x @ c(wk)).reshape(B, L, cfg.n_heads, dh)
-        v = (x @ c(wv)).reshape(B, L, cfg.n_heads, dh)
-        a = attn_fn(q, k, v, causal=True).reshape(B, L, cfg.d_model)
-        h = h + c(a) @ c(wo)
-        x = c(_ln(h, ln2_s, ln2_b))
-        return h + c(jax.nn.gelu(x @ c(w1) + c(b1))) @ c(w2) + c(b2)
+        with jax.named_scope("attention"):
+            x = c(_ln(h, ln1_s, ln1_b))
+            q = (x @ c(wq)).reshape(B, L, cfg.n_heads, dh)
+            k = (x @ c(wk)).reshape(B, L, cfg.n_heads, dh)
+            v = (x @ c(wv)).reshape(B, L, cfg.n_heads, dh)
+            a = attn_fn(q, k, v, causal=True).reshape(B, L, cfg.d_model)
+            h = h + c(a) @ c(wo)
+        with jax.named_scope("mlp"):
+            x = c(_ln(h, ln2_s, ln2_b))
+            return h + c(jax.nn.gelu(x @ c(w1) + c(b1))) @ c(w2) + c(b2)
 
     if remat == "dots":
         block_fn = jax.checkpoint(
@@ -225,7 +227,8 @@ def _ce_head(h2, embed, y1, fwd_cd, bwd_cd):
         lambda h2, embed, y1: fwd(h2, embed, y1)[0]
     )
     f.defvjp(fwd, bwd)
-    return f(h2, embed, y1)
+    with jax.named_scope("ce_head"):
+        return f(h2, embed, y1)
 
 
 def loss_and_acc(
@@ -315,9 +318,10 @@ def loss_and_acc(
         dl, dh_ = chunk_stats(h_blk, y_blk)
         return (loss_sum + dl, hit_sum + dh_), None
 
-    (loss_sum, hit_sum), _ = jax.lax.scan(
-        scan_body, (jnp.float32(0.0), jnp.float32(0.0)), (hf, yf)
-    )
+    with jax.named_scope("ce_head"):
+        (loss_sum, hit_sum), _ = jax.lax.scan(
+            scan_body, (jnp.float32(0.0), jnp.float32(0.0)), (hf, yf)
+        )
     return loss_sum / N, hit_sum / N
 
 

@@ -124,7 +124,8 @@ def _fused_grad_and_metrics(loss_fn, p_k, batched, client_X, client_y):
         jnp.zeros_like(p[0]) if batched else jnp.zeros_like(p)
         for p in p_k
     ]
-    (loss, acc), g = jax.value_and_grad(mean_loss, has_aux=True)(zeros)
+    with jax.named_scope("client_grad"):
+        (loss, acc), g = jax.value_and_grad(mean_loss, has_aux=True)(zeros)
     return loss, acc, g
 
 
@@ -177,10 +178,12 @@ def make_fused_rounds(
             loss, acc, g = _fused_grad_and_metrics(
                 loss_fn, p_k, batched, client_X, client_y
             )
-            mean_p = (
-                [jnp.mean(pk, axis=0) for pk in p_k] if batched else p_k
-            )
-            new_p = [mp - lr * gi for mp, gi in zip(mean_p, g)]
+            with jax.named_scope("client_reduce"):
+                mean_p = (
+                    [jnp.mean(pk, axis=0) for pk in p_k] if batched else p_k
+                )
+            with jax.named_scope("server_update"):
+                new_p = [mp - lr * gi for mp, gi in zip(mean_p, g)]
             return new_p, (loss, acc)
 
         def body():
@@ -250,14 +253,16 @@ def make_sharded_fused_round(
         # REPLICATED params/lr — pmean outputs are device-invariant and
         # mixing the pcast-varying lr back in would make the outputs
         # varying, which out_specs=P() rejects
-        g = [lax.pmean(gi, axis) for gi in g]
-        if batched:
-            mean_p = [
-                lax.pmean(jnp.mean(p, axis=0), axis) for p in p_k
-            ]
-        else:
-            mean_p = params
-        new_params = [mp - lr * gi for mp, gi in zip(mean_p, g)]
+        with jax.named_scope("client_reduce"):
+            g = [lax.pmean(gi, axis) for gi in g]
+            if batched:
+                mean_p = [
+                    lax.pmean(jnp.mean(p, axis=0), axis) for p in p_k
+                ]
+            else:
+                mean_p = params
+        with jax.named_scope("server_update"):
+            new_params = [mp - lr * gi for mp, gi in zip(mean_p, g)]
         return (
             new_params,
             lax.pmean(loss, axis),

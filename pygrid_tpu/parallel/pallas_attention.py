@@ -207,6 +207,7 @@ def _fwd_impl(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
     # [B·H, Lqp, Dp] → [B, Lq, H, D]
     out = out[:, :Lq, :D].reshape(B, H, Lq, D).transpose(0, 2, 1, 3)
@@ -504,6 +505,7 @@ def _flash_bwd(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qf, dof, lse_t8, delta_t8, kf, vf)
 
     dq_q_index = lambda bh, qi, ki: (bh, qi, 0)  # noqa: E731
@@ -538,6 +540,7 @@ def _flash_bwd(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qf, dof, lse8, delta8, kf, vf)
 
     def back(x, L_true, dtype):

@@ -50,6 +50,16 @@ Kwon et al. SOSP '23; prefix sharing after RadixAttention):
   per-model acceptance-rate telemetry (``serving_spec_*``) tells
   operators when drafting loses.
 
+Every instant of the worker thread belongs to one of six phases of a
+:class:`~pygrid_tpu.telemetry.loopclock.LoopClock` — ``idle`` (nothing
+queued, nothing live), ``admit`` (slot, pages, prompt padding, keys),
+``prefill`` (the prefill program's call until its first token is on the
+host), ``build`` (a decode dispatch's inputs and its enqueue), ``fetch``
+(blocked on the device for the tokens) and ``emit`` (tokens into rows,
+finished requests out). Each is seconds on
+``serving_loop_seconds_total{phase}`` and an ``engine.<phase>``
+annotation in a ``jax.profiler`` trace (docs/OBSERVABILITY.md §4, §6).
+
 ``PYGRID_KV_PAGED=off`` (or ``EngineConfig(paged=False)``) falls back
 to the PR-3 contiguous slot cache — the operational escape hatch and
 the bench baseline for capacity-per-GB comparisons.
@@ -352,6 +362,10 @@ class GenerationEngine:
         #: (chaos_hold_blocks) — never visible to admission, always
         #: accounted for by ledger() so a forgotten hold reads as a leak
         self._chaos_blocks: list[int] = []
+        #: the worker thread's phases (engine thread only)
+        self._clock = telemetry.loopclock.LoopClock(
+            "serving_loop_seconds_total", "engine."
+        )
         from pygrid_tpu.utils import jaxenv
 
         device = jaxenv.device_info()
@@ -833,12 +847,17 @@ class GenerationEngine:
             self._thread.start()
 
     def _loop(self) -> None:
+        clock = self._clock
         while True:
             with self._work:
                 while self._running and not self._queue and self._live == 0:
+                    clock.enter("idle")
                     self._work.wait()
-                if not self._running:
-                    return
+                running = self._running
+            if not running:
+                clock.stop()
+                return
+            clock.flush()
             try:
                 self._admit()
                 if self._spec and self._live:
@@ -874,7 +893,9 @@ class GenerationEngine:
     def _admit(self) -> None:
         import jax.numpy as jnp
 
+        clock = self._clock
         while True:
+            clock.enter("admit")
             with self._lock:
                 if not self._queue:
                     return
@@ -903,43 +924,49 @@ class GenerationEngine:
             telemetry.observe(
                 "serving_queue_wait_seconds", now - row.enqueued_at
             )
+            telemetry.incr("serving_admitted_total")
             if row.temperature > 0.0 and row.keys is None:
                 row.keys = self._row_keys(
                     row.seed, row.row, row.batch, row.n_new
                 )
+            request_id = row.pending.request_id
             t0 = time.perf_counter()
             if self._paged:
                 chunk_len = len(row.prompt) - row.start
                 bucket = self._prompt_bucket(chunk_len)
                 padded = np.zeros(bucket, np.int32)
                 padded[:chunk_len] = row.prompt[row.start :]
+                # the program's small arguments go to the device under
+                # ``admit``: ``prefill`` begins at the program's call
+                args = (
+                    self._table(), jnp.int32(slot), jnp.asarray(padded),
+                    jnp.int32(row.start), jnp.int32(len(row.prompt)),
+                    jnp.float32(row.temperature), self._key_for(row, 0),
+                )
                 if self._spec:
                     # spec admission prefills the DRAFT cache too (it
                     # needs the prompt's k/v before it can propose) —
                     # one program, first token still from the target
                     fn = self.programs.spec_prefill(bucket)
+                    clock.enter(
+                        "prefill", request_id=request_id, bucket=bucket
+                    )
                     # gridlint: disable-next=GL202 — cache buffers are engine-thread-confined
                     tok, self._k, self._v, self._pos, self._dk, self._dv = fn(
                         self.params, self._draft_params,
                         self._k, self._v, self._pos, self._dk, self._dv,
-                        self._table(), jnp.int32(slot),
-                        jnp.asarray(padded), jnp.int32(row.start),
-                        jnp.int32(len(row.prompt)),
-                        jnp.float32(row.temperature),
-                        self._key_for(row, 0),
+                        *args,
                     )
                 else:
                     fn = self.programs.paged_prefill(bucket)
+                    clock.enter(
+                        "prefill", request_id=request_id, bucket=bucket
+                    )
                     # the cache buffers are single-writer: only the
                     # engine thread swaps _k/_v/_pos between lock epochs
                     # gridlint: disable-next=GL202
                     tok, self._k, self._v, self._pos = fn(
-                        self.params, self._k, self._v, self._pos,
-                        self._table(), jnp.int32(slot),
-                        jnp.asarray(padded),
-                        jnp.int32(row.start), jnp.int32(len(row.prompt)),
-                        jnp.float32(row.temperature),
-                        self._key_for(row, 0),
+                        self.params, self._k, self._v, self._pos, *args
                     )
                 # publish the full-prompt pages for future prefix hits
                 # (first prefill wins; a matched chain is only touched)
@@ -950,15 +977,20 @@ class GenerationEngine:
                 padded = np.zeros(bucket, np.int32)
                 padded[: len(row.prompt)] = row.prompt
                 fn = self.programs.prefill(bucket)
-                # gridlint: disable-next=GL202 — engine-thread-confined
-                tok, self._k, self._v, self._pos = fn(
-                    self.params, self._k, self._v, self._pos,
+                args = (
                     jnp.int32(slot), jnp.asarray(padded),
                     jnp.int32(len(row.prompt)),
-                    jnp.float32(row.temperature),
-                    self._key_for(row, 0),
+                    jnp.float32(row.temperature), self._key_for(row, 0),
+                )
+                clock.enter(
+                    "prefill", request_id=request_id, bucket=bucket
+                )
+                # gridlint: disable-next=GL202 — engine-thread-confined
+                tok, self._k, self._v, self._pos = fn(
+                    self.params, self._k, self._v, self._pos, *args
                 )
             first = int(tok)
+            clock.enter("emit")
             telemetry.observe(
                 "serving_ttft_seconds", time.perf_counter() - row.enqueued_at
             )
@@ -1045,9 +1077,12 @@ class GenerationEngine:
         any slot freed (a finished request left the batch)."""
         import jax.numpy as jnp
 
+        clock = self._clock
+        clock.enter("build")
         live, width = self._live_snapshot()
         if not live:
             return False
+        clock.annotate(path="step", width=width, live=len(live), steps=1)
         tokens = np.zeros(width, np.int32)
         temps = np.zeros(width, np.float32)
         keys = np.zeros((width, 2), np.uint32)
@@ -1071,8 +1106,11 @@ class GenerationEngine:
                 self.params, self._k, self._v, self._pos,
                 jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
             )
+        clock.enter("fetch")
         toks = np.asarray(toks)
         dt = time.perf_counter() - t0
+        clock.enter("emit")
+        self._note_dispatch("step", width, len(live), 1, dt)
         telemetry.observe(
             "serving_batch_occupancy", float(len(live)),
             bounds=_OCCUPANCY_BOUNDS,
@@ -1094,10 +1132,15 @@ class GenerationEngine:
         instead of ``quantum`` of each. Engine thread only."""
         import jax.numpy as jnp
 
+        clock = self._clock
+        clock.enter("build")
         live, width = self._live_snapshot()
         if not live:
             return
         steps = self.config.quantum
+        clock.annotate(
+            path="fused", width=width, live=len(live), steps=steps
+        )
         tokens = np.zeros(width, np.int32)
         temps = np.zeros(width, np.float32)
         budget = np.zeros(width, np.int32)
@@ -1119,8 +1162,11 @@ class GenerationEngine:
             jnp.asarray(tokens), jnp.asarray(budget), jnp.asarray(temps),
             jnp.asarray(keys),
         )
+        clock.enter("fetch")
         toks = np.asarray(toks)  # [steps, width]
         dt = time.perf_counter() - t0
+        clock.enter("emit")
+        self._note_dispatch("fused", width, len(live), steps, dt)
         telemetry.observe(
             "serving_batch_occupancy", float(len(live)),
             bounds=_OCCUPANCY_BOUNDS,
@@ -1156,10 +1202,13 @@ class GenerationEngine:
         thread only."""
         import jax.numpy as jnp
 
+        clock = self._clock
+        clock.enter("build")
         live, width = self._live_snapshot()
         if not live:
             return 0, False
         K = self._spec_k
+        clock.annotate(path="spec", width=width, live=len(live), steps=K)
         tokens = np.zeros(width, np.int32)
         temps = np.zeros(width, np.float32)
         active = np.zeros(width, bool)
@@ -1186,10 +1235,13 @@ class GenerationEngine:
             jnp.asarray(tokens), jnp.asarray(active),
             jnp.asarray(temps), jnp.asarray(keys),
         )
+        clock.enter("fetch")
         emitted = np.asarray(emitted)
         accepted = np.asarray(accepted)
         counts = np.asarray(counts)
         dt = time.perf_counter() - t0
+        clock.enter("emit")
+        self._note_dispatch("spec", width, len(live), K, dt)
         telemetry.observe(
             "serving_batch_occupancy", float(len(live)),
             bounds=_OCCUPANCY_BOUNDS,
@@ -1227,6 +1279,21 @@ class GenerationEngine:
                 model=self.model_id,
             )
         return max_emit, freed
+
+    def _note_dispatch(
+        self, path: str, width: int, live: int, steps: int, dt: float
+    ) -> None:
+        """One decode dispatch on the bus: its seconds from the
+        program's call to the tokens fetched, under its path and width
+        bucket, and the row-steps it computed against those that
+        belonged to an occupied slot."""
+        telemetry.observe(
+            "serving_dispatch_seconds", dt, path=path, width=str(width)
+        )
+        telemetry.incr_many(
+            "serving_dispatch_rowsteps_total", "kind",
+            {"live": live * steps, "computed": width * steps},
+        )
 
     def _emit(self, slot: int, row: _Row, token: int) -> bool:
         """Append one generated token to a row; retire the row (freeing

@@ -111,11 +111,12 @@ class ProgramSet:
         import jax
         import jax.numpy as jnp
 
-        safe_t = jnp.where(temp > 0.0, temp, jnp.float32(1.0))
-        sampled = jax.random.categorical(key, logits / safe_t, axis=-1)
-        return jnp.where(
-            temp > 0.0, sampled, jnp.argmax(logits, axis=-1)
-        ).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            safe_t = jnp.where(temp > 0.0, temp, jnp.float32(1.0))
+            sampled = jax.random.categorical(key, logits / safe_t, axis=-1)
+            return jnp.where(
+                temp > 0.0, sampled, jnp.argmax(logits, axis=-1)
+            ).astype(jnp.int32)
 
     def prefill(self, bucket: int) -> Callable:
         """``fn(params, k, v, pos, slot, prompt[bucket], length, temp,

@@ -168,5 +168,6 @@ def pallas_ring_matmul(a: Ring64, b: Ring64, interpret: bool = False) -> Ring64:
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="ring_matmul",
     )(a_lo, a_hi, b_lo, b_hi)
     return Ring64(lo[:M, :N], hi[:M, :N])

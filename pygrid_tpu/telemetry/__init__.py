@@ -17,6 +17,9 @@ through:
 - :mod:`pygrid_tpu.telemetry.promtext` — a strict Prometheus
   text-format parser used by the scrape-validity tests (and handy for
   ops tooling).
+- :mod:`pygrid_tpu.telemetry.loopclock` — a loop thread's time
+  partitioned into named phases: seconds per phase on the bus, and one
+  ``jax.profiler.TraceAnnotation`` per phase on the profiler's clock.
 - :mod:`pygrid_tpu.telemetry.profiler` — per-jit-callsite
   compile/execute timing (``GET /telemetry/programs``) and background
   device-memory gauges; off-switch ``PYGRID_PROFILER=off``.
@@ -34,6 +37,7 @@ loop's budget is < 2% over the bare wire path
 from __future__ import annotations
 
 from pygrid_tpu.telemetry import (  # noqa: F401
+    loopclock,
     profiler,
     recorder,
     slo,
@@ -47,6 +51,7 @@ from pygrid_tpu.telemetry.bus import (  # noqa: F401
     events,
     histograms,
     incr,
+    incr_many,
     observe,
     record,
     reset,

@@ -22,7 +22,7 @@ import bisect
 import threading
 import time
 from collections import deque
-from typing import Any, Iterable
+from typing import Any, Iterable, Mapping
 
 #: structured events kept in memory (oldest evicted first)
 RING_SIZE = 4096
@@ -120,6 +120,21 @@ _FAMILY_HELP: dict[str, str] = {
     "serving_prefill_seconds": "per-request slot prefill (admission) time",
     "serving_queue_wait_seconds": "generation queue wait before a slot",
     "serving_batch_occupancy": "live slots per decode step",
+    # the engine thread's loop clock (telemetry/loopclock.py): every
+    # instant of the thread is in exactly one phase
+    "serving_loop_seconds_total": (
+        "engine-thread seconds by phase (idle, admit, prefill, build, "
+        "fetch, emit) — the phases partition the thread's time"
+    ),
+    "serving_dispatch_seconds": (
+        "one decode dispatch (step, fused scan or spec cycle), from the "
+        "program's call to its tokens fetched, by path and width bucket"
+    ),
+    "serving_dispatch_rowsteps_total": (
+        "decode row-steps per dispatch: kind=live (occupied rows × steps) "
+        "against kind=computed (width bucket × steps)"
+    ),
+    "serving_admitted_total": "rows admitted into a slot (prefilled)",
     # paged KV cache (docs/SERVING.md): block tables + prefix sharing
     "serving_prefix_lookups_total": (
         "prompt-prefix cache lookups at admission, by model and outcome"
@@ -265,6 +280,17 @@ class TelemetryBus:
             key = self._admit(name, _label_key(labels), self._counters)
             self._counters[key] = self._counters.get(key, 0) + value
 
+    def incr_many(
+        self, name: str, label: str, amounts: Mapping[str, float]
+    ) -> None:
+        """Add ``amounts[v]`` to ``name{label=v}`` for every ``v`` under
+        ONE hold of the lock — a producer that keeps its own sums (the
+        engine's loop clock) pays one lock a flush, not one a series."""
+        with self._lock:
+            for value, amount in amounts.items():
+                key = self._admit(name, ((label, value),), self._counters)
+                self._counters[key] = self._counters.get(key, 0) + amount
+
     def observe(
         self,
         name: str,
@@ -314,6 +340,7 @@ BUS = TelemetryBus()
 
 record = BUS.record
 incr = BUS.incr
+incr_many = BUS.incr_many
 observe = BUS.observe
 events = BUS.events
 counters = BUS.counters

@@ -3,9 +3,11 @@
 The reference has no profiling at all (SURVEY.md §5.1 — stdlib logging
 only); the rebuild note there calls for real instrumentation via
 ``jax.profiler`` + ``block_until_ready`` timers. These are the shared
-helpers: a sync-correct timer (device fetch, not dispatch, marks the end),
-an XLA trace context for tensorboard/perfetto dumps, and a process-wide
-stats registry the node's ``/status`` surface can report."""
+helpers: a sync-correct timer (device fetch, not dispatch, marks the end)
+and a process-wide stats registry the node's ``/status`` surface can
+report. A device profile is taken with ``jax.profiler`` from outside (the
+benchmark's ``perfbench/lib/trace.py``); the serving engine's phases show
+in it as ``engine.<phase>`` (``telemetry/loopclock.py``)."""
 
 from __future__ import annotations
 
@@ -14,7 +16,7 @@ import threading
 import time
 from collections import defaultdict
 from dataclasses import dataclass, field
-from typing import Any, Callable, Iterator
+from typing import Any, Iterator
 
 
 @dataclass
@@ -83,25 +85,3 @@ def timed(name: str, sync: Any = None) -> Iterator[dict]:
             jax.block_until_ready(target)
         box["seconds"] = time.monotonic() - t0
         stats.record(name, box["seconds"])
-
-
-def timed_call(name: str, fn: Callable, *args: Any, **kwargs: Any):
-    """Run ``fn``, block on its outputs, record; returns (result, seconds)."""
-    with timed(name) as box:
-        result = fn(*args, **kwargs)
-        box["sync"] = result
-    return result, box["seconds"]
-
-
-@contextlib.contextmanager
-def xla_trace(log_dir: str) -> Iterator[None]:
-    """``jax.profiler`` trace context → tensorboard/perfetto dump in
-    ``log_dir``. The computation-tracing sibling (Plans) lives in
-    :mod:`pygrid_tpu.plans`; this one is the performance profiler."""
-    import jax
-
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()

@@ -4,8 +4,6 @@ from __future__ import annotations
 
 import time
 
-import jax.numpy as jnp
-
 from pygrid_tpu.utils import profiling
 
 
@@ -21,14 +19,12 @@ def test_timed_records_wall_time():
     assert snap["count"] == 1 and snap["total_s"] >= 0.02
 
 
-def test_timed_call_blocks_on_device_result():
-    def work(x):
-        return jnp.sum(x * x)
+def test_timed_ends_after_the_device_result_it_is_given():
+    import jax.numpy as jnp
 
-    result, seconds = profiling.timed_call(
-        "unit.device", work, jnp.arange(1024.0)
-    )
-    assert float(result) > 0 and seconds > 0
+    with profiling.timed("unit.device") as box:
+        box["sync"] = jnp.sum(jnp.arange(1024.0) ** 2)
+    assert float(box["sync"]) > 0 and box["seconds"] > 0
     assert profiling.stats.snapshot()["unit.device"]["count"] == 1
 
 
