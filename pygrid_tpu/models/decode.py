@@ -17,7 +17,10 @@ generation exists here because the transformer family does. The decode
 attention is a masked dense pass over the cache — at single-token decode
 the op is bandwidth-bound on the cache read and XLA's fused
 softmax(qkᵀ)v is already the right program, so no Pallas kernel is
-needed (the flash kernel earns its keep on the L×L training path).
+needed (the flash kernel earns its keep on the L×L training path). The
+PAGED step is the exception: gathering whole block tables made it read
+~90× the live k/v, so on a TPU its attention is
+:mod:`pygrid_tpu.serving.paged_attention`'s kernel (PERF.md §6, PR 25).
 
 Correctness contract: greedy decode from a prompt must equal repeated
 full-forward ``transformer.apply`` argmax (teacher-forced equivalence,
@@ -602,7 +605,15 @@ def paged_decode_step(
     stepping a batch after some rows finish (wasted compute, no state
     damage) — an active row's numerics are untouched by the mask, so
     the bit-identical-greedy contract survives fusion.
+
+    Attention takes one of two paths, by one rule read when the program
+    is traced (:func:`pygrid_tpu.serving.paged_attention.eligible`): on
+    a TPU, at shapes it tiles, a Pallas kernel reads each slot's live
+    pages in place; everywhere else the slot's whole table is gathered
+    and masked, as below.
     """
+    from pygrid_tpu.serving import paged_attention
+
     cd = jnp.dtype(compute_dtype) if compute_dtype is not None else None
 
     def c(x):
@@ -624,6 +635,13 @@ def paged_decode_step(
     h = c(embed[token] + pos_emb[jnp.minimum(t, cfg.max_len - 1)])
     mask = jnp.arange(rows)[None, :] <= t[:, None]  # [w, rows]
     scale = dh**-0.5
+    use_kernel = paged_attention.eligible(cache.k, max_pages)
+    if use_kernel:
+        #: rows the kernel attends over: ``l <= t``. A free slot inside
+        #: the width (zeroed table row: block 0 is never a live row's
+        #: page) has a stale, growing ``pos``; it reads one page of
+        #: trash, not 32
+        lengths = jnp.where(tw[:, 0] != 0, jnp.minimum(t + 1, rows), 1)
 
     new_k, new_v = cache.k, cache.v
     idx = 2
@@ -637,6 +655,14 @@ def paged_decode_step(
             with jax.named_scope("kv_write"):
                 new_k = new_k.at[layer, blk, off].set(k.astype(new_k.dtype))
                 new_v = new_v.at[layer, blk, off].set(v.astype(new_v.dtype))
+            if use_kernel:
+                with jax.named_scope("paged_attention"):
+                    # off the TPU only a test that patched ``eligible``
+                    # gets here: the same kernel, interpreted
+                    return paged_attention.paged_decode_attention(
+                        q, new_k, new_v, jnp.int32(layer), tw, lengths,
+                        interpret=jax.default_backend() != "tpu",
+                    ).reshape(w, cfg.d_model)
             with jax.named_scope("kv_gather"):
                 k_rows = new_k[layer][tw].reshape(w, rows, cfg.n_heads, dh)
                 v_rows = new_v[layer][tw].reshape(w, rows, cfg.n_heads, dh)

@@ -92,7 +92,7 @@ from typing import Any
 import numpy as np
 
 from pygrid_tpu import telemetry
-from pygrid_tpu.serving import pagedkv
+from pygrid_tpu.serving import paged_attention, pagedkv
 from pygrid_tpu.serving.programs import (
     ProgramSet,
     prompt_buckets,
@@ -333,6 +333,11 @@ class GenerationEngine:
         # held as separate refs: the jitted programs donate and return
         # them, and the engine swaps in the new buffers every call
         self._k, self._v, self._pos = cache.k, cache.v, cache.pos
+        #: whether decode attention reads live pages in place (the rule
+        #: the decode programs themselves apply when they are traced)
+        self._kv_kernel = self._paged and paged_attention.eligible(
+            self._k, self._max_pages
+        )
         #: the draft's k/v pool: same block ids/tables as the target
         #: (allocation covers both), fewer layers; position state is
         #: shared — the draft is always exactly at the target's pos
@@ -1110,7 +1115,7 @@ class GenerationEngine:
         toks = np.asarray(toks)
         dt = time.perf_counter() - t0
         clock.enter("emit")
-        self._note_dispatch("step", width, len(live), 1, dt)
+        self._note_dispatch("step", width, live, 1, dt)
         telemetry.observe(
             "serving_batch_occupancy", float(len(live)),
             bounds=_OCCUPANCY_BOUNDS,
@@ -1166,7 +1171,7 @@ class GenerationEngine:
         toks = np.asarray(toks)  # [steps, width]
         dt = time.perf_counter() - t0
         clock.enter("emit")
-        self._note_dispatch("fused", width, len(live), steps, dt)
+        self._note_dispatch("fused", width, live, steps, dt)
         telemetry.observe(
             "serving_batch_occupancy", float(len(live)),
             bounds=_OCCUPANCY_BOUNDS,
@@ -1241,7 +1246,7 @@ class GenerationEngine:
         counts = np.asarray(counts)
         dt = time.perf_counter() - t0
         clock.enter("emit")
-        self._note_dispatch("spec", width, len(live), K, dt)
+        self._note_dispatch("spec", width, live, K, dt)
         telemetry.observe(
             "serving_batch_occupancy", float(len(live)),
             bounds=_OCCUPANCY_BOUNDS,
@@ -1281,19 +1286,54 @@ class GenerationEngine:
         return max_emit, freed
 
     def _note_dispatch(
-        self, path: str, width: int, live: int, steps: int, dt: float
+        self,
+        path: str,
+        width: int,
+        live: list[tuple[int, "_Row"]],
+        steps: int,
+        dt: float,
     ) -> None:
-        """One decode dispatch on the bus: its seconds from the
-        program's call to the tokens fetched, under its path and width
-        bucket, and the row-steps it computed against those that
-        belonged to an occupied slot."""
+        """One decode dispatch on the bus, BEFORE its tokens are emitted
+        (the rows still hold the lengths the program ran at): its
+        seconds from the program's call to the tokens fetched, under its
+        path and width bucket; the row-steps it computed against those
+        that belonged to an occupied slot; and, paged, the KV pages its
+        attention read against the pages its block tables span."""
         telemetry.observe(
             "serving_dispatch_seconds", dt, path=path, width=str(width)
         )
         telemetry.incr_many(
             "serving_dispatch_rowsteps_total", "kind",
-            {"live": live * steps, "computed": width * steps},
+            {"live": len(live) * steps, "computed": width * steps},
         )
+        if self._paged:
+            table = width * self._max_pages * steps
+            telemetry.incr_many(
+                "serving_kv_pages_total", "kind",
+                {
+                    "read": (
+                        self._kernel_pages(width, live, steps)
+                        if self._kv_kernel and path != "spec"
+                        else table  # the gather reads whole tables
+                    ),
+                    "table": table,
+                },
+            )
+
+    def _kernel_pages(
+        self, width: int, live: list[tuple[int, "_Row"]], steps: int
+    ) -> int:
+        """Pages ``paged_attention``'s kernel reads over one dispatch of
+        ``steps`` steps, from the host's own row state: a live row's
+        pages up to its length at each step (parked once the row has its
+        tokens, as its position is), one trash page for each free slot
+        inside the width."""
+        base = np.array([len(r.prompt) + len(r.out) for _, r in live])
+        need = np.array([r.n_new - len(r.out) for _, r in live])
+        lengths = base[:, None] + np.minimum(np.arange(steps), need[:, None])
+        rows = self._max_pages * self._block
+        pages = -(-np.minimum(lengths, rows) // self._block)
+        return int(pages.sum()) + (width - len(live)) * steps
 
     def _emit(self, slot: int, row: _Row, token: int) -> bool:
         """Append one generated token to a row; retire the row (freeing

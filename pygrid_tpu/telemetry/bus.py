@@ -135,6 +135,10 @@ _FAMILY_HELP: dict[str, str] = {
         "against kind=computed (width bucket × steps)"
     ),
     "serving_admitted_total": "rows admitted into a slot (prefilled)",
+    "serving_kv_pages_total": (
+        "KV pages per decode dispatch: kind=read (pages its attention "
+        "read) against kind=table (width bucket × pages a table × steps)"
+    ),
     # paged KV cache (docs/SERVING.md): block tables + prefix sharing
     "serving_prefix_lookups_total": (
         "prompt-prefix cache lookups at admission, by model and outcome"

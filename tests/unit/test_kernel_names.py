@@ -92,3 +92,68 @@ def test_ring_matmul_lowers_for_the_v5e_under_its_name(
         .lower(Ring64(word, word), Ring64(word, word)).compile().as_text()
     )
     assert "tpu_custom_call" in text and "ring_matmul" in text
+
+
+#: the serving cells' pool and tables (Cerebras-GPT-1.3B served in bf16:
+#: 24 layers, 768 blocks of 64 tokens, 16 heads of 128, 32 pages a slot)
+POOL = (24, 768, 64, 16, 128)
+MAX_PAGES = 32
+
+
+@pytest.mark.parametrize("q_dtype", ["float32", "bfloat16"])
+def test_paged_decode_attention_lowers_for_the_v5e_under_its_name(
+    q_dtype, one_chip, no_compile_cache
+):
+    from pygrid_tpu.serving.paged_attention import paged_decode_attention
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = arg(POOL, jnp.bfloat16)
+    text = (
+        jax.jit(lambda *a: paged_decode_attention(*a))
+        .lower(
+            arg((16, 16, 128), q_dtype), pool, pool, arg((), jnp.int32),
+            arg((16, MAX_PAGES), jnp.int32), arg((16,), jnp.int32),
+        ).compile().as_text()
+    )
+    assert "tpu_custom_call" in text and "paged_decode_attention" in text
+
+
+def test_decode_step_at_the_cells_width_holds_nothing_pool_sized(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """The width-16 step program, traced as it is on a TPU: one kernel a
+    layer, and no value of one layer's pool (the gather's operand), of
+    all the tables' pages (the gather's result) or a copy of the pool."""
+    import re
+
+    from pygrid_tpu.models import transformer as T
+    from pygrid_tpu.serving.programs import ProgramSet
+
+    # what ``paged_attention.eligible`` and the step's ``interpret`` read
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = T.TransformerConfig(
+        vocab=50257, d_model=2048, n_heads=16, n_layers=24, d_ff=8192,
+        max_len=2048,
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(lambda: T.init(jax.random.PRNGKey(0), cfg))
+    params = [arg(p.shape, jnp.bfloat16) for p in shapes]
+    pool, w = arg(POOL, jnp.bfloat16), 16
+    step = ProgramSet(cfg).paged_decode(w)
+    while not hasattr(step, "lower"):  # the profiler's wrapper
+        step = step.__wrapped__
+    text = step.lower(
+        params, pool, pool, arg((16,), jnp.int32),
+        arg((16, MAX_PAGES), jnp.int32), arg((w,), jnp.int32),
+        arg((w,), jnp.float32), arg((w, 2), jnp.uint32),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") == cfg.n_layers
+    assert "paged_decode_attention" in text
+    one_layer, tables = "768,64,16,128]", f"{w * MAX_PAGES},64,16,128]"
+    assert not re.search(rf"= \w+\[({re.escape(one_layer)}|{re.escape(tables)})", text)
+    assert not re.search(r"= \w+\[24,768,64,16,128\][^ ]* copy\(", text)

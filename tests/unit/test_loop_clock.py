@@ -204,6 +204,47 @@ def test_rowsteps_match_a_hand_count(
     assert only == same and only["count"] == dispatches
 
 
+@pytest.mark.parametrize("kernel", [False, True], ids=["gather", "kernel"])
+@pytest.mark.parametrize(
+    "fused, n_long, table, live_pages",
+    [
+        # 3 dispatches at width 16, tables of 32 / 8 = 4 pages. Every
+        # row is under one page (prompt 5 + at most 3); once the short
+        # rows left, 15 free slots read one trash page each
+        (False, 4, 3 * 16 * 4, 16 + (1 + 15) + (1 + 15)),
+        # 2 scans of 8 steps. Scan 1: the short rows park at 7 rows (15
+        # x 8 pages), the long row grows 6..13 rows (3 steps in one
+        # page, 5 in two). Scan 2: it grows 14..16, then parks at 17
+        # rows (3 x 2 + 5 x 3 pages) beside 15 free slots
+        (True, 12, 2 * 16 * 8 * 4, (120 + 3 + 10) + (6 + 15 + 120)),
+    ],
+    ids=["step", "fused"],
+)
+def test_kv_pages_match_a_hand_count(
+    params, fused, n_long, table, live_pages, kernel
+):
+    """``table`` counts the pages the dispatched tables span; ``read``
+    equals it on the gather path, and is the rows' live pages where the
+    engine found its pool eligible for the kernel (set by hand here: the
+    count is the host's own arithmetic, the programs still gather)."""
+    eng = _engine(params, fused=fused)
+    assert eng._kv_kernel is False  # tiny heads on a CPU
+    eng._kv_kernel = kernel
+    go, _wall = _gate(eng)
+    try:
+        short = eng.enqueue(np.tile(PROMPT, (15, 1)), 2)  # slots 0..14
+        long = eng.enqueue(PROMPT[None, :], n_long)  # slot 15
+        go.set()
+        short.result(120)
+        long.result(120)
+    finally:
+        eng.close()
+    assert _counter("serving_kv_pages_total", kind="table") == table
+    assert _counter("serving_kv_pages_total", kind="read") == (
+        live_pages if kernel else table
+    )
+
+
 def test_new_families_reach_metrics_and_parse_strictly(params):
     from pygrid_tpu.telemetry import promtext
     from pygrid_tpu.utils.metrics import Exposition
@@ -230,9 +271,12 @@ def test_new_families_reach_metrics_and_parse_strictly(params):
     rows = families["pygrid_serving_dispatch_rowsteps_total"]
     assert {s[1]["kind"] for s in rows.samples} == {"live", "computed"}
     assert families["pygrid_serving_admitted_total"].samples[0][2] == 1.0
+    pages = families["pygrid_serving_kv_pages_total"]
+    assert {s[1]["kind"] for s in pages.samples} == {"read", "table"}
     for name in (
         "serving_loop_seconds_total", "serving_dispatch_seconds",
         "serving_dispatch_rowsteps_total", "serving_admitted_total",
+        "serving_kv_pages_total",
     ):
         assert not telemetry.bus.family_help(name).startswith(
             "pygrid telemetry metric"
