@@ -29,6 +29,7 @@ full-forward ``transformer.apply`` argmax (teacher-forced equivalence,
 
 from __future__ import annotations
 
+import sys
 from typing import Any, NamedTuple, Sequence
 
 import jax
@@ -59,8 +60,13 @@ def bundle(
     }
 
 
-def from_bundle(spec: dict) -> tuple[TransformerConfig, list[jax.Array]]:
-    """Inverse of :func:`bundle` (validates the family tag)."""
+def from_bundle(spec: dict) -> tuple[Any, Any]:
+    """Inverse of a family's ``bundle`` (validates the family tag):
+    ``(config, parameters)`` as the serving engine takes them."""
+    if isinstance(spec, dict) and spec.get("family") == "jamba":
+        from pygrid_tpu.models import jamba
+
+        return jamba.from_bundle(spec)
     if not isinstance(spec, dict) or spec.get("family") != "transformer":
         raise ValueError("not a generative transformer bundle")
     cfg = TransformerConfig(*[int(v) for v in spec["cfg"]])
@@ -71,6 +77,51 @@ def from_bundle(spec: dict) -> tuple[TransformerConfig, list[jax.Array]]:
             f"bundle has {len(params)} params, config needs {expect}"
         )
     return cfg, params
+
+
+# ── what the serving engine asks of a model family ───────────────────────
+#
+# The engine and its programs reach a hosted model only through its
+# family's module (:func:`family_of`): ``init_paged_cache``,
+# ``paged_prefill_chunk`` and ``paged_decode_step`` over a cache whose
+# first three fields are ``k, v, pos``, and the facts below. This module
+# is the transformer's; :mod:`pygrid_tpu.models.jamba` the hybrid's.
+
+#: no recurrent state: prefix pages can be shared, a draft can be rolled back
+RECURRENT = False
+
+
+def family_of(cfg) -> Any:
+    """The module that serves configurations of ``cfg``'s type."""
+    from pygrid_tpu.models import jamba
+
+    return jamba if isinstance(cfg, jamba.JambaConfig) else sys.modules[__name__]
+
+
+def kv_layers(cfg: TransformerConfig) -> int:
+    """Layers that hold keys and values in the block pool: all of them."""
+    return cfg.n_layers
+
+
+def kv_heads(cfg: TransformerConfig) -> int:
+    return cfg.n_heads
+
+
+def kv_kernel(cache_k: jax.Array, max_pages: int) -> bool:
+    """Whether decode attention over this pool reads live pages in place."""
+    from pygrid_tpu.serving import paged_attention
+
+    return paged_attention.eligible(cache_k, max_pages)
+
+
+def state_bytes_per_slot(cfg: TransformerConfig, dtype: Any) -> int:
+    """Device bytes a slot holds beside its K/V pages: none."""
+    return 0
+
+
+def cache_elements(cfg: TransformerConfig, batch: int) -> int:
+    """Cache elements ``batch`` rows can hold at ``max_len``."""
+    return 2 * cfg.n_layers * batch * cfg.max_len * cfg.d_model
 
 
 class KVCache(NamedTuple):
@@ -463,6 +514,10 @@ class PagedKVCache(NamedTuple):
     k: jax.Array
     v: jax.Array
     pos: jax.Array
+
+
+#: the cache the paged programs carry (``family_of``'s contract)
+PagedCache = PagedKVCache
 
 
 def init_paged_cache(

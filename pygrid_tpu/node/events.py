@@ -657,9 +657,7 @@ def _prepare_generation(ctx: NodeContext, message: dict):
     # plans/translators.py. The batch engine's cache is allocated per
     # SLOT, not per request, but the same cap bounds how many rows one
     # frame may enqueue.
-    cache_elems = (
-        2 * cfg.n_layers * prompt.shape[0] * cfg.max_len * cfg.d_model
-    )
+    cache_elems = decode.family_of(cfg).cache_elements(cfg, prompt.shape[0])
     if cache_elems > _MAX_GENERATION_CACHE_ELEMENTS:
         return {
             SUCCESS: False,
@@ -736,6 +734,13 @@ def _legacy_generate(hosted, prompt, n_new: int, temperature, seed):
     import numpy as np
 
     cfg, params = hosted.generation_cache
+    from pygrid_tpu.models import decode
+
+    if decode.family_of(cfg) is not decode:
+        raise E.PyGridError(
+            "PYGRID_SERVING=off serves only the transformer family: this "
+            "model runs through the batch engine"
+        )
     if temperature > 0.0 and seed is None:
         # unseeded sampling must actually vary across requests
         seed = int.from_bytes(os.urandom(4), "big")

@@ -530,6 +530,12 @@ async def metrics(request: web.Request) -> web.Response:
                   "generation slots decoding right now", labels)
         exp.gauge("serving_max_slots", eng["max_slots"],
                   "generation slots in the shared KV cache", labels)
+        exp.gauge("serving_kv_kernel", eng["kv_kernel"],
+                  "decode attention path: 1 the paged kernel, 0 the gather",
+                  labels)
+        exp.gauge("serving_state_bytes", eng["state_bytes"],
+                  "device bytes of per-slot recurrent state beside the "
+                  "KV pool", labels)
         if eng.get("paged"):
             # block-pool occupancy: free / used (held by live requests,
             # INCLUDING cached blocks they share) / cached (reclaimable

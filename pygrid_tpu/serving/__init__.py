@@ -113,9 +113,12 @@ class ServingManager:
         budget, size this model's block pool to its admission-weight
         share (``weight / Σ weights × PYGRID_KV_BUDGET``); explicit
         ``num_blocks``/``kv_budget_bytes`` on the base config win."""
+        from pygrid_tpu.models import decode
+
         base = self.config
+        recurrent = decode.family_of(cfg).RECURRENT  # served paged only
         if (
-            not pagedkv.paged_enabled(base.paged)
+            not (recurrent or pagedkv.paged_enabled(base.paged))
             or base.num_blocks is not None
             or base.kv_budget_bytes is not None
             or self.budget.total_bytes is None
@@ -128,7 +131,11 @@ class ServingManager:
         if dtype is None:
             dtype = pagedkv.default_cache_dtype()
         extra = 0
-        if pagedkv.spec_enabled(base.spec_decode) and cfg.n_layers >= 2:
+        if (
+            pagedkv.spec_enabled(base.spec_decode)
+            and cfg.n_layers >= 2
+            and not recurrent
+        ):
             # the speculative draft's pool rides the same block ids —
             # its layers are part of what a granted block costs
             extra = pagedkv.resolve_spec_layers(
@@ -137,6 +144,8 @@ class ServingManager:
         blocks = self.budget.blocks_for(
             model_id,
             pagedkv.block_bytes(cfg, block, dtype, extra_layers=extra),
+            # a recurrent family's per-slot state is charged first
+            fixed_bytes=pagedkv.state_bytes(cfg, base.max_slots, dtype),
         )
         if blocks is None:
             return base

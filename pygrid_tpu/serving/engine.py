@@ -92,7 +92,7 @@ from typing import Any
 import numpy as np
 
 from pygrid_tpu import telemetry
-from pygrid_tpu.serving import paged_attention, pagedkv
+from pygrid_tpu.serving import pagedkv
 from pygrid_tpu.serving.programs import (
     ProgramSet,
     prompt_buckets,
@@ -224,6 +224,7 @@ class GenerationEngine:
         config: EngineConfig | None = None,
         model_id: str = "",
     ) -> None:
+        import jax
         import jax.numpy as jnp
 
         from pygrid_tpu.models import decode
@@ -232,7 +233,16 @@ class GenerationEngine:
         self.model_id = model_id
         self.config = config or EngineConfig()
         self.params = params
-        self._paged = pagedkv.paged_enabled(self.config.paged)
+        #: the module that serves this config's family (its cache, its
+        #: prefill and decode step, and what it keeps per slot)
+        self._family = decode.family_of(cfg)
+        #: a recurrent state beside the K/V pages: observed, not a knob.
+        #: Such a model is served paged only (its K/V layers have no
+        #: contiguous twin), shares no prefix pages and drafts nothing
+        self._recurrent = bool(self._family.RECURRENT)
+        self._paged = self._recurrent or pagedkv.paged_enabled(
+            self.config.paged
+        )
         #: fused multi-step decode and self-speculative decoding both
         #: need the block-table discipline (trash-routed frozen writes),
         #: so they ride the paged path only; spec additionally needs a
@@ -240,11 +250,13 @@ class GenerationEngine:
         self._fused = self._paged and pagedkv.fused_enabled(
             self.config.fused
         )
-        self._spec = (
-            self._paged
-            and cfg.n_layers >= 2
-            and pagedkv.spec_enabled(self.config.spec_decode)
+        spec_asked = cfg.n_layers >= 2 and pagedkv.spec_enabled(
+            self.config.spec_decode
         )
+        self._spec = self._paged and spec_asked and not self._recurrent
+        #: said once, at warm-up: speculative decode was asked for and a
+        #: draft over recurrent state cannot be rolled back
+        self._spec_ignored = spec_asked and self._recurrent
         self._spec_k = pagedkv.resolve_spec_k(self.config.spec_k)
         draft_cfg = None
         self._draft_params = None
@@ -280,6 +292,8 @@ class GenerationEngine:
                 else pagedkv.default_cache_dtype()
             )
         )
+        #: bytes a slot holds beside its K/V pages (0: the transformer)
+        self._state_per_slot = pagedkv.state_bytes(cfg, 1, self._kv_dtype)
         if self._paged:
             self._block = pagedkv.resolve_block_size(
                 cfg.max_len, self.config.block_size
@@ -298,15 +312,20 @@ class GenerationEngine:
                 )
                 # the trash block counts INSIDE the byte budget (same
                 # accounting as DeviceBudget.blocks_for): an operator
-                # sizing to available HBM must never be overshot
-                num_blocks = int(self.config.kv_budget_bytes) // per_block
+                # sizing to available HBM must never be overshot. A
+                # recurrent family's fixed state comes out of it first
+                num_blocks = (
+                    int(self.config.kv_budget_bytes) - self._state_bytes()
+                ) // per_block
             else:
                 # byte parity with the contiguous slot cache — same
                 # footprint, but short requests free what they don't use
                 num_blocks = 1 + self.config.max_slots * self._max_pages
             self._num_blocks = max(2, num_blocks)
             self._pool = pagedkv.BlockPool(self._num_blocks)
-            self._prefix = pagedkv.PrefixCache(self._pool, self._block)
+            self._prefix = pagedkv.PrefixCache(
+                self._pool, self._block, shareable=not self._recurrent
+            )
             #: blocks given back to the device budget by live
             #: re-partitioning (shrink_blocks) — survives _fail_all's
             #: pool rebuild
@@ -322,7 +341,7 @@ class GenerationEngine:
             self._prefix_hits = 0
             self._prefix_misses = 0
             self._prefix_tokens_saved = 0
-            cache = decode.init_paged_cache(
+            cache = self._family.init_paged_cache(
                 cfg, self.config.max_slots, self._num_blocks,
                 self._block, dtype=self._kv_dtype,
             )
@@ -331,11 +350,14 @@ class GenerationEngine:
                 cfg, self.config.max_slots, dtype=self._kv_dtype
             )
         # held as separate refs: the jitted programs donate and return
-        # them, and the engine swaps in the new buffers every call
-        self._k, self._v, self._pos = cache.k, cache.v, cache.pos
+        # them, and the engine swaps in the new buffers every call.
+        # ``_state`` is what the family keeps per slot beside k/v/pos
+        # (a recurrent state; nothing for the transformer): it rides
+        # every paged program call, donated like the rest
+        self._k, self._v, self._pos, *self._state = cache
         #: whether decode attention reads live pages in place (the rule
         #: the decode programs themselves apply when they are traced)
-        self._kv_kernel = self._paged and paged_attention.eligible(
+        self._kv_kernel = self._paged and self._family.kv_kernel(
             self._k, self._max_pages
         )
         #: the draft's k/v pool: same block ids/tables as the target
@@ -371,6 +393,9 @@ class GenerationEngine:
         self._clock = telemetry.loopclock.LoopClock(
             "serving_loop_seconds_total", "engine."
         )
+        self._weights_bytes = int(
+            sum(leaf.nbytes for leaf in jax.tree.leaves(params))
+        )
         from pygrid_tpu.utils import jaxenv
 
         device = jaxenv.device_info()
@@ -386,7 +411,7 @@ class GenerationEngine:
             "compute_dtype": np.dtype(
                 self.config.compute_dtype
                 if self.config.compute_dtype is not None
-                else params[0].dtype
+                else jax.tree.leaves(params)[0].dtype
             ).name,
         }
 
@@ -546,6 +571,16 @@ class GenerationEngine:
                 "paged": self._paged,
                 "fused": self._fused,
                 "spec": self._spec,
+                # which decode-attention path the programs took (1: the
+                # kernel reads live pages in place; 0: the gather)
+                "kv_kernel": int(self._kv_kernel),
+                # what the slots hold beside their K/V pages (a
+                # recurrent family's fixed state; 0 for the transformer)
+                "state_bytes": self._state_bytes(),
+                "state_bytes_per_slot": self._state_per_slot,
+                # what a decode step reads whatever the batch: the
+                # parameters, once
+                "weights_bytes": self._weights_bytes,
                 **self._runs_on,
             }
             if self._fused:
@@ -585,6 +620,7 @@ class GenerationEngine:
                 out.update(
                     {
                         "block_size": self._block,
+                        "kv_block_bytes": self.block_cost_bytes(),
                         "kv_blocks_total": self._pool.usable,
                         "kv_blocks_retired": self._pool.retired_count(),
                         "kv_blocks_free": self._pool.free_count(),
@@ -652,6 +688,9 @@ class GenerationEngine:
                 "cached": cached,
                 "retired": pool["retired"],
                 "chaos_held": chaos,
+                # fixed per-slot state beside the pool: never allocated
+                # or freed with traffic, so it is outside the balance
+                "state_bytes": self._state_bytes(),
                 "drained": drained,
                 # not-drained engines are balanced as long as the pool's
                 # own accounting closes; once drained the stronger
@@ -706,6 +745,10 @@ class GenerationEngine:
             self.cfg, self._block, self._kv_dtype, extra_layers=extra
         )
 
+    def _state_bytes(self) -> int:
+        """Device bytes of the per-slot state beside the block pool."""
+        return self.config.max_slots * self._state_per_slot
+
     def shrink_blocks(self, n: int) -> int:
         """Give up to ``n`` KV blocks back to the node's device budget
         — live re-partitioning when another model registers against the
@@ -741,6 +784,20 @@ class GenerationEngine:
         with self._lock:
             if self._live > 0 or self._queue:
                 return
+        if self._spec_ignored:
+            logger.warning(
+                "engine %s: PYGRID_SPEC_DECODE is ignored — the model "
+                "keeps a recurrent state a rejected draft could not be "
+                "rolled back from", self.model_id,
+            )
+        if self._paged and not self._kv_kernel and (
+            self._runs_on["platform"] == "tpu"
+        ):
+            logger.warning(
+                "engine %s: decode attention takes the gather path on "
+                "this TPU (pool %s %s does not tile for the paged kernel)",
+                self.model_id, self._k.dtype, tuple(self._k.shape),
+            )
         zero_key = jnp.zeros((2,), jnp.uint32)
         seen = set()
         for p_len in prompt_lens or (1,):
@@ -761,9 +818,9 @@ class GenerationEngine:
                 )
             elif self._paged:
                 fn = self.programs.paged_prefill(bucket)
-                _tok, self._k, self._v, self._pos = fn(
+                _tok, self._k, self._v, self._pos, *self._state = fn(
                     self.params, self._k, self._v, self._pos,
-                    self._table(), jnp.int32(0),
+                    *self._state, self._table(), jnp.int32(0),
                     jnp.zeros((bucket,), jnp.int32), jnp.int32(0),
                     jnp.int32(1), jnp.float32(0.0), zero_key,
                 )
@@ -789,9 +846,9 @@ class GenerationEngine:
                 )
             elif self._paged:
                 fn = self.programs.paged_decode(w)
-                _toks, self._k, self._v, self._pos = fn(
+                _toks, self._k, self._v, self._pos, *self._state = fn(
                     self.params, self._k, self._v, self._pos,
-                    self._table(), jnp.zeros((w,), jnp.int32),
+                    *self._state, self._table(), jnp.zeros((w,), jnp.int32),
                     jnp.zeros((w,), jnp.float32),
                     jnp.zeros((w, 2), jnp.uint32),
                 )
@@ -800,9 +857,10 @@ class GenerationEngine:
                     fn = self.programs.paged_decode_fused(
                         w, self.config.quantum
                     )
-                    _e, self._k, self._v, self._pos = fn(
+                    _e, self._k, self._v, self._pos, *self._state = fn(
                         self.params, self._k, self._v, self._pos,
-                        self._table(), jnp.zeros((w,), jnp.int32),
+                        *self._state, self._table(),
+                        jnp.zeros((w,), jnp.int32),
                         jnp.zeros((w,), jnp.int32),
                         jnp.zeros((w,), jnp.float32),
                         jnp.zeros(
@@ -941,6 +999,18 @@ class GenerationEngine:
                 bucket = self._prompt_bucket(chunk_len)
                 padded = np.zeros(bucket, np.int32)
                 padded[:chunk_len] = row.prompt[row.start :]
+                # tokens the prefill program computes over against the
+                # prompt's own: the bucket's padding is real device work
+                telemetry.incr_many(
+                    "serving_prefill_tokens_total", "kind",
+                    {"true": chunk_len, "padded": bucket},
+                )
+                if self._recurrent:
+                    # the prefill writes the slot's whole state
+                    telemetry.incr(
+                        "serving_state_bytes_total",
+                        self._state_per_slot, kind="written",
+                    )
                 # the program's small arguments go to the device under
                 # ``admit``: ``prefill`` begins at the program's call
                 args = (
@@ -970,8 +1040,9 @@ class GenerationEngine:
                     # the cache buffers are single-writer: only the
                     # engine thread swaps _k/_v/_pos between lock epochs
                     # gridlint: disable-next=GL202
-                    tok, self._k, self._v, self._pos = fn(
-                        self.params, self._k, self._v, self._pos, *args
+                    tok, self._k, self._v, self._pos, *self._state = fn(
+                        self.params, self._k, self._v, self._pos,
+                        *self._state, *args,
                     )
                 # publish the full-prompt pages for future prefix hits
                 # (first prefill wins; a matched chain is only touched)
@@ -1100,8 +1171,9 @@ class GenerationEngine:
         if self._paged:
             fn = self.programs.paged_decode(width)
             # gridlint: disable-next=GL202 — cache buffers are engine-thread-confined
-            toks, self._k, self._v, self._pos = fn(
-                self.params, self._k, self._v, self._pos, self._table(),
+            toks, self._k, self._v, self._pos, *self._state = fn(
+                self.params, self._k, self._v, self._pos, *self._state,
+                self._table(),
                 jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
             )
         else:
@@ -1162,8 +1234,9 @@ class GenerationEngine:
         t0 = time.perf_counter()
         fn = self.programs.paged_decode_fused(width, steps)
         # gridlint: disable-next=GL202 — cache buffers are engine-thread-confined
-        toks, self._k, self._v, self._pos = fn(
-            self.params, self._k, self._v, self._pos, self._table(),
+        toks, self._k, self._v, self._pos, *self._state = fn(
+            self.params, self._k, self._v, self._pos, *self._state,
+            self._table(),
             jnp.asarray(tokens), jnp.asarray(budget), jnp.asarray(temps),
             jnp.asarray(keys),
         )
@@ -1319,6 +1392,15 @@ class GenerationEngine:
                     "table": table,
                 },
             )
+        if self._recurrent:
+            # a live row's state is read and written once a step (rows
+            # the width computes beyond the live ones are not counted:
+            # they are padding, as in ``rowsteps``)
+            moved = len(live) * steps * self._state_per_slot
+            telemetry.incr_many(
+                "serving_state_bytes_total", "kind",
+                {"read": moved, "written": moved},
+            )
 
     def _kernel_pages(
         self, width: int, live: list[tuple[int, "_Row"]], steps: int
@@ -1400,7 +1482,7 @@ class GenerationEngine:
                         2, self._num_blocks - self._shrunk_blocks
                     )
                     self._shrunk_blocks = 0
-                cache = decode.init_paged_cache(
+                cache = self._family.init_paged_cache(
                     self.cfg, self.config.max_slots, self._num_blocks,
                     self._block, dtype=self._kv_dtype,
                 )
@@ -1423,7 +1505,8 @@ class GenerationEngine:
             if self._paged:
                 self._demand_pages = 0
             if cache is not None:
-                self._k, self._v, self._pos = cache.k, cache.v, cache.pos
+                # the whole cache: a recurrent family's state with it
+                self._k, self._v, self._pos, *self._state = cache
             if dcache is not None:
                 self._dk, self._dv = dcache.k, dcache.v
         if self._paged:
@@ -1438,7 +1521,10 @@ class GenerationEngine:
                 # gridlint: disable-next=GL202 — engine-thread-confined swap, requests already failed
                 self._pool = pagedkv.BlockPool(self._num_blocks)
                 # gridlint: disable-next=GL202 — engine-thread-confined swap, requests already failed
-                self._prefix = pagedkv.PrefixCache(self._pool, self._block)
+                self._prefix = pagedkv.PrefixCache(
+                    self._pool, self._block,
+                    shareable=not self._recurrent,
+                )
                 # chaos holds named the OLD pool; releasing those ids
                 # against the fresh allocator would be a refcount bug
                 # gridlint: disable-next=GL202 — engine-thread-confined swap, requests already failed

@@ -16,8 +16,9 @@ programs):
   pool reference per cached block; eviction is LRU leaf-first, so a
   block is never evicted while a cached descendant still needs it for
   matching, and never *freed* while any live request still reads it.
-- :class:`DeviceBudget` — ONE device-memory budget for KV cache across
-  every hosted model, partitioned by per-model admission weights
+- :class:`DeviceBudget` — ONE device-memory budget for serving caches
+  (KV blocks, and the fixed per-slot state of a recurrent family)
+  across every hosted model, partitioned by per-model admission weights
   (``PYGRID_KV_BUDGET`` / ``PYGRID_KV_WEIGHTS``). The ServingManager
   asks it for a model's block count at engine build time.
 
@@ -165,18 +166,33 @@ def parse_weights(raw: str | None) -> dict[str, float]:
 
 
 def block_bytes(cfg, block: int, dtype: Any, extra_layers: int = 0) -> int:
-    """Device bytes one KV block costs for ``cfg``: k AND v, all layers
-    — the unit the budget partitions. ``extra_layers`` adds the
-    speculative DRAFT's layers: the draft shares the pool's block ids
-    (same tables, its own k/v arrays), so a block's true device cost
-    when spec decode is on is target layers + draft layers."""
+    """Device bytes one KV block costs for ``cfg``: k AND v, every layer
+    that holds them (the family says which: a hybrid's state-space
+    layers hold none) — the unit the budget partitions. ``extra_layers``
+    adds the speculative DRAFT's layers: the draft shares the pool's
+    block ids (same tables, its own k/v arrays), so a block's true
+    device cost when spec decode is on is target layers + draft
+    layers."""
     import jax.numpy as jnp
 
+    from pygrid_tpu.models import decode
+
+    model = decode.family_of(cfg)
     dh = cfg.d_model // cfg.n_heads
     return int(
-        2 * (cfg.n_layers + extra_layers) * block * cfg.n_heads * dh
-        * jnp.dtype(dtype).itemsize
+        2 * (model.kv_layers(cfg) + extra_layers) * block
+        * model.kv_heads(cfg) * dh * jnp.dtype(dtype).itemsize
     )
+
+
+def state_bytes(cfg, slots: int, dtype: Any) -> int:
+    """Device bytes of the state ``cfg``'s family keeps per slot BESIDE
+    the block pool, for ``slots`` slots: fixed, not pageable, and
+    charged to the budget before any block is granted. 0 for a family
+    whose whole cache is the pool."""
+    from pygrid_tpu.models import decode
+
+    return int(slots) * decode.family_of(cfg).state_bytes_per_slot(cfg, dtype)
 
 
 class BlockPool:
@@ -314,14 +330,22 @@ class PrefixCache:
     still computes its first-token logits (and the continuation chunk is
     never empty)."""
 
-    def __init__(self, pool: BlockPool, block_tokens: int) -> None:
+    def __init__(
+        self, pool: BlockPool, block_tokens: int, shareable: bool = True
+    ) -> None:
         self._pool = pool
         self._block = int(block_tokens)
+        #: False for a model with recurrent state: a shared page would
+        #: need the state at its boundary, so nothing matches and
+        #: nothing is published (ROADMAP: snapshots at page boundaries)
+        self._shareable = bool(shareable)
         self._lock = threading.Lock()
         #: key -> node; insertion-ordered = LRU (move_to_end on touch)
         self._nodes: dict[Any, _PrefixNode] = {}
 
     def _shareable_pages(self, prompt_len: int) -> int:
+        if not self._shareable:
+            return 0
         return max(0, (int(prompt_len) - 1) // self._block)
 
     def probe(self, prompt: np.ndarray) -> int:
@@ -502,22 +526,30 @@ class DeviceBudget:
         denom = sum(self.weight_of(m) for m in members)
         return int(self.total_bytes * self.weight_of(model_id) / denom)
 
-    def blocks_for(self, model_id: str, bytes_per_block: int) -> int | None:
+    def blocks_for(
+        self, model_id: str, bytes_per_block: int, fixed_bytes: int = 0
+    ) -> int | None:
         """The block count ``model_id``'s engine should allocate, or
         None when no budget is configured (engine falls back to
-        contiguous-parity sizing). Always grants at least one block
-        beyond trash so a registered model can serve SOMETHING."""
+        contiguous-parity sizing). ``fixed_bytes`` is what the model
+        holds per slot beside the pool (a recurrent state): it comes
+        out of the model's share FIRST, and blocks are granted from the
+        rest. Always grants at least one block beyond trash so a
+        registered model can serve SOMETHING."""
         if self.total_bytes is None or bytes_per_block <= 0:
             return None
+        fixed_bytes = max(0, int(fixed_bytes))
         with self._lock:
             live = dict(self._allocated)
             live.pop(model_id, None)
             self._allocated.pop(model_id, None)
             share = self._fair_share_locked(model_id)
             remaining = self.total_bytes - sum(live.values())
-            grant = max(min(share, remaining), 2 * bytes_per_block)
+            grant = max(
+                min(share, remaining) - fixed_bytes, 2 * bytes_per_block
+            )
             blocks = max(2, grant // bytes_per_block)
-            self._allocated[model_id] = blocks * bytes_per_block
+            self._allocated[model_id] = blocks * bytes_per_block + fixed_bytes
             return int(blocks)
 
     def overage(self, model_id: str, joining: str | None = None) -> int:

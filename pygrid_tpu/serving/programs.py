@@ -69,6 +69,16 @@ class ProgramSet:
         #: truncated-layer draft config for the speculative programs
         #: (None: spec_prefill/spec_verify are unavailable)
         self.draft_cfg = draft_cfg
+        from pygrid_tpu.models import decode
+
+        #: the module that serves this config's family: the paged
+        #: programs reach the model only through it
+        self._family = decode.family_of(cfg)
+        #: its paged cache: ``k, v, pos`` and whatever state the family
+        #: keeps beside them, all donated (argument 0 is ``params``)
+        self._cache_of = self._family.PagedCache._make
+        self._cache_arrays = len(self._family.PagedCache._fields)
+        self._donated = tuple(range(1, 1 + self._cache_arrays))
         self._prefill: dict[int, Callable] = {}
         self._decode: dict[int, Callable] = {}
         self._paged_prefill: dict[int, Callable] = {}
@@ -187,29 +197,28 @@ class ProgramSet:
         """``fn(params, k, v, pos, table, slot, chunk[bucket], start,
         length, temp, key) -> (first_token, k, v, pos)`` — admission of
         one request through its block table, continuing after a shared
-        prefix of ``start`` tokens; first token picked on-device."""
+        prefix of ``start`` tokens; first token picked on-device. A
+        family whose cache has more arrays than ``k, v, pos`` (a
+        recurrent state) takes and returns them after ``pos``, donated
+        like the rest: so for every paged program below."""
         fn = self._paged_prefill.get(bucket)
         if fn is None:
             import jax
 
-            from pygrid_tpu.models import decode
+            model, cfg, cd = self._family, self.cfg, self.compute_dtype
+            cache_of, n = self._cache_of, self._cache_arrays
 
-            cfg, cd = self.cfg, self.compute_dtype
-
-            def _paged_prefill(
-                params, k, v, pos, table, slot, chunk, start, length,
-                temp, key,
-            ):
-                cache = decode.PagedKVCache(k=k, v=v, pos=pos)
-                logits, cache = decode.paged_prefill_chunk(
-                    params, cache, table, slot, chunk, start, length,
-                    cfg, cd,
+            def _paged_prefill(params, *args):
+                table, slot, chunk, start, length, temp, key = args[n:]
+                logits, cache = model.paged_prefill_chunk(
+                    params, cache_of(args[:n]), table, slot, chunk, start,
+                    length, cfg, cd,
                 )
                 tok = self._pick(logits, temp, key)
-                return tok, cache.k, cache.v, cache.pos
+                return (tok, *cache)
 
             fn = telemetry.profiler.wrap(
-                jax.jit(_paged_prefill, donate_argnums=(1, 2, 3)),
+                jax.jit(_paged_prefill, donate_argnums=self._donated),
                 kind="paged_prefill", bucket=bucket,
                 model_id=self.model_id,
             )
@@ -241,33 +250,33 @@ class ProgramSet:
             import jax.numpy as jnp
             from jax import lax
 
-            from pygrid_tpu.models import decode
+            model, cfg, cd = self._family, self.cfg, self.compute_dtype
+            cache_of, n = self._cache_of, self._cache_arrays
 
-            cfg, cd = self.cfg, self.compute_dtype
+            def _fused(params, *args):
+                table, tokens, budget, temps, keys = args[n:]
 
-            def _fused(params, k, v, pos, table, tokens, budget, temps, keys):
                 def body(carry, step_keys):
-                    kk, vv, pp, tok, remaining = carry
-                    cache = decode.PagedKVCache(k=kk, v=vv, pos=pp)
+                    *arrays, tok, remaining = carry
                     alive = remaining > 0
-                    logits, cache = decode.paged_decode_step(
-                        params, cache, table, tok, cfg, cd, active=alive
+                    logits, cache = model.paged_decode_step(
+                        params, cache_of(arrays), table, tok, cfg, cd,
+                        active=alive,
                     )
                     picked = jax.vmap(self._pick)(logits, temps, step_keys)
                     nxt = jnp.where(alive, picked, tok)
                     carry = (
-                        cache.k, cache.v, cache.pos, nxt,
-                        remaining - alive.astype(jnp.int32),
+                        *cache, nxt, remaining - alive.astype(jnp.int32),
                     )
                     return carry, nxt
 
-                (kk, vv, pp, _, _), emitted = lax.scan(
-                    body, (k, v, pos, tokens, budget), keys
+                (*arrays, _, _), emitted = lax.scan(
+                    body, (*args[:n], tokens, budget), keys
                 )
-                return emitted, kk, vv, pp
+                return (emitted, *arrays)
 
             fn = telemetry.profiler.wrap(
-                jax.jit(_fused, donate_argnums=(1, 2, 3)),
+                jax.jit(_fused, donate_argnums=self._donated),
                 kind="paged_decode_fused", bucket=width,
                 model_id=self.model_id,
             )
@@ -283,20 +292,19 @@ class ProgramSet:
         if fn is None:
             import jax
 
-            from pygrid_tpu.models import decode
+            model, cfg, cd = self._family, self.cfg, self.compute_dtype
+            cache_of, n = self._cache_of, self._cache_arrays
 
-            cfg, cd = self.cfg, self.compute_dtype
-
-            def _paged_decode_step(params, k, v, pos, table, tokens, temps, keys):
-                cache = decode.PagedKVCache(k=k, v=v, pos=pos)
-                logits, cache = decode.paged_decode_step(
-                    params, cache, table, tokens, cfg, cd
+            def _paged_decode_step(params, *args):
+                table, tokens, temps, keys = args[n:]
+                logits, cache = model.paged_decode_step(
+                    params, cache_of(args[:n]), table, tokens, cfg, cd
                 )
                 toks = jax.vmap(self._pick)(logits, temps, keys)
-                return toks, cache.k, cache.v, cache.pos
+                return (toks, *cache)
 
             fn = telemetry.profiler.wrap(
-                jax.jit(_paged_decode_step, donate_argnums=(1, 2, 3)),
+                jax.jit(_paged_decode_step, donate_argnums=self._donated),
                 kind="paged_decode", bucket=width,
                 model_id=self.model_id,
             )
