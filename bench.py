@@ -1391,533 +1391,6 @@ def bench_fed_transformer_long() -> dict:
     return out
 
 
-def bench_decode() -> dict:
-    """Serving-side decode: KV-cache greedy generation on the flagship
-    transformer config (models/decode.py), one jitted program for
-    prefill + the whole decode scan. Latency-bound at small batch (the
-    per-step cost is the cache/param read, not FLOPs) — reported as
-    tokens/sec + ms/token, not MFU."""
-    import jax
-
-    from pygrid_tpu.models import decode, transformer
-
-    cfg = transformer.TransformerConfig(
-        vocab=8192, d_model=512, n_heads=4, n_layers=4, d_ff=2048,
-        max_len=512,
-    )
-    params = transformer.init(jax.random.PRNGKey(0), cfg)
-    B, P, N = 8, 32, 256
-    prompt = jax.random.randint(
-        jax.random.PRNGKey(1), (B, P), 0, cfg.vocab
-    )
-    fn = jax.jit(
-        lambda p, x: decode.generate(
-            p, x, N, cfg, compute_dtype="bfloat16"
-        )
-    )
-    out = fn(params, prompt)
-    _ = int(out[0, 0])  # compile + host fetch (waits for the scan)
-    times = []
-    for _ in range(4):
-        t0 = time.perf_counter()
-        out = fn(params, prompt)
-        _ = int(out[0, 0])
-        times.append(time.perf_counter() - t0)
-    dt = min(times)
-    tok_s = B * N / dt
-    print(
-        f"decode[{cfg.n_layers}L d{cfg.d_model} bf16 KV-cache]: {B} seqs "
-        f"× {N} tokens in {dt*1e3:.1f} ms — {tok_s:,.0f} tokens/sec "
-        f"({dt/N*1e3:.3f} ms/step)",
-        file=sys.stderr,
-    )
-    return {
-        "decode_tokens_per_sec": round(tok_s, 0),
-        "decode_ms_per_step": round(dt / N * 1e3, 3),
-    }
-
-
-def bench_serving(tiny: bool = False) -> dict:
-    """Continuous-batching generation engine vs. the legacy per-request
-    path, at 8 concurrent requests with DISTINCT ``n_new`` and prompt
-    lengths (all within one engine bucket) — the traffic shape a serving
-    node actually sees.
-
-    The baseline is what the node did before pygrid_tpu/serving: one
-    whole-generation XLA program jitted per distinct ``n_new``, requests
-    served one after another. Its timing INCLUDES those compiles because
-    they recur for every new (n_new, prompt-length) a client sends —
-    that is the pathology, not a warmup artifact. The engine's fixed
-    bucket set is compiled once in warmup (excluded: it is paid once per
-    hosted model, amortized over all future traffic) and the capture
-    asserts ZERO recompiles while the 8 mixed requests run. A warm
-    baseline (compiles pre-paid) is reported alongside for the
-    steady-state comparison. Outputs are asserted bit-identical between
-    the two paths before any throughput is reported."""
-    import threading
-
-    import jax
-    import numpy as np
-
-    from pygrid_tpu.models import decode, transformer
-    from pygrid_tpu.serving import EngineConfig, GenerationEngine
-
-    if tiny:
-        cfg = transformer.TransformerConfig(
-            vocab=127, d_model=32, n_heads=2, n_layers=2, d_ff=64,
-            max_len=64,
-        )
-        base_new = 6
-    else:
-        cfg = transformer.TransformerConfig(
-            vocab=8192, d_model=512, n_heads=4, n_layers=4, d_ff=2048,
-            max_len=512,
-        )
-        base_new = 48
-    params = transformer.init(jax.random.PRNGKey(0), cfg)
-    rng = np.random.RandomState(7)
-    n_requests = 8
-    cases = [
-        (
-            rng.randint(
-                0, cfg.vocab, size=(1, int(rng.randint(2, 10)))
-            ).astype(np.int32),
-            base_new + i,  # every request a distinct n_new
-        )
-        for i in range(n_requests)
-    ]
-    total_tokens = sum(n for _, n in cases)
-
-    # ── baseline: sequential per-request programs (the pre-engine node
-    # path), one compile per distinct n_new ─────────────────────────────
-    def _baseline_fns():
-        return [
-            jax.jit(lambda p, x, n=n_new: decode.generate(p, x, n, cfg))
-            for _, n_new in cases
-        ]
-
-    fns = _baseline_fns()
-    t0 = time.perf_counter()
-    baseline_out = []
-    for fn, (prompt, _) in zip(fns, cases):
-        toks = np.asarray(fn(params, prompt))  # np.asarray = true sync
-        baseline_out.append(toks)
-    baseline_s = time.perf_counter() - t0
-
-    # warm steady state: same programs, compiles already paid
-    t0 = time.perf_counter()
-    for fn, (prompt, _) in zip(fns, cases):
-        np.asarray(fn(params, prompt))
-    baseline_warm_s = time.perf_counter() - t0
-
-    # ── engine: 8 requests in flight at once, fixed program set ─────────
-    import jax.numpy as jnp
-
-    engine = GenerationEngine(
-        cfg, params,
-        # f32 cache pinned: the engine default is bf16 on TPU, but the
-        # per-request baseline above decodes with generate()'s f32
-        # cache — the equal-outputs assert must compare like for like
-        EngineConfig(max_slots=8, cache_dtype=jnp.float32),
-        model_id="bench",
-    )
-    try:
-        engine.warmup(prompt_lens=(max(p.shape[1] for p, _ in cases),))
-        compiles_before = engine.compile_count()
-        engine_out: list = [None] * n_requests
-
-        def _go(i):
-            prompt, n_new = cases[i]
-            engine_out[i] = engine.submit(prompt, n_new, timeout=600)
-
-        threads = [
-            threading.Thread(target=_go, args=(i,))
-            for i in range(n_requests)
-        ]
-        t0 = time.perf_counter()
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        engine_s = time.perf_counter() - t0
-        recompiles = engine.compile_count() - compiles_before
-        # the tentpole contracts: equal outputs, zero recompiles while
-        # n_new / prompt length vary within one bucket
-        assert recompiles == 0, f"{recompiles} recompiles under traffic"
-        for got, expect in zip(engine_out, baseline_out):
-            assert np.array_equal(got, expect), "engine != per-request"
-    finally:
-        engine.close()
-
-    out = {
-        "serving_requests": n_requests,
-        "serving_total_tokens": total_tokens,
-        "serving_engine_s": round(engine_s, 3),
-        "serving_baseline_s": round(baseline_s, 3),
-        "serving_baseline_warm_s": round(baseline_warm_s, 3),
-        "serving_engine_tokens_per_sec": round(total_tokens / engine_s, 1),
-        "serving_baseline_tokens_per_sec": round(
-            total_tokens / baseline_s, 1
-        ),
-        "serving_baseline_warm_tokens_per_sec": round(
-            total_tokens / baseline_warm_s, 1
-        ),
-        "serving_throughput_ratio": round(baseline_s / engine_s, 2),
-        "serving_throughput_ratio_warm": round(
-            baseline_warm_s / engine_s, 2
-        ),
-        "serving_engine_compiled_programs": compiles_before,
-        "serving_engine_recompiles_under_traffic": recompiles,
-        "serving_baseline_programs_compiled": len(
-            {n for _, n in cases}
-        ),
-    }
-    print(
-        f"serving[{cfg.n_layers}L d{cfg.d_model}]: {n_requests} concurrent "
-        f"mixed requests, {total_tokens} tokens — engine {engine_s:.2f}s "
-        f"({out['serving_engine_tokens_per_sec']:,.0f} tok/s, "
-        f"{compiles_before} programs, 0 recompiles) vs per-request "
-        f"{baseline_s:.2f}s incl. {len({n for _, n in cases})} compiles "
-        f"({out['serving_throughput_ratio']}x), warm "
-        f"{baseline_warm_s:.2f}s ({out['serving_throughput_ratio_warm']}x)",
-        file=sys.stderr,
-    )
-    return out
-
-
-def bench_serving_paged(tiny: bool = False) -> dict:
-    """Paged KV mode: concurrent-request capacity per GB of cache and
-    prefix-hit prefill savings vs the contiguous-slot baseline, at
-    EQUAL BYTE BUDGETS and equal (bit-identical greedy) outputs.
-
-    The pathology the paged cache removes: a contiguous slot pins
-    ``max_len`` tokens of k/v regardless of the request, so a node's
-    concurrent-request capacity per GB is ``1 / max_len`` rows per
-    token of cache no matter how short the traffic. The paged engine
-    holds only the pages covering prompt + n_new (block-table storage,
-    docs/SERVING.md), so the same bytes serve
-    ``max_len / (pages_per_request × block)`` × more concurrent
-    requests — measured here by DRIVING both engines with the same
-    short-request workload at the same cache bytes and asserting every
-    output equals single-request ``generate()``. The prefix phase then
-    shows shared-prefix prefill savings: N requests with one common
-    system prompt, the engine's prefix-hit counters proving all but the
-    first skipped the shared pages' prefill work. Zero recompiles under
-    shape AND prefix variety is asserted across the whole run."""
-    import threading
-
-    import jax
-    import numpy as np
-
-    from pygrid_tpu.models import decode, transformer
-    from pygrid_tpu.serving import EngineConfig, GenerationEngine
-
-    if tiny:
-        cfg = transformer.TransformerConfig(
-            vocab=127, d_model=32, n_heads=2, n_layers=2, d_ff=64,
-            max_len=64,
-        )
-        block = 16
-        contig_slots = 4
-        sys_prompt_pages = 2
-        n_prefix = 8
-    else:
-        cfg = transformer.TransformerConfig(
-            vocab=8192, d_model=512, n_heads=4, n_layers=4, d_ff=2048,
-            max_len=512,
-        )
-        block = 64
-        contig_slots = 8
-        sys_prompt_pages = 4
-        n_prefix = 16
-    params = transformer.init(jax.random.PRNGKey(0), cfg)
-    import jax.numpy as jnp
-
-    from pygrid_tpu.serving import pagedkv
-
-    kv_dtype = jnp.float32
-    # equal byte budgets: the contiguous baseline's S × max_len token
-    # slab, re-cut into `block`-token pages for the paged pool
-    cache_tokens = contig_slots * cfg.max_len
-    num_blocks = cache_tokens // block  # usable pages at byte parity
-    cache_bytes = cache_tokens * pagedkv.block_bytes(cfg, 1, kv_dtype)
-    paged_slots = num_blocks  # slots are ~free; blocks are the budget
-    rng = np.random.RandomState(11)
-
-    # the workload: every request fits one page (prompt + n_new ≤ block)
-    # with DISTINCT prompt lengths and n_new inside one bucket
-    cases = []
-    for i in range(paged_slots):
-        p_len = 4 + i % 5
-        n_new = block - p_len
-        prompt = rng.randint(0, cfg.vocab, size=(1, p_len)).astype(np.int32)
-        cases.append((prompt, n_new))
-    refs = [
-        np.asarray(decode.generate(params, p, n, cfg)) for p, n in cases
-    ]
-
-    def _drive(engine, cases):
-        outs: list = [None] * len(cases)
-
-        def _go(i):
-            prompt, n_new = cases[i]
-            outs[i] = engine.submit(prompt, n_new, timeout=600)
-
-        threads = [
-            threading.Thread(target=_go, args=(i,))
-            for i in range(len(cases))
-        ]
-        t0 = time.perf_counter()
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        return outs, time.perf_counter() - t0
-
-    # ── contiguous-slot baseline at the same cache bytes ────────────────
-    # cache dtype pinned to f32 on BOTH engines: the engine default is
-    # backend-dependent (bf16 on TPU) while the generate() references
-    # below default to f32 — the bit-identity asserts must compare like
-    # for like on every backend (capacity/GB is dtype-orthogonal)
-    contig = GenerationEngine(
-        cfg, params,
-        EngineConfig(
-            max_slots=contig_slots, paged=False, cache_dtype=kv_dtype
-        ),
-        model_id="bench-contig",
-    )
-    try:
-        contig.warmup(prompt_lens=(8,))
-        contig_out, contig_s = _drive(contig, cases)
-        for got, ref in zip(contig_out, refs):
-            assert np.array_equal(got, ref), "contiguous != generate()"
-    finally:
-        contig.close()
-
-    # ── paged engine: same bytes, block-table storage ───────────────────
-    widths = tuple(sorted({1, 4, 8, paged_slots}))
-    sys_prompt = rng.randint(
-        0, cfg.vocab, size=sys_prompt_pages * block
-    ).astype(np.int32)
-    prefix_cases = []
-    for i in range(n_prefix):
-        suffix = rng.randint(0, cfg.vocab, size=4).astype(np.int32)
-        prefix_cases.append(
-            (np.concatenate([sys_prompt, suffix])[None, :], 6)
-        )
-    prefix_refs = [
-        np.asarray(decode.generate(params, p, n, cfg))
-        for p, n in prefix_cases
-    ]
-    engine = GenerationEngine(
-        cfg, params,
-        EngineConfig(
-            max_slots=paged_slots, slot_buckets=widths, paged=True,
-            block_size=block, num_blocks=num_blocks + 1,  # +1 = trash
-            max_queue=4 * paged_slots, cache_dtype=kv_dtype,
-        ),
-        model_id="bench-paged",
-    )
-    try:
-        # warm every bucket the run touches: the short prompts, the
-        # full system prompt chunk, and the post-hit suffix chunk
-        engine.warmup(
-            prompt_lens=(8, len(sys_prompt) + 4, 4 + 1)
-        )
-        compiles_before = engine.compile_count()
-
-        paged_out, paged_s = _drive(engine, cases)
-        for got, ref in zip(paged_out, refs):
-            assert np.array_equal(got, ref), "paged != generate()"
-
-        # ── shared-prefix phase: first request prefills + publishes,
-        # the rest map the system prompt's pages copy-on-write ─────────
-        first = engine.submit(*prefix_cases[0], timeout=600)
-        assert np.array_equal(first, prefix_refs[0])
-        rest_out, _ = _drive(engine, prefix_cases[1:])
-        for got, ref in zip(rest_out, prefix_refs[1:]):
-            assert np.array_equal(got, ref), "prefix-hit != generate()"
-        recompiles = engine.compile_count() - compiles_before
-        assert recompiles == 0, f"{recompiles} recompiles under traffic"
-        stats = engine.stats()
-        assert stats["prefix_hits"] >= n_prefix - 1, stats
-        saved_tokens = stats["prefix_tokens_saved"]
-        assert saved_tokens >= (n_prefix - 1) * len(sys_prompt), stats
-    finally:
-        engine.close()
-
-    # capacity: concurrent requests resident per GB of KV cache. The
-    # contiguous engine can hold at most its slot count regardless of
-    # request size; the paged engine is bounded by blocks — and the run
-    # above really did serve that many concurrently, bit-identically.
-    contig_capacity = contig_slots
-    paged_capacity = num_blocks  # 1 page/request workload, all resident
-    gb = cache_bytes / (1 << 30)
-    ratio = paged_capacity / contig_capacity
-    prefill_tokens_total = sum(
-        p.shape[1] for p, _ in prefix_cases
-    )
-    out = {
-        "paged_block_tokens": block,
-        "paged_cache_bytes": cache_bytes,
-        "paged_capacity_requests": paged_capacity,
-        "contig_capacity_requests": contig_capacity,
-        "paged_requests_per_gb": round(paged_capacity / gb, 1),
-        "contig_requests_per_gb": round(contig_capacity / gb, 1),
-        "paged_capacity_ratio": round(ratio, 2),
-        "paged_workload_s": round(paged_s, 3),
-        "contig_workload_s": round(contig_s, 3),
-        "paged_recompiles_under_traffic": recompiles,
-        "paged_prefix_hits": stats["prefix_hits"],
-        "paged_prefix_tokens_saved": saved_tokens,
-        "paged_prefix_prefill_saved_pct": round(
-            100.0 * saved_tokens / prefill_tokens_total, 1
-        ),
-    }
-    print(
-        f"serving-paged[{cfg.n_layers}L d{cfg.d_model}]: "
-        f"{paged_capacity} concurrent requests resident vs "
-        f"{contig_capacity} contiguous at equal {cache_bytes >> 20} MiB "
-        f"cache ({ratio:.1f}x capacity/GB), outputs bit-identical, "
-        f"0 recompiles; shared-prefix: {stats['prefix_hits']} hits, "
-        f"{saved_tokens} prompt tokens not re-prefilled "
-        f"({out['paged_prefix_prefill_saved_pct']}% of prefix-phase "
-        "prefill)",
-        file=sys.stderr,
-    )
-    return out
-
-
-def bench_serving_fused(tiny: bool = False) -> dict:
-    """Fused multi-step + self-speculative decode vs the WARM per-step
-    engine (the PR-7 steady state), at equal (bit-identical greedy)
-    outputs — ROADMAP headline #4's metric: steady-state
-    tokens/sec/slot.
-
-    The pathology fused decode removes: every decode token costs one
-    host→device dispatch, so on small/medium models the hot loop is
-    dominated by Python/XLA launch overhead rather than FLOPs
-    (bench_serving's warm baseline). The fused engine runs a whole
-    quantum of steps as one ``lax.scan`` program; the measurement
-    below holds everything else constant — same model, same paged
-    cache, same slot shape, same requests, warm programs on both
-    sides — and flips ONLY ``EngineConfig.fused``.
-
-    The speculative section is reported SEPARATELY and honestly: a
-    truncated-layer draft of this random-weights bench checkpoint
-    proposes poorly (acceptance rate is printed), so its net ratio is
-    a floor for real checkpoints, not a claim — ``spec_net_speedup``
-    is only flagged True when the measured ratio clears 1.0."""
-    import threading
-
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from pygrid_tpu.models import decode, transformer
-    from pygrid_tpu.serving import EngineConfig, GenerationEngine
-
-    if tiny:
-        cfg = transformer.TransformerConfig(
-            vocab=127, d_model=32, n_heads=2, n_layers=2, d_ff=64,
-            max_len=64,
-        )
-        slots, p_len, n_new = 4, 4, 48
-    else:
-        cfg = transformer.TransformerConfig(
-            vocab=8192, d_model=512, n_heads=4, n_layers=4, d_ff=2048,
-            max_len=512,
-        )
-        slots, p_len, n_new = 8, 8, 192
-    params = transformer.init(jax.random.PRNGKey(0), cfg)
-    rng = np.random.RandomState(3)
-    prompts = [
-        rng.randint(0, cfg.vocab, size=(1, p_len)).astype(np.int32)
-        for _ in range(slots)
-    ]
-    refs = [
-        np.asarray(decode.generate(params, p, n_new, cfg))
-        for p in prompts
-    ]
-
-    def _drive(engine):
-        outs: list = [None] * slots
-
-        def _go(i):
-            outs[i] = engine.submit(prompts[i], n_new, timeout=600)
-
-        threads = [
-            threading.Thread(target=_go, args=(i,)) for i in range(slots)
-        ]
-        t0 = time.perf_counter()
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        return outs, time.perf_counter() - t0
-
-    def _measure(label, **flags):
-        engine = GenerationEngine(
-            cfg, params,
-            EngineConfig(
-                max_slots=slots, slot_buckets=(1, 4, slots),
-                min_prompt_bucket=8, cache_dtype=jnp.float32, **flags,
-            ),
-            model_id=f"bench-{label}",
-        )
-        try:
-            engine.warmup(prompt_lens=(p_len,))
-            _drive(engine)  # warm pass: steady state, compiles paid
-            compiles_before = engine.compile_count()
-            outs, dt = _drive(engine)
-            recompiles = engine.compile_count() - compiles_before
-            assert recompiles == 0, f"{label}: {recompiles} recompiles"
-            for got, ref in zip(outs, refs):
-                assert np.array_equal(got, ref), f"{label} != generate()"
-            return dt, engine.stats()
-        finally:
-            engine.close()
-
-    base_s, _ = _measure("perstep", fused=False, spec_decode=False)
-    fused_s, fused_stats = _measure("fused", fused=True, spec_decode=False)
-    spec_s, spec_stats = _measure("spec", spec_decode=True, spec_k=4)
-
-    per_slot = lambda dt: slots * n_new / dt / slots  # noqa: E731
-    fused_ratio = base_s / fused_s
-    spec_ratio = base_s / spec_s
-    acceptance = spec_stats.get("spec_acceptance") or 0.0
-    out = {
-        "fused_slots": slots,
-        "fused_tokens_per_request": n_new,
-        "fused_baseline_tok_s_slot": round(per_slot(base_s), 1),
-        "fused_tok_s_slot": round(per_slot(fused_s), 1),
-        "fused_ratio": round(fused_ratio, 2),
-        "fused_wasted_steps": fused_stats.get("fused_wasted_steps", 0),
-        "spec_tok_s_slot": round(per_slot(spec_s), 1),
-        "spec_ratio": round(spec_ratio, 2),
-        "spec_acceptance_rate": round(acceptance, 3),
-        "spec_draft_layers": spec_stats.get("spec_draft_layers"),
-        # the HONEST claim bit: speculative decode only advertises a
-        # net win when this run measured one (a random-init bench
-        # checkpoint drafts badly — real checkpoints decide per model
-        # via the same serving_spec_* telemetry)
-        "spec_net_speedup": bool(spec_ratio > 1.0),
-    }
-    print(
-        f"serving-fused[{cfg.n_layers}L d{cfg.d_model}]: {slots} slots × "
-        f"{n_new} tokens warm — per-step "
-        f"{out['fused_baseline_tok_s_slot']:,.0f} tok/s/slot, fused "
-        f"{out['fused_tok_s_slot']:,.0f} ({out['fused_ratio']}x, "
-        f"{out['fused_wasted_steps']} wasted steps), speculative "
-        f"{out['spec_tok_s_slot']:,.0f} ({out['spec_ratio']}x at "
-        f"{out['spec_acceptance_rate']:.0%} acceptance, "
-        f"{out['spec_draft_layers']}-layer draft"
-        f"{', net win' if out['spec_net_speedup'] else ', drafting loses here'})",
-        file=sys.stderr,
-    )
-    return out
-
-
 def bench_data_centric() -> dict:
     """Data-centric plane measured (SURVEY §6 row 3) in a CPU-pinned
     SUBPROCESS. Two reasons: this process holds the chip, and a chip
@@ -2562,9 +2035,6 @@ def main() -> None:
     kernel = bench_tpu()
     proto.update(bench_wire())
     proto.update(bench_telemetry_overhead())
-    proto.update(bench_serving())
-    proto.update(bench_serving_paged())
-    proto.update(bench_serving_fused())
     proto.update(bench_protocol("json"))
     proto.update(bench_protocol("binary"))
     proto.update(bench_protocol_hier())
@@ -2575,7 +2045,6 @@ def main() -> None:
     proto.update(bench_attention_train())
     proto.update(bench_fed_transformer())
     proto.update(bench_fed_transformer_long())
-    proto.update(bench_decode())
     cpu_rps = bench_cpu_torch_baseline()
     # headline = the fastest of the identical-output kernel shapes
     # (identities asserted in test_fedavg_sim.py / test_fedavg_fused.py)

@@ -245,8 +245,7 @@ def serve_phase(cfg, params, node_url: str, platform: str,
     _check(row["platform"] == platform,
            f"the node's engine runs on platform {row['platform']!r} "
            f"({row['device_kind']!r}), need {platform!r}")
-    _check(row["paged"] is True and row["fused"] is True,
-           f"engine paged={row['paged']} fused={row['fused']}")
+    _check(row["fused"] is True, f"engine fused={row['fused']}")
     _check(row["fused_scans"] > 0, "no fused decode scan ran")
     _check(row["prefix_hits"] > 0, "no prefix-cache hit")
     _check(row["queue_depth"] == 0 and row["live_slots"] == 0,

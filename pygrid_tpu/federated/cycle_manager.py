@@ -229,6 +229,13 @@ class CycleManager:
             self._schedule_deadline(cycle.id, cycle_time)
         return cycle
 
+    def _completion_key(self, cycle_id: int) -> str:
+        """The ``run_task_once`` key of a cycle's readiness check. It
+        names this manager as well as the cycle: every database numbers
+        its cycles from 1, and a check coalesced into ANOTHER manager's
+        in-flight run of the same number would be lost or run late."""
+        return f"complete_cycle_{id(self):x}_{cycle_id}"
+
     def _schedule_deadline(self, cycle_id: int, delay_s: float) -> None:
         """Fire a readiness check at ``cycle.end`` so straggler-drop happens
         on time even if no further report ever arrives. The reference only
@@ -239,7 +246,7 @@ class CycleManager:
         def _fire() -> None:
             self._deadline_timers.pop(cycle_id, None)
             tasks.run_task_once(
-                f"complete_cycle_{cycle_id}", self.complete_cycle, cycle_id
+                self._completion_key(cycle_id), self.complete_cycle, cycle_id
             )
 
         timer = threading.Timer(max(delay_s, 0.0) + 0.05, _fire)
@@ -462,7 +469,7 @@ class CycleManager:
             )
             self._note_report(cycle, wc, diff, wire_codec)
             tasks.run_task_once(
-                f"complete_cycle_{cycle.id}", self.complete_cycle, cycle.id
+                self._completion_key(cycle.id), self.complete_cycle, cycle.id
             )
             return
         # decode BEFORE storing: a malformed blob must bounce back to the
@@ -539,7 +546,9 @@ class CycleManager:
                 # drop the orphaned entry or it leaks per raced cycle
                 with self._accum_lock:
                     self._accum.pop(cycle.id, None)
-        tasks.run_task_once(f"complete_cycle_{cycle.id}", self.complete_cycle, cycle.id)
+        tasks.run_task_once(
+            self._completion_key(cycle.id), self.complete_cycle, cycle.id
+        )
 
     # --- hierarchical (sub-aggregated) reports ------------------------------
 
@@ -707,7 +716,7 @@ class CycleManager:
             )
             self._note_partial(cycle, wcs, diff, wire_codec, count, t0)
             tasks.run_task_once(
-                f"complete_cycle_{cycle.id}", self.complete_cycle, cycle.id
+                self._completion_key(cycle.id), self.complete_cycle, cycle.id
             )
             return
         raws = state_raw_tensors(diff)
@@ -741,7 +750,7 @@ class CycleManager:
             with self._accum_lock:
                 self._accum.pop(cycle.id, None)
         tasks.run_task_once(
-            f"complete_cycle_{cycle.id}", self.complete_cycle, cycle.id
+            self._completion_key(cycle.id), self.complete_cycle, cycle.id
         )
 
     def _submit_async_partial(
@@ -787,7 +796,7 @@ class CycleManager:
             acc = self._async_accum.setdefault(pid, _DiffAccumulator())
             acc.add_partial_raw(raws, count, ws, scale=scale)
         tasks.run_task_once(
-            f"complete_cycle_{open_cycle.id}", self.complete_cycle,
+            self._completion_key(open_cycle.id), self.complete_cycle,
             open_cycle.id,
         )
 
@@ -1074,7 +1083,7 @@ class CycleManager:
             acc = self._async_accum.setdefault(pid, _DiffAccumulator())
             acc.add(decoded, weight)
         tasks.run_task_once(
-            f"complete_cycle_{open_cycle.id}", self.complete_cycle,
+            self._completion_key(open_cycle.id), self.complete_cycle,
             open_cycle.id,
         )
 

@@ -6,10 +6,16 @@ a static-shape KV cache, a dense single-pass ``prefill``, and a
 ``lax.scan``-driven decode loop so a whole ``generate`` call is ONE
 compiled XLA program (no per-token Python dispatch, no dynamic shapes —
 the cache is allocated at ``max_len`` and masked by position, the idiom
-XLA/TPU wants). The ``SlotKVCache`` family below is the continuous-
-batching variant the serving engine (:mod:`pygrid_tpu.serving`) drives:
-one shared cache of request slots, per-slot positions, per-slot masked
-attention.
+XLA/TPU wants).
+
+Two cache layouts live here and no third. ``KVCache`` with ``prefill``,
+``decode_step`` and ``generate`` is the **reference**: one request (or
+one lock-step batch) over a contiguous ``[max_len]`` cache, what the
+tests, the chip smoke and the examples compare the engine against.
+``PagedKVCache`` with ``paged_prefill_chunk`` and ``paged_decode_step``
+is the **served** layout: a block pool shared by independent requests,
+driven by :mod:`pygrid_tpu.serving` and by nothing else. The node
+generates only through the second.
 
 No reference analog: the reference's inference surface is data-centric
 ``run_inference`` over MLP/CNN plans (SURVEY §2.1); autoregressive
@@ -87,7 +93,8 @@ def from_bundle(spec: dict) -> tuple[Any, Any]:
 # first three fields are ``k, v, pos``, and the facts below. This module
 # is the transformer's; :mod:`pygrid_tpu.models.jamba` the hybrid's.
 
-#: no recurrent state: prefix pages can be shared, a draft can be rolled back
+#: no recurrent state, so prefix pages can be shared: that sharing is the
+#: one thing a family switches off by saying True here
 RECURRENT = False
 
 
@@ -303,195 +310,12 @@ def prefill(
     return logits, KVCache(k=new_k, v=new_v, pos=t0 + P)
 
 
-# ── slot-structured shared cache (continuous-batching serving) ───────────────
-#
-# The serving engine (pygrid_tpu.serving) keeps ONE persistent cache of S
-# request slots per hosted model and advances every live slot with a single
-# jitted program per step. Requests join a free slot (per-slot prefill),
-# decode together at their own positions, and leave between steps — so the
-# compiled programs are keyed only by (config, slot-width bucket, prompt
-# bucket), never by a request's prompt length or n_new.
-
-
-class SlotKVCache(NamedTuple):
-    """Per-slot key/value cache shared by independent requests.
-
-    ``k``/``v``: [n_layers, S, max_len, n_heads, head_dim]; ``pos``: [S]
-    int32, each slot's count of valid rows. Unlike :class:`KVCache` the
-    "batch" axis carries *unrelated* sequences at *different* positions;
-    every read is masked per slot, so no slot can see another's rows.
-    """
-
-    k: jax.Array
-    v: jax.Array
-    pos: jax.Array
-
-
-def init_slot_cache(
-    cfg: TransformerConfig,
-    slots: int,
-    dtype: Any = jnp.float32,
-) -> SlotKVCache:
-    dh = cfg.d_model // cfg.n_heads
-    shape = (cfg.n_layers, slots, cfg.max_len, cfg.n_heads, dh)
-    return SlotKVCache(
-        k=jnp.zeros(shape, dtype),
-        v=jnp.zeros(shape, dtype),
-        pos=jnp.zeros((slots,), jnp.int32),
-    )
-
-
-def prefill_slot(
-    params: Sequence[jax.Array],
-    cache: SlotKVCache,
-    slot: jax.Array,
-    prompt: jax.Array,
-    length: jax.Array,
-    cfg: TransformerConfig = TransformerConfig(),
-    compute_dtype: Any | None = None,
-) -> tuple[jax.Array, SlotKVCache]:
-    """Dense single-pass prefill of ONE slot of the shared cache.
-
-    ``prompt``: [P] int32 padded to a bucket width; ``length``: the true
-    token count (traced, so one compiled program serves every prompt
-    length ≤ P); ``slot``: traced slot index. Returns the logits at
-    position ``length - 1`` ([vocab]) and the cache with rows [0, P) of
-    that slot rewritten and ``pos[slot] = length`` — other slots'
-    rows/positions are untouched, so admission never disturbs a live
-    request mid-decode. Rows ≥ ``length`` hold pad garbage; they are
-    masked by ``pos`` and each is overwritten by a later decode step
-    before ``pos`` ever reaches it.
-    """
-    cd = jnp.dtype(compute_dtype) if compute_dtype is not None else None
-
-    def c(x):
-        return _cast(x, cd)
-
-    embed, pos_emb = params[0], params[1]
-    P = prompt.shape[0]
-    dh = cfg.d_model // cfg.n_heads
-    h = c(embed[prompt] + pos_emb[:P])  # [P, d] — a slot starts at 0
-    scale = dh**-0.5
-    causal = (
-        jnp.arange(P)[None, :] <= jnp.arange(P)[:, None]
-    )  # [P, P]
-
-    new_k, new_v = cache.k, cache.v
-    idx = 2
-    for layer in range(cfg.n_layers):
-
-        def attn(x, wq, wk, wv, layer=layer):
-            nonlocal new_k, new_v
-            q = (x @ wq).reshape(P, cfg.n_heads, dh)
-            # round k/v through the CACHE dtype before attending — the
-            # decode steps read these rows post-rounding, and a narrowed
-            # cache (bf16) must see identical values from prefill and
-            # decode or the bit-identical-greedy contract breaks
-            k = (x @ wk).reshape(P, cfg.n_heads, dh).astype(new_k.dtype)
-            v = (x @ wv).reshape(P, cfg.n_heads, dh).astype(new_v.dtype)
-            new_k = lax.dynamic_update_slice(
-                new_k, k[None, None], (layer, slot, 0, 0, 0)
-            )
-            new_v = lax.dynamic_update_slice(
-                new_v, v[None, None], (layer, slot, 0, 0, 0)
-            )
-            # attention stays within the prompt: a fresh slot has no
-            # earlier rows, so the [P, P] causal pass never reads the
-            # shared cache
-            s = jnp.einsum(
-                "phd,lhd->hpl", q, k, preferred_element_type=jnp.float32
-            ) * scale
-            s = jnp.where(causal[None, :, :], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum(
-                "hpl,lhd->phd", p.astype(v.dtype), v,
-                preferred_element_type=jnp.float32,
-            ).reshape(P, cfg.d_model)
-
-        h = _block(h, params[idx : idx + PARAMS_PER_LAYER], c, attn)
-        idx += PARAMS_PER_LAYER
-    h_last = lax.dynamic_index_in_dim(
-        h, length - 1, axis=0, keepdims=False
-    )
-    h_last = _ln(h_last, params[idx], params[idx + 1])
-    logits = jnp.dot(
-        c(h_last), c(embed).T, preferred_element_type=jnp.float32
-    )
-    return logits, SlotKVCache(
-        k=new_k, v=new_v, pos=cache.pos.at[slot].set(length)
-    )
-
-
-def decode_step_slots(
-    params: Sequence[jax.Array],
-    cache: SlotKVCache,
-    token: jax.Array,
-    cfg: TransformerConfig = TransformerConfig(),
-    compute_dtype: Any | None = None,
-) -> tuple[jax.Array, SlotKVCache]:
-    """One decode step for the first ``w = token.shape[0]`` slots of the
-    shared cache, each at its OWN position ``cache.pos[s]`` → (logits
-    [w, vocab] f32, cache with one row appended per advanced slot).
-
-    ``w`` may be smaller than S (the engine's width buckets: compile once
-    per bucket, not per live-request count); slots ≥ w are untouched.
-    Free slots inside the width write a garbage row at their stale
-    position — harmless, because a slot's rows are only ever read below
-    its own ``pos`` and a joining request rewrites [0, length) first.
-    """
-    cd = jnp.dtype(compute_dtype) if compute_dtype is not None else None
-
-    def c(x):
-        return _cast(x, cd)
-
-    embed, pos_emb = params[0], params[1]
-    w = token.shape[0]
-    dh = cfg.d_model // cfg.n_heads
-    t = cache.pos[:w]  # [w] per-slot positions
-    slots = jnp.arange(w)
-    h = c(embed[token] + pos_emb[t])  # [w, d]
-    #: slot s may read rows [0, t_s] — its own history plus the k/v this
-    #: step writes; rows of OTHER slots are unreachable by construction
-    #: (the attention below is batched per slot, never cross-slot)
-    mask = jnp.arange(cfg.max_len)[None, :] <= t[:, None]  # [w, max_len]
-    scale = dh**-0.5
-
-    new_k, new_v = cache.k, cache.v
-    idx = 2
-    for layer in range(cfg.n_layers):
-
-        def attn(x, wq, wk, wv, layer=layer):
-            nonlocal new_k, new_v
-            q = (x @ wq).reshape(w, cfg.n_heads, dh)
-            k = (x @ wk).reshape(w, cfg.n_heads, dh)
-            v = (x @ wv).reshape(w, cfg.n_heads, dh)
-            new_k = new_k.at[layer, slots, t].set(k.astype(new_k.dtype))
-            new_v = new_v.at[layer, slots, t].set(v.astype(new_v.dtype))
-            s = jnp.einsum(
-                "whd,wlhd->whl", q, new_k[layer, :w],
-                preferred_element_type=jnp.float32,
-            ) * scale
-            s = jnp.where(mask[:, None, :], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum(
-                "whl,wlhd->whd", p.astype(new_v.dtype), new_v[layer, :w],
-                preferred_element_type=jnp.float32,
-            ).reshape(w, cfg.d_model)
-
-        h = _block(h, params[idx : idx + PARAMS_PER_LAYER], c, attn)
-        idx += PARAMS_PER_LAYER
-    h = _ln(h, params[idx], params[idx + 1])
-    logits = jnp.dot(
-        c(h), c(embed).T, preferred_element_type=jnp.float32
-    )
-    new_pos = cache.pos.at[:w].add(1)
-    return logits, SlotKVCache(k=new_k, v=new_v, pos=new_pos)
-
-
 # ── paged (block-table) shared cache ─────────────────────────────────────────
 #
-# The paged variant of the slot cache (PagedAttention, Kwon et al. SOSP '23;
-# prefix sharing after RadixAttention, Zheng et al.): instead of one
+# The cache the serving engine (pygrid_tpu.serving) keeps, ONE per hosted
+# model, for S request slots that join (per-slot prefill), decode together at
+# their own positions and leave between steps (PagedAttention, Kwon et al.
+# SOSP '23; prefix sharing after RadixAttention, Zheng et al.): instead of one
 # contiguous [max_len] region per slot, k/v live in ONE pool of fixed-size
 # blocks and each slot carries a block table mapping logical pages to pool
 # blocks. Short requests hold only the pages they use, and identical prompt
@@ -599,9 +423,9 @@ def paged_prefill_chunk(
         def attn(x, wq, wk, wv, layer=layer):
             nonlocal new_k, new_v
             q = (x @ wq).reshape(Pb, cfg.n_heads, dh)
-            # round k/v through the CACHE dtype before attending, like
-            # prefill_slot — decode reads these rows post-rounding and
-            # bit-identical greedy requires prefill to see the same
+            # round k/v through the CACHE dtype before attending —
+            # decode reads these rows post-rounding and bit-identical
+            # greedy requires prefill to see the same
             k = (x @ wk).reshape(Pb, cfg.n_heads, dh).astype(new_k.dtype)
             v = (x @ wv).reshape(Pb, cfg.n_heads, dh).astype(new_v.dtype)
             with jax.named_scope("kv_write"):
@@ -647,11 +471,11 @@ def paged_decode_step(
     active: jax.Array | None = None,
 ) -> tuple[jax.Array, PagedKVCache]:
     """One decode step for the first ``w`` slots through their block
-    tables — the paged twin of :func:`decode_step_slots`, same contract:
-    each slot at its own ``pos``, logits [w, vocab] f32, one row appended
-    per advanced slot. A free slot inside the width has a zeroed table
-    row, so its garbage write lands in trash block 0 — it can never
-    corrupt a block that was freed and reallocated to a live request.
+    tables: each slot at its own ``pos``, logits [w, vocab] f32, one row
+    appended per advanced slot. A free slot inside the width has a
+    zeroed table row, so its garbage write lands in trash block 0 — it
+    can never corrupt a block that was freed and reallocated to a live
+    request.
 
     ``active`` ([w] bool, optional) freezes rows mid-batch: a frozen
     row's k/v write routes to trash block 0 and its ``pos`` does not
@@ -746,132 +570,6 @@ def paged_decode_step(
     )
     new_pos = cache.pos.at[:w].add(advance)
     return logits, PagedKVCache(k=new_k, v=new_v, pos=new_pos)
-
-
-def paged_verify_chunk(
-    params: Sequence[jax.Array],
-    cache: PagedKVCache,
-    table: jax.Array,
-    tokens: jax.Array,
-    cfg: TransformerConfig = TransformerConfig(),
-    compute_dtype: Any | None = None,
-    active: jax.Array | None = None,
-) -> tuple[jax.Array, PagedKVCache]:
-    """Speculative VERIFY pass: ``K`` consecutive tokens per slot in one
-    wide step through the block tables.
-
-    ``tokens``: [w, K] int32 — slot ``s`` feeds tokens at positions
-    ``pos[s] .. pos[s]+K-1`` (the draft's proposal chain: the slot's
-    last emitted token followed by the first K-1 proposals); their k/v
-    are written through the table and the returned logits [w, K, vocab]
-    give the target model's next-token distribution at every one of the
-    K positions — a full decode-step logits row for each, computed at
-    prefill-style arithmetic intensity instead of K separate dispatches.
-    ``pos`` is NOT advanced here: the caller advances by the accepted
-    count (rejected positions hold garbage k/v in the row's own private
-    pages above ``pos`` — masked, and overwritten before ``pos`` ever
-    reaches them, the same discipline as pad rows).
-
-    Positions past the slot's table (or the whole row when ``active``
-    is False) scatter into trash block 0, so a wasted verify tail near
-    the end of a generation can never write a shared or foreign page.
-    """
-    cd = jnp.dtype(compute_dtype) if compute_dtype is not None else None
-
-    def c(x):
-        return _cast(x, cd)
-
-    embed, pos_emb = params[0], params[1]
-    w, K = tokens.shape
-    block = cache.k.shape[2]
-    max_pages = table.shape[1]
-    rows = max_pages * block
-    dh = cfg.d_model // cfg.n_heads
-    t0 = cache.pos[:w]  # [w]
-    tw = table[:w]  # [w, max_pages]
-    positions = t0[:, None] + jnp.arange(K)[None, :]  # [w, K], unclipped
-    in_table = positions < rows
-    page = jnp.minimum(positions // block, max_pages - 1)
-    blk = jnp.take_along_axis(tw, page, axis=1)  # [w, K]
-    #: overflow (and frozen-row) scatter targets route to trash — the
-    #: same rule paged_prefill_chunk applies to pad positions
-    valid = in_table
-    if active is not None:
-        valid = valid & active[:, None]
-    blk = jnp.where(valid, blk, 0)
-    off = jnp.where(valid, positions % block, 0)
-    h = c(
-        embed[tokens]
-        + pos_emb[jnp.minimum(positions, cfg.max_len - 1)]
-    )  # [w, K, d]
-    #: query j of slot s sees rows [0, t0_s + j]: its history plus the
-    #: chain tokens scattered this pass (written before the gather)
-    mask = (
-        jnp.arange(rows)[None, None, :] <= positions[:, :, None]
-    )  # [w, K, rows]
-    scale = dh**-0.5
-
-    new_k, new_v = cache.k, cache.v
-    idx = 2
-    for layer in range(cfg.n_layers):
-
-        def attn(x, wq, wk, wv, layer=layer):
-            nonlocal new_k, new_v
-            q = (x @ wq).reshape(w, K, cfg.n_heads, dh)
-            k = (x @ wk).reshape(w, K, cfg.n_heads, dh)
-            v = (x @ wv).reshape(w, K, cfg.n_heads, dh)
-            new_k = new_k.at[layer, blk, off].set(k.astype(new_k.dtype))
-            new_v = new_v.at[layer, blk, off].set(v.astype(new_v.dtype))
-            k_rows = new_k[layer][tw].reshape(w, rows, cfg.n_heads, dh)
-            v_rows = new_v[layer][tw].reshape(w, rows, cfg.n_heads, dh)
-            s = jnp.einsum(
-                "wkhd,wlhd->wkhl", q, k_rows,
-                preferred_element_type=jnp.float32,
-            ) * scale
-            s = jnp.where(mask[:, :, None, :], s, -1e30)
-            p = jax.nn.softmax(s, axis=-1)
-            return jnp.einsum(
-                "wkhl,wlhd->wkhd", p.astype(v_rows.dtype), v_rows,
-                preferred_element_type=jnp.float32,
-            ).reshape(w, K, cfg.d_model)
-
-        h = _block(h, params[idx : idx + PARAMS_PER_LAYER], c, attn)
-        idx += PARAMS_PER_LAYER
-    h = _ln(h, params[idx], params[idx + 1])
-    logits = jnp.dot(
-        c(h), c(embed).T, preferred_element_type=jnp.float32
-    )
-    return logits, PagedKVCache(k=new_k, v=new_v, pos=cache.pos)
-
-
-def truncated_draft(
-    cfg: TransformerConfig,
-    params: Sequence[jax.Array],
-    n_layers: int,
-) -> tuple[TransformerConfig, list[jax.Array]]:
-    """The self-speculative DRAFT: the same checkpoint truncated to its
-    first ``n_layers`` transformer blocks, reusing the full model's
-    embeddings and final layer norm as the draft's output head. No new
-    weights, no training — the draft is expressible in the existing
-    transformer family, so every decode primitive in this module serves
-    it unchanged (its paged cache just has fewer layers). Early layers
-    of a deep residual stack predict the final distribution well enough
-    to propose; the target VERIFIES every proposal, so draft quality
-    only moves the acceptance rate, never correctness."""
-    if not 1 <= n_layers < cfg.n_layers:
-        raise ValueError(
-            f"draft must keep between 1 and {cfg.n_layers - 1} of the "
-            f"model's {cfg.n_layers} layers, got {n_layers}"
-        )
-    draft_cfg = TransformerConfig(
-        vocab=cfg.vocab, d_model=cfg.d_model, n_heads=cfg.n_heads,
-        n_layers=n_layers, d_ff=cfg.d_ff, max_len=cfg.max_len,
-    )
-    draft_params = (
-        list(params[: 2 + PARAMS_PER_LAYER * n_layers])
-        + list(params[-2:])
-    )
-    return draft_cfg, draft_params
 
 
 def generate(

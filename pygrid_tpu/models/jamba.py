@@ -104,8 +104,8 @@ class JambaConfig(NamedTuple):
 
 # ── what the engine asks of a family ─────────────────────────────────────
 
-#: a recurrent state rides beside the K/V pool: no shared prefix pages,
-#: no speculative draft (either would need a state snapshot to roll to)
+#: a recurrent state rides beside the K/V pool: no shared prefix pages
+#: (a second request would need a snapshot of the state at the page edge)
 RECURRENT = True
 
 

@@ -14,7 +14,6 @@ from __future__ import annotations
 import base64
 import binascii
 import logging
-import os
 import uuid
 from typing import Any, Callable
 
@@ -577,44 +576,6 @@ def host_model(ctx: NodeContext, message: dict, conn: Connection) -> dict:
 #: what would OOM the node's chip/host from one hostile frame
 _MAX_GENERATION_CACHE_ELEMENTS = 1 << 28
 
-#: memoized jitted decode programs, keyed on everything trace-relevant
-#: ((cfg ints, n_new, seeded) — temperature is a TRACED argument in the
-#: sampled program, so one compile serves every temperature;
-#: params/prompt shapes key jit's own cache); bounded so hostile n_new
-#: variety can't grow it without limit
-_GENERATION_JIT: dict = {}
-
-
-def _generation_fn(cfg, n_new: int, seeded: bool):
-    cache_key = (tuple(cfg), n_new, seeded)
-    fn = _GENERATION_JIT.pop(cache_key, None)
-    if fn is not None:
-        # LRU touch: re-insert at the back so hot programs survive a
-        # client cycling n_new values (dicts iterate insertion-ordered)
-        _GENERATION_JIT[cache_key] = fn
-    if fn is None:
-        import jax
-
-        from pygrid_tpu.models import decode
-
-        if len(_GENERATION_JIT) >= 64:
-            # evict only the single least-recently-used entry — clearing
-            # the whole dict let one hostile client flush every hot
-            # compiled program for all models at once
-            _GENERATION_JIT.pop(next(iter(_GENERATION_JIT)))
-        if seeded:
-            fn = jax.jit(
-                lambda p, x, k, temp: decode.generate(
-                    p, x, n_new, cfg, temperature=temp, key=k
-                )
-            )
-        else:
-            fn = jax.jit(
-                lambda p, x: decode.generate(p, x, n_new, cfg)
-            )
-        _GENERATION_JIT[cache_key] = fn
-    return fn
-
 
 def _prepare_generation(ctx: NodeContext, message: dict):
     """Validate a run-generation message end to end. Returns either an
@@ -724,40 +685,6 @@ def _prepare_generation(ctx: NodeContext, message: dict):
     return hosted, prompt, n_new, temperature, seed
 
 
-def _legacy_generate(hosted, prompt, n_new: int, temperature, seed):
-    """The pre-engine per-request path (one whole-generation XLA program
-    jitted per distinct ``n_new``) — kept as the ``PYGRID_SERVING=off``
-    escape hatch and as the baseline ``bench_serving`` measures the
-    batch engine against."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    cfg, params = hosted.generation_cache
-    from pygrid_tpu.models import decode
-
-    if decode.family_of(cfg) is not decode:
-        raise E.PyGridError(
-            "PYGRID_SERVING=off serves only the transformer family: this "
-            "model runs through the batch engine"
-        )
-    if temperature > 0.0 and seed is None:
-        # unseeded sampling must actually vary across requests
-        seed = int.from_bytes(os.urandom(4), "big")
-    sampled = temperature > 0.0
-    fn = _generation_fn(cfg, n_new, sampled)
-    if sampled:
-        toks = fn(
-            params,
-            jnp.asarray(prompt),
-            jax.random.PRNGKey(int(seed)),
-            jnp.float32(temperature),
-        )
-    else:
-        toks = fn(params, jnp.asarray(prompt))
-    return np.asarray(toks)
-
-
 def run_generation(ctx: NodeContext, message: dict, conn: Connection) -> dict:
     """Autoregressive generation from a hosted transformer bundle —
     the serving twin of ``run_inference`` for the generative model
@@ -765,16 +692,15 @@ def run_generation(ctx: NodeContext, message: dict, conn: Connection) -> dict:
     prompt [B, P]), ``n_new``, optional ``temperature`` + ``seed``.
     Gated by the same ``allow_remote_inference`` flag.
 
-    Since the serving engine (``pygrid_tpu/serving/``, docs/SERVING.md)
-    this handler is a thin enqueue-and-await wrapper: the request joins
-    the model's continuous batch and this (executor) thread blocks on
-    the result future while the engine's dedicated thread drives the
-    device — concurrent requests share one persistent batched program
-    instead of serializing whole-generation XLA calls, and a full queue
-    answers a typed busy error instead of piling up. Greedy results
-    equal the direct ``decode.generate`` path (bit for bit on the CPU
-    at f32; up to rounding ties on the TPU — docs/SERVING.md);
-    ``PYGRID_SERVING=off`` restores the legacy per-request programs."""
+    The handler is a thin enqueue-and-await wrapper over the serving
+    engine (``pygrid_tpu/serving/``, docs/SERVING.md), the one way the
+    node generates: the request joins the model's continuous batch and
+    this (executor) thread blocks on the result future while the
+    engine's dedicated thread drives the device — concurrent requests
+    share one persistent batched program, and a full queue answers a
+    typed busy error instead of piling up. Greedy results equal the
+    reference ``decode.generate`` (bit for bit on the CPU at f32; up to
+    rounding ties on the TPU — docs/SERVING.md)."""
     _authenticated(conn)
     import numpy as np
 
@@ -783,13 +709,10 @@ def run_generation(ctx: NodeContext, message: dict, conn: Connection) -> dict:
         if isinstance(prep, dict):
             return prep
         hosted, prompt, n_new, temperature, seed = prep
-        if os.environ.get("PYGRID_SERVING", "").lower() in ("off", "0"):
-            toks = _legacy_generate(hosted, prompt, n_new, temperature, seed)
-        else:
-            engine = ctx.serving.engine_for(
-                str(message[MSG_FIELD.MODEL_ID]), hosted
-            )
-            toks = engine.submit(prompt, n_new, temperature, seed)
+        engine = ctx.serving.engine_for(
+            str(message[MSG_FIELD.MODEL_ID]), hosted
+        )
+        toks = engine.submit(prompt, n_new, temperature, seed)
         return {SUCCESS: True, "tokens": np.asarray(toks).tolist()}
     except E.ServerBusyError as err:
         return {SUCCESS: False, "busy": True, ERROR: str(err)}
@@ -803,8 +726,8 @@ def delete_model(ctx: NodeContext, message: dict, conn: Connection) -> dict:
         result = ctx.models.delete(
             ctx.local_worker.id, message[MSG_FIELD.MODEL_ID]
         )
-        # the serving engine holds the bundle's device params + slot
-        # cache — deleting the model must release them
+        # the serving engine holds the bundle's device params + block
+        # pool — deleting the model must release them
         ctx.serving.evict(str(message[MSG_FIELD.MODEL_ID]))
         return result
     except E.PyGridError as err:

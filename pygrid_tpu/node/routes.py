@@ -536,33 +536,32 @@ async def metrics(request: web.Request) -> web.Response:
         exp.gauge("serving_state_bytes", eng["state_bytes"],
                   "device bytes of per-slot recurrent state beside the "
                   "KV pool", labels)
-        if eng.get("paged"):
-            # block-pool occupancy: free / used (held by live requests,
-            # INCLUDING cached blocks they share) / cached (reclaimable
-            # cache-only) — the three sum to the pool, and peak
-            # shared-prefix load reads as USED, not as cache bloat
-            total = eng["kv_blocks_total"]
-            free = eng["kv_blocks_free"]
-            idle_cached = eng["kv_blocks_idle_cached"]
-            for state, value in (
-                ("free", free),
-                ("used", max(0, total - free - idle_cached)),
-                ("cached", idle_cached),
-            ):
-                exp.gauge(
-                    "serving_kv_blocks", value,
-                    "paged KV pool blocks, by state (free/used/cached)",
-                    {**labels, "state": state},
-                )
+        # block-pool occupancy: free / used (held by live requests,
+        # INCLUDING cached blocks they share) / cached (reclaimable
+        # cache-only) — the three sum to the pool, and peak
+        # shared-prefix load reads as USED, not as cache bloat
+        total = eng["kv_blocks_total"]
+        free = eng["kv_blocks_free"]
+        idle_cached = eng["kv_blocks_idle_cached"]
+        for state, value in (
+            ("free", free),
+            ("used", max(0, total - free - idle_cached)),
+            ("cached", idle_cached),
+        ):
             exp.gauge(
-                "serving_kv_block_tokens", eng["block_size"],
-                "tokens per paged KV block", labels,
+                "serving_kv_blocks", value,
+                "paged KV pool blocks, by state (free/used/cached)",
+                {**labels, "state": state},
             )
-            exp.gauge(
-                "serving_kv_fragmentation", eng["kv_fragmentation"],
-                "allocated-but-unwritten fraction of live KV pages",
-                labels,
-            )
+        exp.gauge(
+            "serving_kv_block_tokens", eng["block_size"],
+            "tokens per paged KV block", labels,
+        )
+        exp.gauge(
+            "serving_kv_fragmentation", eng["kv_fragmentation"],
+            "allocated-but-unwritten fraction of live KV pages",
+            labels,
+        )
     # the telemetry bus: event counters + every histogram family
     # (request latency by route, frame decode time, report latency,
     # cycle phases, wire bytes by codec, serde tensor copies)

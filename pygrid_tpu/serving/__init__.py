@@ -3,8 +3,8 @@
 The node's ``run-generation`` surface routes through this package: a
 :class:`ServingManager` holds one :class:`GenerationEngine` per hosted
 transformer bundle, and each engine serves many concurrent requests
-from one persistent slot-structured KV cache with a fixed, bucketed set
-of compiled programs — the inference-side counterpart of the wire-v2
+from one persistent paged KV cache with a fixed, bucketed set of
+compiled programs — the inference-side counterpart of the wire-v2
 hot-loop work (CHANGES.md PR 1).
 """
 
@@ -44,7 +44,7 @@ class ServingManager:
     """Node-wide registry: hosted model id → its generation engine.
 
     Engines build lazily on first generation request (parsing the bundle
-    and allocating the slot cache is paid once, not per request) and
+    and allocating the block pool is paid once, not per request) and
     rebuild when a model id is re-hosted with new content — staleness is
     detected by HostedModel object identity (a re-host constructs a new
     object), tracked with a weakref so the registry never pins a deleted
@@ -113,13 +113,9 @@ class ServingManager:
         budget, size this model's block pool to its admission-weight
         share (``weight / Σ weights × PYGRID_KV_BUDGET``); explicit
         ``num_blocks``/``kv_budget_bytes`` on the base config win."""
-        from pygrid_tpu.models import decode
-
         base = self.config
-        recurrent = decode.family_of(cfg).RECURRENT  # served paged only
         if (
-            not (recurrent or pagedkv.paged_enabled(base.paged))
-            or base.num_blocks is not None
+            base.num_blocks is not None
             or base.kv_budget_bytes is not None
             or self.budget.total_bytes is None
         ):
@@ -130,20 +126,9 @@ class ServingManager:
         dtype = base.cache_dtype or base.compute_dtype
         if dtype is None:
             dtype = pagedkv.default_cache_dtype()
-        extra = 0
-        if (
-            pagedkv.spec_enabled(base.spec_decode)
-            and cfg.n_layers >= 2
-            and not recurrent
-        ):
-            # the speculative draft's pool rides the same block ids —
-            # its layers are part of what a granted block costs
-            extra = pagedkv.resolve_spec_layers(
-                cfg.n_layers, base.spec_layers
-            )
         blocks = self.budget.blocks_for(
             model_id,
-            pagedkv.block_bytes(cfg, block, dtype, extra_layers=extra),
+            pagedkv.block_bytes(cfg, block, dtype),
             # a recurrent family's per-slot state is charged first
             fixed_bytes=pagedkv.state_bytes(cfg, base.max_slots, dtype),
         )

@@ -3,8 +3,9 @@
 One :class:`GenerationEngine` serves one hosted transformer bundle. It
 owns a persistent KV cache and a dedicated worker thread that runs the
 device loop — the Orca-style continuous-batching core (Yu et al., OSDI
-'22), with **paged block-table storage by default** (PagedAttention,
-Kwon et al. SOSP '23; prefix sharing after RadixAttention):
+'22), over **paged block-table storage** (PagedAttention, Kwon et al.
+SOSP '23; prefix sharing after RadixAttention). This is the only way
+the node generates:
 
 - the cache is a pool of fixed-size KV blocks
   (:class:`~pygrid_tpu.models.decode.PagedKVCache`); a request holds
@@ -37,18 +38,7 @@ Kwon et al. SOSP '23; prefix sharing after RadixAttention):
   ``PYGRID_FUSED_DECODE=off``): per-row token budgets freeze rows that
   finish mid-scan (their writes trash-route, their positions park), so
   the host pays one dispatch + one token fetch per quantum instead of
-  per step — the dominant cost of small/medium-model decode;
-- with ``PYGRID_SPEC_DECODE=on``, a **self-speculative** truncated-layer
-  draft of the same checkpoint proposes ``spec_k`` tokens per cycle and
-  the full model verifies them all in one wide block-table step (the
-  draft's proposal scan and the verify run as one program). Greedy
-  output stays bit-identical by construction (the target's argmax
-  arbitrates every emitted token); sampling uses the standard
-  speculative rejection estimator, keyed per (seed, row, position). The
-  draft's k/v pool shares the block tables and ids — allocation, prefix
-  sharing, and COW cover both caches with zero extra bookkeeping — and
-  per-model acceptance-rate telemetry (``serving_spec_*``) tells
-  operators when drafting loses.
+  per step — the dominant cost of small/medium-model decode.
 
 Every instant of the worker thread belongs to one of six phases of a
 :class:`~pygrid_tpu.telemetry.loopclock.LoopClock` — ``idle`` (nothing
@@ -59,10 +49,6 @@ host), ``build`` (a decode dispatch's inputs and its enqueue), ``fetch``
 finished requests out). Each is seconds on
 ``serving_loop_seconds_total{phase}`` and an ``engine.<phase>``
 annotation in a ``jax.profiler`` trace (docs/OBSERVABILITY.md §4, §6).
-
-``PYGRID_KV_PAGED=off`` (or ``EngineConfig(paged=False)``) falls back
-to the PR-3 contiguous slot cache — the operational escape hatch and
-the bench baseline for capacity-per-GB comparisons.
 
 Greedy results are bit-identical to single-request
 :func:`pygrid_tpu.models.decode.generate` on the CPU at f32 (tested).
@@ -116,12 +102,11 @@ class EngineConfig:
     (always topped up with ``max_slots``); prompt buckets derive from
     the model's ``max_len`` (see :func:`programs.prompt_buckets`).
 
-    Paged-KV knobs (docs/SERVING.md): ``paged`` defaults to on
-    (``PYGRID_KV_PAGED=off`` opts out); ``block_size`` is the KV page
+    Paged-KV knobs (docs/SERVING.md): ``block_size`` is the KV page
     in tokens (``PYGRID_KV_BLOCK``, default 64, power-of-two-bucketed);
     ``num_blocks`` overrides the pool size directly, else
-    ``kv_budget_bytes`` sizes it, else the pool defaults to byte parity
-    with the contiguous cache (``max_slots`` × pages-per-slot + trash);
+    ``kv_budget_bytes`` sizes it, else the pool holds ``max_slots``
+    full-length requests (``max_slots`` × pages-per-slot + trash);
     ``kv_overcommit`` bounds how far QUEUED worst-case block demand may
     run past the pool before enqueue answers busy — block exhaustion,
     not slot exhaustion, is the admission limit. Per-model admission
@@ -138,7 +123,6 @@ class EngineConfig:
     default_timeout_s: float = 300.0
     compute_dtype: Any = None
     cache_dtype: Any = None
-    paged: bool | None = None
     block_size: int | None = None
     num_blocks: int | None = None
     kv_budget_bytes: int | None = None
@@ -147,13 +131,6 @@ class EngineConfig:
     #: ONE lax.scan program when no admission is pending (default on;
     #: ``PYGRID_FUSED_DECODE=off``) — kills per-step host dispatch
     fused: bool | None = None
-    #: self-speculative decoding: a truncated-layer draft of the SAME
-    #: checkpoint proposes ``spec_k`` tokens, the full model verifies
-    #: them in one wide block-table step (OPT-IN: ``PYGRID_SPEC_DECODE``;
-    #: per-model acceptance-rate telemetry says whether it wins)
-    spec_decode: bool | None = None
-    spec_k: int | None = None
-    spec_layers: int | None = None
 
 
 class _Row:
@@ -225,7 +202,6 @@ class GenerationEngine:
         model_id: str = "",
     ) -> None:
         import jax
-        import jax.numpy as jnp
 
         from pygrid_tpu.models import decode
 
@@ -237,43 +213,16 @@ class GenerationEngine:
         #: prefill and decode step, and what it keeps per slot)
         self._family = decode.family_of(cfg)
         #: a recurrent state beside the K/V pages: observed, not a knob.
-        #: Such a model is served paged only (its K/V layers have no
-        #: contiguous twin), shares no prefix pages and drafts nothing
+        #: It switches off exactly one thing, prefix sharing: a page of
+        #: K/V can be mapped into a second request, the state that ran
+        #: over the same tokens cannot
         self._recurrent = bool(self._family.RECURRENT)
-        self._paged = self._recurrent or pagedkv.paged_enabled(
-            self.config.paged
-        )
-        #: fused multi-step decode and self-speculative decoding both
-        #: need the block-table discipline (trash-routed frozen writes),
-        #: so they ride the paged path only; spec additionally needs a
-        #: stack deep enough to truncate
-        self._fused = self._paged and pagedkv.fused_enabled(
-            self.config.fused
-        )
-        spec_asked = cfg.n_layers >= 2 and pagedkv.spec_enabled(
-            self.config.spec_decode
-        )
-        self._spec = self._paged and spec_asked and not self._recurrent
-        #: said once, at warm-up: speculative decode was asked for and a
-        #: draft over recurrent state cannot be rolled back
-        self._spec_ignored = spec_asked and self._recurrent
-        self._spec_k = pagedkv.resolve_spec_k(self.config.spec_k)
-        draft_cfg = None
-        self._draft_params = None
-        if self._spec:
-            n_draft = pagedkv.resolve_spec_layers(
-                cfg.n_layers, self.config.spec_layers
-            )
-            draft_cfg, self._draft_params = decode.truncated_draft(
-                cfg, params, n_draft
-            )
-        self._draft_cfg = draft_cfg
+        self._fused = pagedkv.fused_enabled(self.config.fused)
         self.programs = ProgramSet(
             cfg,
             compute_dtype=self.config.compute_dtype,
             cache_dtype=self.config.cache_dtype,
             model_id=model_id,
-            draft_cfg=draft_cfg,
         )
         self._prompt_buckets = prompt_buckets(
             cfg.max_len, self.config.min_prompt_bucket
@@ -294,88 +243,60 @@ class GenerationEngine:
         )
         #: bytes a slot holds beside its K/V pages (0: the transformer)
         self._state_per_slot = pagedkv.state_bytes(cfg, 1, self._kv_dtype)
-        if self._paged:
-            self._block = pagedkv.resolve_block_size(
-                cfg.max_len, self.config.block_size
-            )
-            self._max_pages = -(-cfg.max_len // self._block)
-            if self.config.num_blocks is not None:
-                num_blocks = int(self.config.num_blocks)
-            elif self.config.kv_budget_bytes is not None:
-                per_block = pagedkv.block_bytes(
-                    cfg, self._block, self._kv_dtype,
-                    # the draft pool shares block ids: a block's true
-                    # byte cost under spec decode includes its layers
-                    extra_layers=(
-                        self._draft_cfg.n_layers if self._spec else 0
-                    ),
-                )
-                # the trash block counts INSIDE the byte budget (same
-                # accounting as DeviceBudget.blocks_for): an operator
-                # sizing to available HBM must never be overshot. A
-                # recurrent family's fixed state comes out of it first
-                num_blocks = (
-                    int(self.config.kv_budget_bytes) - self._state_bytes()
-                ) // per_block
-            else:
-                # byte parity with the contiguous slot cache — same
-                # footprint, but short requests free what they don't use
-                num_blocks = 1 + self.config.max_slots * self._max_pages
-            self._num_blocks = max(2, num_blocks)
-            self._pool = pagedkv.BlockPool(self._num_blocks)
-            self._prefix = pagedkv.PrefixCache(
-                self._pool, self._block, shareable=not self._recurrent
-            )
-            #: blocks given back to the device budget by live
-            #: re-partitioning (shrink_blocks) — survives _fail_all's
-            #: pool rebuild
-            self._shrunk_blocks = 0
-            #: host mirror of the device block table; rebuilt lazily
-            #: (``_table``) after any admission/free edit
-            self._table_np = np.zeros(
-                (self.config.max_slots, self._max_pages), np.int32
-            )
-            self._table_dev = None
-            self._table_dirty = True
-            self._demand_pages = 0
-            self._prefix_hits = 0
-            self._prefix_misses = 0
-            self._prefix_tokens_saved = 0
-            cache = self._family.init_paged_cache(
-                cfg, self.config.max_slots, self._num_blocks,
-                self._block, dtype=self._kv_dtype,
-            )
+        self._block = pagedkv.resolve_block_size(
+            cfg.max_len, self.config.block_size
+        )
+        self._max_pages = -(-cfg.max_len // self._block)
+        if self.config.num_blocks is not None:
+            num_blocks = int(self.config.num_blocks)
+        elif self.config.kv_budget_bytes is not None:
+            # the trash block counts INSIDE the byte budget (same
+            # accounting as DeviceBudget.blocks_for): an operator
+            # sizing to available HBM must never be overshot. A
+            # recurrent family's fixed state comes out of it first
+            num_blocks = (
+                int(self.config.kv_budget_bytes) - self._state_bytes()
+            ) // self.block_cost_bytes()
         else:
-            cache = decode.init_slot_cache(
-                cfg, self.config.max_slots, dtype=self._kv_dtype
-            )
+            # room for every slot at full length; short requests free
+            # what they don't use
+            num_blocks = 1 + self.config.max_slots * self._max_pages
+        self._num_blocks = max(2, num_blocks)
+        self._pool = pagedkv.BlockPool(self._num_blocks)
+        self._prefix = pagedkv.PrefixCache(
+            self._pool, self._block, shareable=not self._recurrent
+        )
+        #: blocks given back to the device budget by live
+        #: re-partitioning (shrink_blocks) — survives _fail_all's
+        #: pool rebuild
+        self._shrunk_blocks = 0
+        #: host mirror of the device block table; rebuilt lazily
+        #: (``_table``) after any admission/free edit
+        self._table_np = np.zeros(
+            (self.config.max_slots, self._max_pages), np.int32
+        )
+        self._table_dev = None
+        self._table_dirty = True
+        self._demand_pages = 0
+        self._prefix_hits = 0
+        self._prefix_misses = 0
+        self._prefix_tokens_saved = 0
+        cache = self._family.init_paged_cache(
+            cfg, self.config.max_slots, self._num_blocks,
+            self._block, dtype=self._kv_dtype,
+        )
         # held as separate refs: the jitted programs donate and return
         # them, and the engine swaps in the new buffers every call.
         # ``_state`` is what the family keeps per slot beside k/v/pos
         # (a recurrent state; nothing for the transformer): it rides
-        # every paged program call, donated like the rest
+        # every program call, donated like the rest
         self._k, self._v, self._pos, *self._state = cache
         #: whether decode attention reads live pages in place (the rule
         #: the decode programs themselves apply when they are traced)
-        self._kv_kernel = self._paged and self._family.kv_kernel(
-            self._k, self._max_pages
-        )
-        #: the draft's k/v pool: same block ids/tables as the target
-        #: (allocation covers both), fewer layers; position state is
-        #: shared — the draft is always exactly at the target's pos
-        self._dk = self._dv = None
-        if self._spec:
-            dcache = decode.init_paged_cache(
-                self._draft_cfg, self.config.max_slots,
-                self._num_blocks, self._block, dtype=self._kv_dtype,
-            )
-            self._dk, self._dv = dcache.k, dcache.v
+        self._kv_kernel = self._family.kv_kernel(self._k, self._max_pages)
         self._fused_scans = 0
         self._fused_steps = 0
         self._fused_wasted = 0
-        self._spec_verifies = 0
-        self._spec_proposed = 0
-        self._spec_accepted = 0
         self._slots: list[_Row | None] = [None] * self.config.max_slots
         self._queue: deque[_Row] = deque()
         self._lock = threading.Lock()
@@ -458,23 +379,22 @@ class GenerationEngine:
             )
             for b in range(batch)
         ]
-        if self._paged:
-            # worst-case page demand per row, credited with the pages
-            # the prefix cache ALREADY holds for this prompt (a probe —
-            # admission re-matches for real; an eviction in between
-            # just parks the row until blocks free)
-            pages_per_row = -(-(p_len + n_new) // self._block)
-            if pages_per_row > self._pool.usable:
-                raise E.PyGridError(
-                    f"request needs {pages_per_row} KV blocks of "
-                    f"{self._block} tokens but the pool holds "
-                    f"{self._pool.usable} — prompt + n_new can never "
-                    "be cached"
-                )
-            for row in rows:
-                row.demand = max(
-                    1, pages_per_row - self._prefix.probe(row.prompt)
-                )
+        # worst-case page demand per row, credited with the pages the
+        # prefix cache ALREADY holds for this prompt (a probe —
+        # admission re-matches for real; an eviction in between just
+        # parks the row until blocks free)
+        pages_per_row = -(-(p_len + n_new) // self._block)
+        if pages_per_row > self._pool.usable:
+            raise E.PyGridError(
+                f"request needs {pages_per_row} KV blocks of "
+                f"{self._block} tokens but the pool holds "
+                f"{self._pool.usable} — prompt + n_new can never "
+                "be cached"
+            )
+        for row in rows:
+            row.demand = max(
+                1, pages_per_row - self._prefix.probe(row.prompt)
+            )
         demand = sum(r.demand for r in rows)
         with self._work:
             if not self._running:
@@ -489,7 +409,7 @@ class GenerationEngine:
                     f"queued, depth limit {self.config.max_queue}) — "
                     "retry later"
                 )
-            if self._paged and self._demand_pages + demand > (
+            if self._demand_pages + demand > (
                 self.config.kv_overcommit * self._pool.usable
             ):
                 telemetry.incr(
@@ -502,8 +422,7 @@ class GenerationEngine:
                     f"{self._pool.usable} blocks, overcommit "
                     f"{self.config.kv_overcommit:g}) — retry later"
                 )
-            if self._paged:
-                self._demand_pages += demand
+            self._demand_pages += demand
             self._queue.extend(rows)
             self._requests += 1
             self._ensure_thread()
@@ -558,6 +477,13 @@ class GenerationEngine:
             queued = list(
                 dict.fromkeys(r.pending.request_id for r in self._queue)
             )
+            live_rows = [r for r in self._slots if r is not None]
+            alloc_pages = sum(
+                len(r.pages) for r in live_rows if r.pages is not None
+            )
+            used_tokens = sum(
+                len(r.prompt) + len(r.out) for r in live_rows
+            )
             out = {
                 "model_id": self.model_id,
                 "queue_depth": len(self._queue),
@@ -568,9 +494,7 @@ class GenerationEngine:
                 "compiles_total": self.programs.compile_count(),
                 "slots": slots,
                 "queued_requests": queued,
-                "paged": self._paged,
                 "fused": self._fused,
-                "spec": self._spec,
                 # which decode-attention path the programs took (1: the
                 # kernel reads live pages in place; 0: the gather)
                 "kv_kernel": int(self._kv_kernel),
@@ -582,6 +506,28 @@ class GenerationEngine:
                 # parameters, once
                 "weights_bytes": self._weights_bytes,
                 **self._runs_on,
+                "block_size": self._block,
+                "kv_block_bytes": self.block_cost_bytes(),
+                "kv_blocks_total": self._pool.usable,
+                "kv_blocks_retired": self._pool.retired_count(),
+                "kv_blocks_free": self._pool.free_count(),
+                "kv_blocks_cached": self._prefix.block_count(),
+                # cache-ONLY (reclaimable) blocks; a cached block shared
+                # with a live request counts as used in the occupancy
+                # gauges, not cached
+                "kv_blocks_idle_cached": self._prefix.idle_block_count(),
+                "kv_demand_pages": self._demand_pages,
+                # internal fragmentation of the LIVE allocation:
+                # allocated-but-unwritten token slots (page-tail
+                # waste) over allocated token slots
+                "kv_fragmentation": round(
+                    1.0 - used_tokens / (alloc_pages * self._block), 4
+                )
+                if alloc_pages
+                else 0.0,
+                "prefix_hits": self._prefix_hits,
+                "prefix_misses": self._prefix_misses,
+                "prefix_tokens_saved": self._prefix_tokens_saved,
             }
             if self._fused:
                 out.update(
@@ -589,61 +535,6 @@ class GenerationEngine:
                         "fused_scans": self._fused_scans,
                         "fused_steps": self._fused_steps,
                         "fused_wasted_steps": self._fused_wasted,
-                    }
-                )
-            if self._spec:
-                out.update(
-                    {
-                        "spec_k": self._spec_k,
-                        "spec_draft_layers": self._draft_cfg.n_layers,
-                        "spec_verifies": self._spec_verifies,
-                        "spec_proposed": self._spec_proposed,
-                        "spec_accepted": self._spec_accepted,
-                        # the honest per-model verdict: below ~1/k the
-                        # draft is pure overhead and the operator
-                        # should turn spec decode off for this model
-                        "spec_acceptance": round(
-                            self._spec_accepted / self._spec_proposed, 4
-                        )
-                        if self._spec_proposed
-                        else None,
-                    }
-                )
-            if self._paged:
-                live_rows = [r for r in self._slots if r is not None]
-                alloc_pages = sum(
-                    len(r.pages) for r in live_rows if r.pages is not None
-                )
-                used_tokens = sum(
-                    len(r.prompt) + len(r.out) for r in live_rows
-                )
-                out.update(
-                    {
-                        "block_size": self._block,
-                        "kv_block_bytes": self.block_cost_bytes(),
-                        "kv_blocks_total": self._pool.usable,
-                        "kv_blocks_retired": self._pool.retired_count(),
-                        "kv_blocks_free": self._pool.free_count(),
-                        "kv_blocks_cached": self._prefix.block_count(),
-                        # cache-ONLY (reclaimable) blocks; a cached
-                        # block shared with a live request counts as
-                        # used in the occupancy gauges, not cached
-                        "kv_blocks_idle_cached": (
-                            self._prefix.idle_block_count()
-                        ),
-                        "kv_demand_pages": self._demand_pages,
-                        # internal fragmentation of the LIVE allocation:
-                        # allocated-but-unwritten token slots (page-tail
-                        # waste) over allocated token slots
-                        "kv_fragmentation": round(
-                            1.0 - used_tokens / (alloc_pages * self._block),
-                            4,
-                        )
-                        if alloc_pages
-                        else 0.0,
-                        "prefix_hits": self._prefix_hits,
-                        "prefix_misses": self._prefix_misses,
-                        "prefix_tokens_saved": self._prefix_tokens_saved,
                     }
                 )
             return out
@@ -659,14 +550,6 @@ class GenerationEngine:
         with self._lock:
             queue_depth = len(self._queue)
             live = self._live
-            if not self._paged:
-                return {
-                    "model_id": self.model_id,
-                    "paged": False,
-                    "queue_depth": queue_depth,
-                    "live_slots": live,
-                    "balanced": queue_depth == 0 and live == 0,
-                }
             pool = self._pool.ledger()
             cached = self._prefix.block_count()
             chaos = len(self._chaos_blocks)
@@ -678,7 +561,6 @@ class GenerationEngine:
             )
             return {
                 "model_id": self.model_id,
-                "paged": True,
                 "queue_depth": queue_depth,
                 "live_slots": live,
                 "demand_pages": self._demand_pages,
@@ -707,8 +589,6 @@ class GenerationEngine:
         burst of long-context requests would. Returns how many are now
         held. Release with :meth:`chaos_release_blocks`; ledger() counts
         the holds so they can never masquerade as a clean drain."""
-        if not self._paged:
-            return 0
         grabbed: list[int] = []
         while n is None or len(grabbed) < n:
             got = self._pool.alloc(1)
@@ -734,16 +614,8 @@ class GenerationEngine:
         return self.programs.compile_count()
 
     def block_cost_bytes(self) -> int:
-        """Device bytes one of this engine's KV blocks really costs —
-        target layers plus the speculative draft's layers when spec
-        decode is on (the draft shares block ids, so a block carries
-        rows in BOTH pools). 0 on the contiguous path."""
-        if not self._paged:
-            return 0
-        extra = self._draft_cfg.n_layers if self._spec else 0
-        return pagedkv.block_bytes(
-            self.cfg, self._block, self._kv_dtype, extra_layers=extra
-        )
+        """Device bytes one of this engine's KV blocks costs."""
+        return pagedkv.block_bytes(self.cfg, self._block, self._kv_dtype)
 
     def _state_bytes(self) -> int:
         """Device bytes of the per-slot state beside the block pool."""
@@ -761,7 +633,7 @@ class GenerationEngine:
         reallocation (re-host or failure recovery) — the give-back is
         ADMISSION capacity first, bytes at the next rebuild
         (docs/SERVING.md §Live re-partitioning)."""
-        if not self._paged or n <= 0:
+        if n <= 0:
             return 0
         retired = self._pool.retire(n)
         while retired < n and self._prefix.evict_one():
@@ -777,22 +649,14 @@ class GenerationEngine:
         Must run before serving traffic (it drives the device directly;
         with live slots it backs off to lazy compilation instead of
         racing the engine thread for the donated cache buffers). The
-        garbage rows it writes land in free slots below their reset-at-
-        admission positions — invisible to every later request."""
+        block table is all zeros here, so every write it makes lands in
+        the trash block — invisible to every later request."""
         import jax.numpy as jnp
 
         with self._lock:
             if self._live > 0 or self._queue:
                 return
-        if self._spec_ignored:
-            logger.warning(
-                "engine %s: PYGRID_SPEC_DECODE is ignored — the model "
-                "keeps a recurrent state a rejected draft could not be "
-                "rolled back from", self.model_id,
-            )
-        if self._paged and not self._kv_kernel and (
-            self._runs_on["platform"] == "tpu"
-        ):
+        if not self._kv_kernel and self._runs_on["platform"] == "tpu":
             logger.warning(
                 "engine %s: decode attention takes the gather path on "
                 "this TPU (pool %s %s does not tile for the paged kernel)",
@@ -805,74 +669,33 @@ class GenerationEngine:
             if bucket in seen:
                 continue
             seen.add(bucket)
-            if self._spec:
-                # all-zero table: every warmup write lands in the
-                # trash block, so no future request can observe it
-                fn = self.programs.spec_prefill(bucket)
-                _tok, self._k, self._v, self._pos, self._dk, self._dv = fn(
-                    self.params, self._draft_params,
-                    self._k, self._v, self._pos, self._dk, self._dv,
-                    self._table(), jnp.int32(0),
-                    jnp.zeros((bucket,), jnp.int32), jnp.int32(0),
-                    jnp.int32(1), jnp.float32(0.0), zero_key,
-                )
-            elif self._paged:
-                fn = self.programs.paged_prefill(bucket)
-                _tok, self._k, self._v, self._pos, *self._state = fn(
-                    self.params, self._k, self._v, self._pos,
-                    *self._state, self._table(), jnp.int32(0),
-                    jnp.zeros((bucket,), jnp.int32), jnp.int32(0),
-                    jnp.int32(1), jnp.float32(0.0), zero_key,
-                )
-            else:
-                fn = self.programs.prefill(bucket)
-                _tok, self._k, self._v, self._pos = fn(
-                    self.params, self._k, self._v, self._pos,
-                    jnp.int32(0), jnp.zeros((bucket,), jnp.int32),
-                    jnp.int32(1), jnp.float32(0.0), zero_key,
-                )
+            fn = self.programs.paged_prefill(bucket)
+            _tok, self._k, self._v, self._pos, *self._state = fn(
+                self.params, self._k, self._v, self._pos,
+                *self._state, self._table(), jnp.int32(0),
+                jnp.zeros((bucket,), jnp.int32), jnp.int32(0),
+                jnp.int32(1), jnp.float32(0.0), zero_key,
+            )
         for w in self._widths:
-            if self._spec:
-                # a spec engine decodes ONLY through the verify program
-                # (all-frozen warmup: counts 0, writes trash-routed)
-                fn = self.programs.spec_verify(w, self._spec_k)
-                _e, _a, _c, self._k, self._v, self._pos, self._dk, self._dv = fn(
-                    self.params, self._draft_params, self._k, self._v,
-                    self._pos, self._dk, self._dv, self._table(),
+            fn = self.programs.paged_decode(w)
+            _toks, self._k, self._v, self._pos, *self._state = fn(
+                self.params, self._k, self._v, self._pos,
+                *self._state, self._table(), jnp.zeros((w,), jnp.int32),
+                jnp.zeros((w,), jnp.float32),
+                jnp.zeros((w, 2), jnp.uint32),
+            )
+            if self._fused:
+                # zero budgets: every row frozen, nothing advances
+                fn = self.programs.paged_decode_fused(
+                    w, self.config.quantum
+                )
+                _e, self._k, self._v, self._pos, *self._state = fn(
+                    self.params, self._k, self._v, self._pos,
+                    *self._state, self._table(),
                     jnp.zeros((w,), jnp.int32),
-                    jnp.zeros((w,), jnp.bool_),
+                    jnp.zeros((w,), jnp.int32),
                     jnp.zeros((w,), jnp.float32),
-                    jnp.zeros((w, self._spec_k, 2), jnp.uint32),
-                )
-            elif self._paged:
-                fn = self.programs.paged_decode(w)
-                _toks, self._k, self._v, self._pos, *self._state = fn(
-                    self.params, self._k, self._v, self._pos,
-                    *self._state, self._table(), jnp.zeros((w,), jnp.int32),
-                    jnp.zeros((w,), jnp.float32),
-                    jnp.zeros((w, 2), jnp.uint32),
-                )
-                if self._fused:
-                    # zero budgets: every row frozen, nothing advances
-                    fn = self.programs.paged_decode_fused(
-                        w, self.config.quantum
-                    )
-                    _e, self._k, self._v, self._pos, *self._state = fn(
-                        self.params, self._k, self._v, self._pos,
-                        *self._state, self._table(),
-                        jnp.zeros((w,), jnp.int32),
-                        jnp.zeros((w,), jnp.int32),
-                        jnp.zeros((w,), jnp.float32),
-                        jnp.zeros(
-                            (self.config.quantum, w, 2), jnp.uint32
-                        ),
-                    )
-            else:
-                fn = self.programs.decode(w)
-                _toks, self._k, self._v, self._pos = fn(
-                    self.params, self._k, self._v, self._pos,
-                    jnp.zeros((w,), jnp.int32), jnp.zeros((w,), jnp.float32),
-                    jnp.zeros((w, 2), jnp.uint32),
+                    jnp.zeros((self.config.quantum, w, 2), jnp.uint32),
                 )
 
     def close(self) -> None:
@@ -923,18 +746,7 @@ class GenerationEngine:
             clock.flush()
             try:
                 self._admit()
-                if self._spec and self._live:
-                    # speculative mode: each verify cycle advances every
-                    # live row by up to spec_k tokens in one dispatch;
-                    # the quantum still caps tokens between admission
-                    # checks (fairness is measured in emitted tokens)
-                    emitted = 0
-                    while emitted < self.config.quantum and self._live:
-                        done, freed = self._spec_cycle()
-                        emitted += max(1, done)
-                        if freed and self._queue:
-                            break
-                elif self._fused and self._live and not self._queue:
+                if self._fused and self._live and not self._queue:
                     # no admission pending: burn the whole quantum in
                     # ONE compiled scan — rows finishing mid-scan
                     # freeze (wasted steps accepted; zero dispatches
@@ -971,7 +783,7 @@ class GenerationEngine:
                 row = self._queue.popleft()
                 self._slots[slot] = row
                 self._live += 1
-            if self._paged and not self._assign_pages(slot, row):
+            if not self._assign_pages(slot, row):
                 # block pool exhausted even after prefix-cache
                 # eviction: park the row at the queue HEAD (FIFO order
                 # kept) until a completing request frees blocks — the
@@ -994,77 +806,42 @@ class GenerationEngine:
                 )
             request_id = row.pending.request_id
             t0 = time.perf_counter()
-            if self._paged:
-                chunk_len = len(row.prompt) - row.start
-                bucket = self._prompt_bucket(chunk_len)
-                padded = np.zeros(bucket, np.int32)
-                padded[:chunk_len] = row.prompt[row.start :]
-                # tokens the prefill program computes over against the
-                # prompt's own: the bucket's padding is real device work
-                telemetry.incr_many(
-                    "serving_prefill_tokens_total", "kind",
-                    {"true": chunk_len, "padded": bucket},
+            chunk_len = len(row.prompt) - row.start
+            bucket = self._prompt_bucket(chunk_len)
+            padded = np.zeros(bucket, np.int32)
+            padded[:chunk_len] = row.prompt[row.start :]
+            # tokens the prefill program computes over against the
+            # prompt's own: the bucket's padding is real device work
+            telemetry.incr_many(
+                "serving_prefill_tokens_total", "kind",
+                {"true": chunk_len, "padded": bucket},
+            )
+            if self._recurrent:
+                # the prefill writes the slot's whole state
+                telemetry.incr(
+                    "serving_state_bytes_total",
+                    self._state_per_slot, kind="written",
                 )
-                if self._recurrent:
-                    # the prefill writes the slot's whole state
-                    telemetry.incr(
-                        "serving_state_bytes_total",
-                        self._state_per_slot, kind="written",
-                    )
-                # the program's small arguments go to the device under
-                # ``admit``: ``prefill`` begins at the program's call
-                args = (
-                    self._table(), jnp.int32(slot), jnp.asarray(padded),
-                    jnp.int32(row.start), jnp.int32(len(row.prompt)),
-                    jnp.float32(row.temperature), self._key_for(row, 0),
-                )
-                if self._spec:
-                    # spec admission prefills the DRAFT cache too (it
-                    # needs the prompt's k/v before it can propose) —
-                    # one program, first token still from the target
-                    fn = self.programs.spec_prefill(bucket)
-                    clock.enter(
-                        "prefill", request_id=request_id, bucket=bucket
-                    )
-                    # gridlint: disable-next=GL202 — cache buffers are engine-thread-confined
-                    tok, self._k, self._v, self._pos, self._dk, self._dv = fn(
-                        self.params, self._draft_params,
-                        self._k, self._v, self._pos, self._dk, self._dv,
-                        *args,
-                    )
-                else:
-                    fn = self.programs.paged_prefill(bucket)
-                    clock.enter(
-                        "prefill", request_id=request_id, bucket=bucket
-                    )
-                    # the cache buffers are single-writer: only the
-                    # engine thread swaps _k/_v/_pos between lock epochs
-                    # gridlint: disable-next=GL202
-                    tok, self._k, self._v, self._pos, *self._state = fn(
-                        self.params, self._k, self._v, self._pos,
-                        *self._state, *args,
-                    )
-                # publish the full-prompt pages for future prefix hits
-                # (first prefill wins; a matched chain is only touched)
-                # gridlint: disable-next=GL202 — PrefixCache takes its own lock; only the engine thread mutates it
-                self._prefix.insert(row.prompt, row.pages)
-            else:
-                bucket = self._prompt_bucket(len(row.prompt))
-                padded = np.zeros(bucket, np.int32)
-                padded[: len(row.prompt)] = row.prompt
-                fn = self.programs.prefill(bucket)
-                args = (
-                    jnp.int32(slot), jnp.asarray(padded),
-                    jnp.int32(len(row.prompt)),
-                    jnp.float32(row.temperature), self._key_for(row, 0),
-                )
-                clock.enter(
-                    "prefill", request_id=request_id, bucket=bucket
-                )
-                # gridlint: disable-next=GL202 — engine-thread-confined
-                tok, self._k, self._v, self._pos = fn(
-                    self.params, self._k, self._v, self._pos, *args
-                )
+            # the program's small arguments go to the device under
+            # ``admit``: ``prefill`` begins at the program's call
+            args = (
+                self._table(), jnp.int32(slot), jnp.asarray(padded),
+                jnp.int32(row.start), jnp.int32(len(row.prompt)),
+                jnp.float32(row.temperature), self._key_for(row, 0),
+            )
+            fn = self.programs.paged_prefill(bucket)
+            clock.enter("prefill", request_id=request_id, bucket=bucket)
+            # the cache buffers are single-writer: only the engine
+            # thread swaps _k/_v/_pos between lock epochs
+            # gridlint: disable-next=GL202
+            tok, self._k, self._v, self._pos, *self._state = fn(
+                self.params, self._k, self._v, self._pos,
+                *self._state, *args,
+            )
+            # publish the full-prompt pages for future prefix hits
+            # (first prefill wins; a matched chain is only touched)
+            # gridlint: disable-next=GL202 — PrefixCache takes its own lock; only the engine thread mutates it
+            self._prefix.insert(row.prompt, row.pages)
             first = int(tok)
             clock.enter("emit")
             telemetry.observe(
@@ -1136,10 +913,10 @@ class GenerationEngine:
 
     def _live_snapshot(self) -> tuple[list[tuple[int, "_Row"]], int]:
         """(live (slot, row) pairs, covering width bucket) for one
-        dispatch — shared by the per-step, fused-scan, and speculative
-        paths. Snapshot under the lock and never re-index self._slots
-        after releasing it (a close() that outwaited its join could
-        swap the list under us). Width 0 means nothing is live."""
+        dispatch — shared by the per-step and fused-scan paths.
+        Snapshot under the lock and never re-index self._slots after
+        releasing it (a close() that outwaited its join could swap the
+        list under us). Width 0 means nothing is live."""
         with self._lock:
             live = [
                 (i, r) for i, r in enumerate(self._slots) if r is not None
@@ -1168,21 +945,13 @@ class GenerationEngine:
             if row.keys is not None:
                 keys[i] = row.keys[len(row.out)]
         t0 = time.perf_counter()
-        if self._paged:
-            fn = self.programs.paged_decode(width)
-            # gridlint: disable-next=GL202 — cache buffers are engine-thread-confined
-            toks, self._k, self._v, self._pos, *self._state = fn(
-                self.params, self._k, self._v, self._pos, *self._state,
-                self._table(),
-                jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
-            )
-        else:
-            fn = self.programs.decode(width)
-            # gridlint: disable-next=GL202 — cache buffers are engine-thread-confined
-            toks, self._k, self._v, self._pos = fn(
-                self.params, self._k, self._v, self._pos,
-                jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
-            )
+        fn = self.programs.paged_decode(width)
+        # gridlint: disable-next=GL202 — cache buffers are engine-thread-confined
+        toks, self._k, self._v, self._pos, *self._state = fn(
+            self.params, self._k, self._v, self._pos, *self._state,
+            self._table(),
+            jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
+        )
         clock.enter("fetch")
         toks = np.asarray(toks)
         dt = time.perf_counter() - t0
@@ -1271,93 +1040,6 @@ class GenerationEngine:
                 model=self.model_id,
             )
 
-    def _spec_cycle(self) -> tuple[int, bool]:
-        """One speculative cycle: the truncated-layer draft proposes
-        ``spec_k`` tokens per live row and the full model verifies them
-        all in one wide block-table step (``programs.spec_verify`` — a
-        single compiled program including the draft's proposal scan).
-        Returns (most tokens any row emitted, any slot freed). Engine
-        thread only."""
-        import jax.numpy as jnp
-
-        clock = self._clock
-        clock.enter("build")
-        live, width = self._live_snapshot()
-        if not live:
-            return 0, False
-        K = self._spec_k
-        clock.annotate(path="spec", width=width, live=len(live), steps=K)
-        tokens = np.zeros(width, np.int32)
-        temps = np.zeros(width, np.float32)
-        active = np.zeros(width, bool)
-        keys = np.zeros((width, K, 2), np.uint32)
-        for i, row in live:
-            tokens[i] = row.last_token
-            temps[i] = row.temperature
-            active[i] = True
-            if row.keys is not None:
-                done = len(row.out)
-                # per-position key schedule, clamped at the tail: a
-                # verify window reaching past n_new reuses the last
-                # key for tokens the drain below discards anyway
-                idx = np.minimum(
-                    np.arange(done, done + K), row.n_new - 1
-                )
-                keys[i] = row.keys[idx]
-        t0 = time.perf_counter()
-        fn = self.programs.spec_verify(width, K)
-        # gridlint: disable-next=GL202 — cache buffers are engine-thread-confined
-        emitted, accepted, counts, self._k, self._v, self._pos, self._dk, self._dv = fn(
-            self.params, self._draft_params, self._k, self._v,
-            self._pos, self._dk, self._dv, self._table(),
-            jnp.asarray(tokens), jnp.asarray(active),
-            jnp.asarray(temps), jnp.asarray(keys),
-        )
-        clock.enter("fetch")
-        emitted = np.asarray(emitted)
-        accepted = np.asarray(accepted)
-        counts = np.asarray(counts)
-        dt = time.perf_counter() - t0
-        clock.enter("emit")
-        self._note_dispatch("spec", width, live, K, dt)
-        telemetry.observe(
-            "serving_batch_occupancy", float(len(live)),
-            bounds=_OCCUPANCY_BOUNDS,
-        )
-        freed = False
-        max_emit = 0
-        proposed_total = 0
-        accepted_total = 0
-        for i, row in live:
-            m = min(int(counts[i]), row.n_new - len(row.out))
-            max_emit = max(max_emit, m)
-            proposed_total += K
-            # acceptance the row could USE: proposals verified past the
-            # row's n_new are wasted verify width, not wins — the
-            # acceptance-rate gauge must not flatter the draft
-            accepted_total += min(int(accepted[i]), m)
-            for j in range(m):
-                telemetry.observe(
-                    "serving_token_seconds", dt / max(1, int(counts[i]))
-                )
-                if self._emit(i, row, int(emitted[i, j])):
-                    freed = True
-        with self._lock:
-            self._spec_verifies += 1
-            self._spec_proposed += proposed_total
-            self._spec_accepted += accepted_total
-        telemetry.incr("serving_spec_verifies_total", model=self.model_id)
-        telemetry.incr(
-            "serving_spec_proposed_total", proposed_total,
-            model=self.model_id,
-        )
-        if accepted_total:
-            telemetry.incr(
-                "serving_spec_accepted_total", accepted_total,
-                model=self.model_id,
-            )
-        return max_emit, freed
-
     def _note_dispatch(
         self,
         path: str,
@@ -1370,7 +1052,7 @@ class GenerationEngine:
         (the rows still hold the lengths the program ran at): its
         seconds from the program's call to the tokens fetched, under its
         path and width bucket; the row-steps it computed against those
-        that belonged to an occupied slot; and, paged, the KV pages its
+        that belonged to an occupied slot; and the KV pages its
         attention read against the pages its block tables span."""
         telemetry.observe(
             "serving_dispatch_seconds", dt, path=path, width=str(width)
@@ -1379,19 +1061,18 @@ class GenerationEngine:
             "serving_dispatch_rowsteps_total", "kind",
             {"live": len(live) * steps, "computed": width * steps},
         )
-        if self._paged:
-            table = width * self._max_pages * steps
-            telemetry.incr_many(
-                "serving_kv_pages_total", "kind",
-                {
-                    "read": (
-                        self._kernel_pages(width, live, steps)
-                        if self._kv_kernel and path != "spec"
-                        else table  # the gather reads whole tables
-                    ),
-                    "table": table,
-                },
-            )
+        table = width * self._max_pages * steps
+        telemetry.incr_many(
+            "serving_kv_pages_total", "kind",
+            {
+                "read": (
+                    self._kernel_pages(width, live, steps)
+                    if self._kv_kernel
+                    else table  # the gather reads whole tables
+                ),
+                "table": table,
+            },
+        )
         if self._recurrent:
             # a live row's state is read and written once a step (rows
             # the width computes beyond the live ones are not counted:
@@ -1432,8 +1113,7 @@ class GenerationEngine:
         with self._lock:
             self._slots[slot] = None
             self._live = max(0, self._live - 1)
-        if self._paged:
-            self._release_row(slot, row)
+        self._release_row(slot, row)
         row.pending.finish_row(row.row, row.out)
         if row.pending.remaining == 0:
             telemetry.incr(
@@ -1459,11 +1139,8 @@ class GenerationEngine:
 
     def _fail_all(self, err: Exception, reset_cache: bool = True) -> None:
         cache = None
-        dcache = None
         snapshot = None
         if reset_cache:
-            from pygrid_tpu.models import decode
-
             # a failure path, not a clean close: capture the engine's
             # last state for the flight recorder BEFORE the slots are
             # wiped (the dump is the only record of who was in flight)
@@ -1471,74 +1148,59 @@ class GenerationEngine:
             # the failed program may have CONSUMED the donated cache
             # buffers before raising — reallocate so the engine serves
             # the next request instead of failing forever on deleted
-            # arrays (skipped on close: no one decodes again)
-            if self._paged:
-                # a live re-partition (shrink_blocks) is REALIZED in
-                # bytes here: the fresh arrays are sized to the
-                # shrunken pool, so the budget give-back stops being
-                # merely logical at the first cache reallocation
-                with self._lock:
-                    self._num_blocks = max(
-                        2, self._num_blocks - self._shrunk_blocks
-                    )
-                    self._shrunk_blocks = 0
-                cache = self._family.init_paged_cache(
-                    self.cfg, self.config.max_slots, self._num_blocks,
-                    self._block, dtype=self._kv_dtype,
+            # arrays (skipped on close: no one decodes again). A live
+            # re-partition (shrink_blocks) is REALIZED in bytes here:
+            # the fresh arrays are sized to the shrunken pool, so the
+            # budget give-back stops being merely logical at the first
+            # cache reallocation
+            with self._lock:
+                self._num_blocks = max(
+                    2, self._num_blocks - self._shrunk_blocks
                 )
-                if self._spec:
-                    dcache = decode.init_paged_cache(
-                        self._draft_cfg, self.config.max_slots,
-                        self._num_blocks, self._block,
-                        dtype=self._kv_dtype,
-                    )
-            else:
-                cache = decode.init_slot_cache(
-                    self.cfg, self.config.max_slots, dtype=self._kv_dtype
-                )
+                self._shrunk_blocks = 0
+            cache = self._family.init_paged_cache(
+                self.cfg, self.config.max_slots, self._num_blocks,
+                self._block, dtype=self._kv_dtype,
+            )
         with self._lock:
             rows = [r for r in self._slots if r is not None]
             rows.extend(self._queue)
             self._queue.clear()
             self._slots = [None] * self.config.max_slots
             self._live = 0
-            if self._paged:
-                self._demand_pages = 0
+            self._demand_pages = 0
             if cache is not None:
                 # the whole cache: a recurrent family's state with it
                 self._k, self._v, self._pos, *self._state = cache
-            if dcache is not None:
-                self._dk, self._dv = dcache.k, dcache.v
-        if self._paged:
-            if reset_cache:
-                # the device pool was reallocated: every cached prefix
-                # block now names stale (zeroed) data — rebuild the
-                # allocator and drop the prefix cache wholesale (engine
-                # thread only; every request future already failed above)
-                # _num_blocks was already rebased above (shrunk blocks
-                # realized in the fresh arrays), so the new pool simply
-                # matches the new device allocation
-                # gridlint: disable-next=GL202 — engine-thread-confined swap, requests already failed
-                self._pool = pagedkv.BlockPool(self._num_blocks)
-                # gridlint: disable-next=GL202 — engine-thread-confined swap, requests already failed
-                self._prefix = pagedkv.PrefixCache(
-                    self._pool, self._block,
-                    shareable=not self._recurrent,
-                )
-                # chaos holds named the OLD pool; releasing those ids
-                # against the fresh allocator would be a refcount bug
-                # gridlint: disable-next=GL202 — engine-thread-confined swap, requests already failed
-                self._chaos_blocks = []
-            else:
-                # clean close: refcounts must balance exactly (the
-                # leak test rides on this) — release each admitted
-                # row's pages individually
-                for row in rows:
-                    if row.pages is not None:
-                        self._pool.release(row.pages)
-                        row.pages = None
-            self._table_np[:] = 0
-            self._table_dirty = True
+        if reset_cache:
+            # the device pool was reallocated: every cached prefix
+            # block now names stale (zeroed) data — rebuild the
+            # allocator and drop the prefix cache wholesale (engine
+            # thread only; every request future already failed above)
+            # _num_blocks was already rebased above (shrunk blocks
+            # realized in the fresh arrays), so the new pool simply
+            # matches the new device allocation
+            # gridlint: disable-next=GL202 — engine-thread-confined swap, requests already failed
+            self._pool = pagedkv.BlockPool(self._num_blocks)
+            # gridlint: disable-next=GL202 — engine-thread-confined swap, requests already failed
+            self._prefix = pagedkv.PrefixCache(
+                self._pool, self._block,
+                shareable=not self._recurrent,
+            )
+            # chaos holds named the OLD pool; releasing those ids
+            # against the fresh allocator would be a refcount bug
+            # gridlint: disable-next=GL202 — engine-thread-confined swap, requests already failed
+            self._chaos_blocks = []
+        else:
+            # clean close: refcounts must balance exactly (the
+            # leak test rides on this) — release each admitted
+            # row's pages individually
+            for row in rows:
+                if row.pages is not None:
+                    self._pool.release(row.pages)
+                    row.pages = None
+        self._table_np[:] = 0
+        self._table_dirty = True
         failed: dict[int, str] = {}
         for row in rows:
             if id(row.pending) not in failed:

@@ -58,64 +58,16 @@ def resolve_block_size(max_len: int, requested: int | None = None) -> int:
     return block
 
 
-def paged_enabled(requested: bool | None = None) -> bool:
-    """Paged storage is the default; ``PYGRID_KV_PAGED=off|0`` (or an
-    explicit ``EngineConfig.paged=False``) falls back to the contiguous
-    slot cache — the operational escape hatch and the bench baseline."""
-    if requested is not None:
-        return bool(requested)
-    return os.environ.get("PYGRID_KV_PAGED", "").lower() not in ("off", "0")
-
-
 def fused_enabled(requested: bool | None = None) -> bool:
     """Fused multi-step decode (one ``lax.scan`` program per quantum of
-    decode steps) is the default on the paged path;
-    ``PYGRID_FUSED_DECODE=off|0`` (or ``EngineConfig(fused=False)``)
-    reverts to one dispatch per step — the PR-3/7 behavior and the
-    bench baseline for the dispatch-overhead comparison."""
+    decode steps) is the default; ``PYGRID_FUSED_DECODE=off|0`` (or
+    ``EngineConfig(fused=False)``) reverts to one dispatch per step —
+    the scan's reference in the parity tests."""
     if requested is not None:
         return bool(requested)
     return os.environ.get(
         "PYGRID_FUSED_DECODE", ""
     ).lower() not in ("off", "0")
-
-
-def spec_enabled(requested: bool | None = None) -> bool:
-    """Self-speculative decoding is OPT-IN per deployment
-    (``PYGRID_SPEC_DECODE=on|1`` or ``EngineConfig(spec_decode=True)``):
-    whether a truncated-layer draft wins depends on the checkpoint (the
-    acceptance-rate telemetry is how operators find out), so it never
-    silently becomes the default."""
-    if requested is not None:
-        return bool(requested)
-    return os.environ.get(
-        "PYGRID_SPEC_DECODE", ""
-    ).lower() in ("on", "1", "true")
-
-
-def resolve_spec_k(requested: int | None = None) -> int:
-    """Draft proposals per verify step (``PYGRID_SPEC_K``, default 4),
-    clamped to [1, 16] — the verify pass widens linearly with k, and a
-    typo must not compile a 1000-wide program."""
-    if requested is None:
-        try:
-            requested = int(os.environ.get("PYGRID_SPEC_K", ""))
-        except (TypeError, ValueError):
-            requested = 4
-    return max(1, min(int(requested), 16))
-
-
-def resolve_spec_layers(n_layers: int, requested: int | None = None) -> int:
-    """Draft depth (``PYGRID_SPEC_LAYERS``, default: half the stack,
-    floor 1), clamped to [1, n_layers - 1] so the draft is always a
-    strict truncation — a draft as deep as the target proposes at full
-    cost and can never win."""
-    if requested is None:
-        try:
-            requested = int(os.environ.get("PYGRID_SPEC_LAYERS", ""))
-        except (TypeError, ValueError):
-            requested = n_layers // 2
-    return max(1, min(int(requested), max(1, n_layers - 1)))
 
 
 def default_cache_dtype() -> Any:
@@ -132,7 +84,7 @@ def default_cache_dtype() -> Any:
 def parse_budget_bytes(raw: str | None) -> int | None:
     """``PYGRID_KV_BUDGET`` parse: plain bytes or K/M/G-suffixed
     (``256M``, ``1.5G``). None/typo → None (no unified budget; each
-    engine sizes its pool to contiguous parity)."""
+    engine sizes its pool to ``max_slots`` full-length requests)."""
     if not raw:
         return None
     raw = raw.strip()
@@ -165,14 +117,10 @@ def parse_weights(raw: str | None) -> dict[str, float]:
     return out
 
 
-def block_bytes(cfg, block: int, dtype: Any, extra_layers: int = 0) -> int:
+def block_bytes(cfg, block: int, dtype: Any) -> int:
     """Device bytes one KV block costs for ``cfg``: k AND v, every layer
     that holds them (the family says which: a hybrid's state-space
-    layers hold none) — the unit the budget partitions. ``extra_layers``
-    adds the speculative DRAFT's layers: the draft shares the pool's
-    block ids (same tables, its own k/v arrays), so a block's true
-    device cost when spec decode is on is target layers + draft
-    layers."""
+    layers hold none) — the unit the budget partitions."""
     import jax.numpy as jnp
 
     from pygrid_tpu.models import decode
@@ -180,8 +128,8 @@ def block_bytes(cfg, block: int, dtype: Any, extra_layers: int = 0) -> int:
     model = decode.family_of(cfg)
     dh = cfg.d_model // cfg.n_heads
     return int(
-        2 * (model.kv_layers(cfg) + extra_layers) * block
-        * model.kv_heads(cfg) * dh * jnp.dtype(dtype).itemsize
+        2 * model.kv_layers(cfg) * block * model.kv_heads(cfg) * dh
+        * jnp.dtype(dtype).itemsize
     )
 
 
@@ -530,11 +478,11 @@ class DeviceBudget:
         self, model_id: str, bytes_per_block: int, fixed_bytes: int = 0
     ) -> int | None:
         """The block count ``model_id``'s engine should allocate, or
-        None when no budget is configured (engine falls back to
-        contiguous-parity sizing). ``fixed_bytes`` is what the model
-        holds per slot beside the pool (a recurrent state): it comes
-        out of the model's share FIRST, and blocks are granted from the
-        rest. Always grants at least one block beyond trash so a
+        None when no budget is configured (the engine then sizes its
+        pool to ``max_slots`` full-length requests). ``fixed_bytes`` is
+        what the model holds per slot beside the pool (a recurrent
+        state): it comes out of the model's share FIRST, and blocks are
+        granted from the rest. Always grants at least one block beyond trash so a
         registered model can serve SOMETHING."""
         if self.total_bytes is None or bytes_per_block <= 0:
             return None

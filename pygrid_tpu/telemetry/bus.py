@@ -127,7 +127,7 @@ _FAMILY_HELP: dict[str, str] = {
         "fetch, emit) — the phases partition the thread's time"
     ),
     "serving_dispatch_seconds": (
-        "one decode dispatch (step, fused scan or spec cycle), from the "
+        "one decode dispatch (step or fused scan), from the "
         "program's call to its tokens fetched, by path and width bucket"
     ),
     "serving_dispatch_rowsteps_total": (
@@ -147,7 +147,7 @@ _FAMILY_HELP: dict[str, str] = {
         "prompt tokens NOT re-prefilled thanks to prefix hits, by model"
     ),
     "serving_blocks_per_request": "KV pool blocks held per admitted request",
-    # fused multi-step + speculative decode (docs/SERVING.md)
+    # fused multi-step decode (docs/SERVING.md)
     "serving_fused_scans_total": (
         "fused multi-step decode scans dispatched, by model"
     ),
@@ -156,16 +156,6 @@ _FAMILY_HELP: dict[str, str] = {
     ),
     "serving_fused_wasted_steps_total": (
         "frozen row-steps burned by rows finishing mid-scan, by model"
-    ),
-    "serving_spec_verifies_total": (
-        "speculative draft-propose + verify cycles, by model"
-    ),
-    "serving_spec_proposed_total": (
-        "draft tokens proposed for verification, by model"
-    ),
-    "serving_spec_accepted_total": (
-        "draft tokens accepted by the target model, by model — "
-        "accepted/proposed is the per-model acceptance rate"
     ),
     "slo_webhook_posts_total": (
         "SLO breach-webhook deliveries, by objective and outcome"

@@ -3,7 +3,7 @@
 N concurrent websocket clients issue mixed-length greedy generation
 requests against one hosted bundle and must get EXACTLY the tokens the
 sequential single-request path produces — the end-to-end proof that the
-shared slot cache leaks nothing across concurrently-decoding requests.
+shared block pool leaks nothing across concurrently-decoding requests.
 Plus: the async HTTP door, typed backpressure over the wire, and the
 new serving metrics families under the strict Prometheus parser.
 """
@@ -11,8 +11,11 @@ new serving metrics families under the strict Prometheus parser.
 from __future__ import annotations
 
 import base64
+import json
+import sys
 import threading
 import time
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -46,7 +49,7 @@ def hosted(grid):
 
 def _cases(n, seed=0):
     """Mixed prompt lengths and n_new — every (len, n_new) distinct
-    enough that the legacy path would compile per request."""
+    enough that a program per request shape would compile each time."""
     rng = np.random.RandomState(seed)
     return [
         (
@@ -196,3 +199,151 @@ def test_serving_metrics_families_strictly_valid(grid, hosted):
     assert engine["tokens_total"] > 0
     assert engine["requests_total"] >= 10
     assert engine["compiles_total"] > 0
+
+
+def test_block_pool_gauges_are_there_for_every_engine(grid, hosted):
+    """``/metrics`` shows the block pool of every engine, whatever it
+    serves: free + used + cached is the pool ``/telemetry/serving``
+    reports, beside the page size and the live pages' tail waste."""
+    base = grid.node_url("dan")
+    _, client = hosted
+    client.run_remote_generation(MODEL_ID, np.array([[5, 6, 7]]), n_new=3)
+    families = promtext.parse(
+        requests.get(base + "/metrics", timeout=10).text
+    )
+    rows = requests.get(base + "/telemetry/serving", timeout=10).json()
+    assert rows["engines"]
+    for row in rows["engines"]:
+
+        def mine(name, model=row["model_id"]):
+            return [
+                (labels, value)
+                for _, labels, value in families[name].samples
+                if labels.get("model") == model
+            ]
+
+        by_state = {
+            labels["state"]: value
+            for labels, value in mine("pygrid_serving_kv_blocks")
+        }
+        assert set(by_state) == {"free", "used", "cached"}
+        assert sum(by_state.values()) == row["kv_blocks_total"]
+        ((_, tokens),) = mine("pygrid_serving_kv_block_tokens")
+        assert tokens == row["block_size"]
+        ((_, waste),) = mine("pygrid_serving_kv_fragmentation")
+        assert 0.0 <= waste < 1.0
+        assert families["pygrid_serving_kv_blocks"].type == "gauge"
+
+
+# ── two model families on one node ───────────────────────────────────────
+
+
+@pytest.mark.parametrize("first", ["transformer", "recurrent"])
+def test_two_families_are_served_through_one_door_under_one_budget(
+    first, monkeypatch
+):
+    """A transformer bundle and a bundle of the family with a recurrent
+    state, hosted on ONE node under one ``PYGRID_KV_BUDGET``, in either
+    order: ``run-generation`` answers both through their engines, each
+    to its own reference (``generate()`` bit for bit; the benchmark's
+    plain float32 adapter to the logit tolerance of
+    ``tests/unit/test_jamba_serving.py``). The recurrent family's fixed
+    per-slot state is charged to its share before any block, neither
+    share runs past its half by more than a block, and both ledgers
+    close. Nothing at the door tells the families apart."""
+    import jax.numpy as jnp
+
+    from pygrid_tpu.models import jamba
+    from pygrid_tpu.node import create_app
+    from pygrid_tpu.serving import pagedkv
+
+    from .conftest import ServerThread, _free_port
+
+    root = Path(__file__).resolve().parents[2]
+    if str(root / "perfbench") not in sys.path:
+        sys.path.append(str(root / "perfbench"))
+    from lib import reference, spec
+
+    adapter = spec.load_model("jamba")
+    cfg = json.loads(
+        (root / "perfbench/configs/ai21-jamba2-3b.json").read_text()
+    )
+    cfg.update(adapter.tiny(cfg))
+    jcfg = adapter.jamba_config(cfg)
+    jparams = adapter.make_program_params(3, cfg, "float32")
+    jweights = adapter.make_weights(3, cfg, "float32")
+    tparams = T.init(jax.random.PRNGKey(11), CFG)
+
+    slots, block = 2, 16
+    state = pagedkv.state_bytes(jcfg, slots, jnp.float32)
+    per_block = {
+        "recurrent": pagedkv.block_bytes(jcfg, block, jnp.float32),
+        "transformer": pagedkv.block_bytes(CFG, block, jnp.float32),
+    }
+    assert state > 0
+    budget = 2 * (state + 16 * per_block["recurrent"])
+    monkeypatch.setenv("PYGRID_KV_BUDGET", str(budget))
+    monkeypatch.setenv("PYGRID_KV_BLOCK", str(block))
+    monkeypatch.setenv("PYGRID_SERVING_SLOTS", str(slots))
+
+    bundles = {
+        "transformer": decode.bundle(CFG, tparams),
+        "recurrent": jamba.bundle(jcfg, jparams),
+    }
+    rng = np.random.RandomState(5)
+    prompts = {
+        "transformer": rng.randint(0, CFG.vocab, size=(1, 7)),
+        "recurrent": rng.randint(0, jcfg.vocab, size=(1, 21)),
+    }
+    order = [first] + [f for f in bundles if f != first]
+
+    server = ServerThread(create_app("two-families"), _free_port()).start()
+    client = DataCentricFLClient(server.url)
+    try:
+        for family in order:
+            out = client.serve_model(
+                bundles[family], family, allow_remote_inference=True
+            )
+            assert out.get("success"), out
+        served = {
+            family: np.asarray(
+                client.run_remote_generation(
+                    family, prompts[family], n_new=6
+                )
+            )
+            for family in order + order  # each engine again, once warm
+        }
+        np.testing.assert_array_equal(
+            served["transformer"],
+            np.asarray(decode.generate(
+                tparams, prompts["transformer"].astype(np.int32), 6, CFG
+            )),
+        )
+        gaps, _ = reference.served_gaps(
+            adapter, jweights, cfg, prompts["recurrent"],
+            served["recurrent"], 32,
+        )
+        assert gaps.max() <= 5e-5, gaps
+
+        serving = server.app["node"].serving
+        rows = {row["model_id"]: row for row in serving.stats()}
+        assert set(rows) == set(bundles)
+        held = serving.budget.snapshot()["allocated_bytes"]
+        for family, row in rows.items():
+            fixed = state if family == "recurrent" else 0
+            assert row["state_bytes"] == fixed
+            assert row["requests_total"] == 2 and row["compiles_total"] > 0
+            # what the budget holds against the model: its fixed state,
+            # then its blocks (the trash block with them)
+            assert held[family] == fixed + (
+                row["kv_blocks_total"] + 1
+            ) * per_block[family], (family, held, row)
+            assert held[family] <= budget // 2 + per_block[family]
+        assert sum(held.values()) <= budget
+        ledger = serving.ledger()
+        assert ledger["balanced"], ledger
+        for led in ledger["engines"]:
+            assert led["drained"], led
+    finally:
+        client.close()
+        server.stop()

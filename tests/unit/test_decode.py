@@ -117,28 +117,6 @@ def test_traced_temperature_zero_falls_back_to_greedy(setup):
     np.testing.assert_array_equal(np.asarray(hot), np.asarray(eager))
 
 
-def test_generation_jit_cache_evicts_lru_not_everything():
-    """Cache pressure (a client cycling trace-relevant keys) pops only
-    the least-recently-used compiled program; a hot entry that keeps
-    being touched survives (ADVICE #3 — .clear() let one client flush
-    every model's hot programs at once)."""
-    from pygrid_tpu.node.events import _GENERATION_JIT, _generation_fn
-
-    _GENERATION_JIT.clear()
-    try:
-        cfg_hot = (19, 8, 1, 1, 16, 8)
-        hot = _generation_fn(cfg_hot, 1, False)
-        for d_ff in range(100, 180):  # well past the 64-entry cap
-            _generation_fn((19, 8, 1, 1, d_ff, 8), 1, False)
-            # the hot program is touched between insertions, so LRU
-            # keeps it while cold entries rotate out
-            assert _generation_fn(cfg_hot, 1, False) is hot
-        assert len(_GENERATION_JIT) <= 64
-        assert (cfg_hot, 1, False) in _GENERATION_JIT
-    finally:
-        _GENERATION_JIT.clear()
-
-
 def test_run_generation_validates_seed_and_temperature(setup):
     """The serving endpoint bounces hostile seed/temperature values as
     typed {success: False} frames: seeds past int64 (ADVICE #1, formerly

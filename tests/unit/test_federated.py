@@ -558,6 +558,62 @@ def test_accumulator_matches_blob_rebuild(fresh_db):
     np.testing.assert_allclose(np.asarray(new[0]), expected, rtol=1e-5)
 
 
+def test_a_second_managers_readiness_check_is_its_own_task():
+    """Every database numbers its cycles from 1 and ``run_task_once``
+    keys are global to the process: while ANOTHER manager's check of
+    its cycle 1 is still in flight (a node beside this one, or the
+    previous test's deadline timer), this manager's report must still
+    complete its own cycle 1 before ``submit_diff`` returns — not be
+    queued as a rerun behind the other's and read stale."""
+    import threading as th
+
+    ctls = []
+    for name in ("mgr-a", "mgr-b"):
+        ctl = FLController(Database(":memory:"))
+        ctl.create_process(
+            model_blob=serialize_model_params(_model_params()),
+            client_plans={"training_plan": _training_plan()},
+            name=name,
+            version="1.0",
+            client_config=dict(CLIENT_CONFIG, name=name),
+            server_config=dict(
+                SERVER_CONFIG, min_workers=1, min_diffs=1, max_diffs=1,
+                num_cycles=1,
+            ),
+        )
+        ctls.append(ctl)
+    a, b = ctls
+    cycle_a = a.cycle_manager._cycles.first(is_completed=False)
+    cycle_b = b.cycle_manager._cycles.first(is_completed=False)
+    assert cycle_a.id == cycle_b.id  # the collision this test is about
+    started, gate = th.Event(), th.Event()
+
+    def held():
+        started.set()
+        gate.wait(10)
+
+    other = th.Thread(
+        target=tasks.run_task_once,
+        args=(a.cycle_manager._completion_key(cycle_a.id), held),
+    )
+    other.start()
+    try:
+        assert started.wait(5)
+        w = _register_worker(b, "solo")
+        resp = b.assign("mgr-b", "1.0", w)
+        diff = [np.full((10, 4), 0.5, np.float32), np.full(4, 0.5, np.float32)]
+        b.submit_diff("solo", resp[CYCLE.KEY], serialize_model_params(diff))
+        assert b.cycle_manager._cycles.first(id=cycle_b.id).is_completed
+        latest = b.model_manager.load(model_id=resp["model_id"], alias="latest")
+        np.testing.assert_allclose(
+            np.asarray(unserialize_model_params(latest.value)[0]),
+            _model_params()[0] - 0.5, rtol=1e-5,
+        )
+    finally:
+        gate.set()
+        other.join(10)
+
+
 def test_deadline_with_zero_diffs_closes_cycle_without_checkpoint(fresh_db):
     """No min_diffs + nobody reports: the deadline closes the cycle with
     the model unchanged (no checkpoint written) and spawns the next cycle —

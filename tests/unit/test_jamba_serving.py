@@ -22,7 +22,6 @@ dropped state and a bucket's garbage padding to the same limit.
 from __future__ import annotations
 
 import json
-import logging
 import sys
 from pathlib import Path
 
@@ -281,7 +280,7 @@ def test_engine_serves_the_references_tokens_staggered_and_reused(
         toks = eng.submit(again[None], 6, timeout=300)[0]
         assert _gaps(model, weights, cfg, again, toks).max() <= TOL
         stats = eng.stats()
-        assert stats["fused"] is fused and stats["spec"] is False
+        assert stats["fused"] is fused
         assert stats["kv_kernel"] == 0 and stats["prefix_hits"] == 0
         if fused:
             assert stats["fused_wasted_steps"] > 0  # rows froze mid-scan
@@ -355,20 +354,48 @@ def test_the_new_counters_and_the_telemetry_row(jcfg, params):
     assert _count("serving_state_bytes_total", kind="written") == 5 * per_slot
 
 
-def test_spec_decode_is_ignored_with_one_warm_up_line(jcfg, params, caplog):
-    eng = _engine(jcfg, params, spec_decode=True, max_slots=1, slot_buckets=(1,))
-    try:
-        with caplog.at_level(logging.WARNING, logger="pygrid_tpu.serving.engine"):
-            eng.warmup((8,))
-        assert eng.stats()["spec"] is False
-        said = [r for r in caplog.records if "PYGRID_SPEC_DECODE is ignored" in r.message]
-        assert len(said) == 1
-        # and paged storage is not a choice for this family
-        off = _engine(jcfg, params, paged=False, max_slots=1, slot_buckets=(1,))
-        assert off.stats()["paged"] is True
-        off.close()
-    finally:
-        eng.close()
+def test_prefix_sharing_is_the_one_thing_a_recurrent_state_switches_off(
+    model, cfg, jcfg, params, weights
+):
+    """The same pair of requests over a shared two-page prefix, through
+    the same engine code, on both families. The transformer maps the
+    prefix's pages into the second request (one hit, two pages saved);
+    the family with a recurrent state shares nothing, and is still
+    right to ``TOL``. Everything else the stats row says of the two
+    engines' paths is the same."""
+    from pygrid_tpu.models import transformer as T
+
+    prefix = _tokens(70, 2 * BLOCK)
+    prompts = [np.concatenate([prefix, _tokens(71 + i, 4 + i)]) for i in range(2)]
+    tcfg = T.TransformerConfig(
+        vocab=jcfg.vocab, d_model=16, n_heads=2, n_layers=2, d_ff=32,
+        max_len=jcfg.max_len,
+    )
+    tparams = T.init(jax.random.PRNGKey(7), tcfg)
+    rows = {}
+    for name, ecfg, eparams in (
+        ("transformer", tcfg, tparams), ("recurrent", jcfg, params),
+    ):
+        eng = _engine(ecfg, eparams, model_id=name)
+        try:
+            served = [eng.submit(p[None], 4, timeout=300)[0] for p in prompts]
+            rows[name] = eng.stats()
+        finally:
+            eng.close()
+        for prompt, toks in zip(prompts, served):
+            if name == "recurrent":
+                assert _gaps(model, weights, cfg, prompt, toks).max() <= TOL
+            else:
+                ref = decode.generate(tparams, prompt[None], 4, tcfg)
+                np.testing.assert_array_equal(toks, np.asarray(ref)[0])
+    assert rows["transformer"]["prefix_hits"] == 1
+    assert rows["transformer"]["prefix_tokens_saved"] == 2 * BLOCK
+    assert rows["recurrent"]["prefix_hits"] == 0
+    assert rows["recurrent"]["prefix_misses"] == 2
+    assert rows["recurrent"]["prefix_tokens_saved"] == 0
+    for key in ("fused", "kv_kernel", "block_size", "max_slots"):
+        assert rows["transformer"][key] == rows["recurrent"][key], key
+    assert rows["recurrent"]["fused_scans"] > 0  # the scan serves both
 
 
 def test_the_engine_recovers_its_state_arrays_after_a_failed_dispatch(

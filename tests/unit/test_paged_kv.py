@@ -53,7 +53,7 @@ def _ref(params, prompt, n_new, **kw):
 def _paged_engine(params, **over):
     kw = dict(
         max_slots=4, slot_buckets=(1, 2, 4), min_prompt_bucket=8,
-        paged=True, block_size=8,
+        block_size=8,
     )
     kw.update(over)
     return GenerationEngine(CFG, params, EngineConfig(**kw), model_id="pg")
@@ -156,7 +156,6 @@ def test_paged_greedy_bit_identical_and_fragmentation_gauges(params):
             got = eng.submit(np.array(p), n)
             np.testing.assert_array_equal(got, _ref(params, p, n))
         stats = eng.stats()
-        assert stats["paged"] is True
         assert stats["kv_blocks_free"] >= 0
         assert stats["block_size"] == 8
     finally:
@@ -351,17 +350,78 @@ def test_paged_zero_recompiles_across_prefix_variety(params):
         eng.close()
 
 
-def test_paged_off_env_falls_back_to_contiguous(params, monkeypatch):
-    monkeypatch.setenv("PYGRID_KV_PAGED", "off")
-    eng = GenerationEngine(
-        CFG, params,
-        EngineConfig(max_slots=2, slot_buckets=(1, 2), min_prompt_bucket=8),
-        model_id="legacy",
+def test_pool_of_s_full_slots_holds_three_times_as_many_short_requests(params):
+    """What paging buys, as a count of blocks: a pool with the bytes S
+    full-length requests would take (``1 + S × max_pages`` blocks)
+    keeps at least 3 S short requests (prompt + n_new ≤ max_len / 4)
+    LIVE AT ONCE — none parked for want of a page, none answered
+    busy. The first prefill is held until every request is queued, so
+    one admission pass sees them all; liveness is read at the first
+    decode dispatch after it."""
+    S = 2
+    max_pages = CFG.max_len // 8
+    eng = _paged_engine(
+        params, max_slots=4 * S, slot_buckets=(4 * S,),
+        num_blocks=1 + S * max_pages,
     )
+    gate = threading.Event()
+    seen: list[dict] = []
+    prefill = eng.programs.paged_prefill
+    step, scan = eng.programs.paged_decode, eng.programs.paged_decode_fused
+
+    def held_prefill(bucket):
+        gate.wait(30)
+        return prefill(bucket)
+
+    def watched(builder):
+        def build(*key):
+            seen.append(eng.stats())
+            return builder(*key)
+
+        return build
+
+    eng.programs.paged_prefill = held_prefill
+    eng.programs.paged_decode = watched(step)
+    eng.programs.paged_decode_fused = watched(scan)
     try:
-        assert eng.stats()["paged"] is False
-        got = eng.submit(np.array([[3, 5, 2]]), 4)
-        np.testing.assert_array_equal(got, _ref(params, [[3, 5, 2]], 4))
+        short = CFG.max_len // 4
+        futures = [
+            eng.enqueue(np.array([[1 + i, 2, 3]]), short - 3)
+            for i in range(4 * S)
+        ]  # no ServerBusyError: none answered busy
+        gate.set()
+        for i, f in enumerate(futures):
+            np.testing.assert_array_equal(
+                f.result(timeout=60),
+                _ref(params, [[1 + i, 2, 3]], short - 3),
+            )
+        first = seen[0]
+        assert first["live_slots"] >= 3 * S, first
+        assert first["queue_depth"] == 0, "a short request was parked"
+        assert first["kv_blocks_total"] == S * max_pages
+        led = eng.ledger()
+        assert led["drained"] and led["balanced"], led
+    finally:
+        gate.set()
+        eng.close()
+
+
+def test_eight_requests_over_one_prefix_share_seven_times(params):
+    """One shared page-aligned prefix under eight different suffixes:
+    the first request prefills it, each of the other seven maps its page
+    read-only and skips that page's prefill — seven hits, seven whole
+    pages of prompt never recomputed, every answer its reference's."""
+    common = [3, 5, 2, 9, 11, 4, 7, 1]  # one 8-token page
+    eng = _paged_engine(params)
+    try:
+        for i in range(8):
+            p = common + [12 + i, 1 + i]
+            got = eng.submit(np.array([p]), 3)
+            np.testing.assert_array_equal(got, _ref(params, [p], 3))
+        stats = eng.stats()
+        assert stats["prefix_hits"] >= 7, stats
+        assert stats["prefix_misses"] == 1, stats
+        assert stats["prefix_tokens_saved"] >= 7 * stats["block_size"]
     finally:
         eng.close()
 
@@ -440,7 +500,7 @@ def test_manager_repartitions_live_engines_on_late_registration(params):
     mgr = ServingManager(
         EngineConfig(
             max_slots=2, slot_buckets=(1, 2), min_prompt_bucket=8,
-            paged=True, block_size=16, cache_dtype=jnp.float32,
+            block_size=16, cache_dtype=jnp.float32,
         ),
         budget=budget,
     )

@@ -11,7 +11,10 @@ bounded queue answers typed backpressure instead of piling up.
 
 from __future__ import annotations
 
+import ast
+import re
 import threading
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -94,8 +97,7 @@ def test_concurrent_mixed_requests_no_cross_slot_leakage(engine, params):
 def test_shape_variety_within_buckets_zero_recompiles(engine, params):
     """The tentpole compile contract: after warmup, varying n_new,
     prompt length (within one prompt bucket), temperature and seed
-    compiles NOTHING — vs. the legacy path's one XLA program per
-    distinct n_new."""
+    compiles NOTHING."""
     engine.warmup(prompt_lens=(1, 8))
     before = engine.compile_count()
     for i, (p_len, n_new) in enumerate(
@@ -222,29 +224,92 @@ def test_manager_rebuilds_engine_on_rehost():
         mgr.close()
 
 
-def test_engine_recovers_after_device_loop_failure(params):
-    """A failed program call may have consumed the donated cache
-    buffers — the engine must fail the in-flight requests typed AND
-    keep serving afterwards (fresh cache), not die on deleted arrays."""
+@pytest.mark.parametrize(
+    "program, fused",
+    [
+        ("paged_prefill", True),
+        ("paged_decode", False),
+        ("paged_decode_fused", True),
+    ],
+)
+def test_engine_recovers_after_device_loop_failure(params, program, fused):
+    """Whichever of the engine's three programs fails: it RUNS (so the
+    donated cache buffers are consumed) and then raises, once. The
+    in-flight request fails typed, the engine holds a fresh zeroed cache
+    instead of the deleted arrays, the next request equals
+    ``generate()`` and the block ledger closes."""
+    import jax.numpy as jnp
+
     eng = GenerationEngine(
         CFG,
         params,
-        EngineConfig(max_slots=1, slot_buckets=(1,), min_prompt_bucket=8),
-        model_id="boom",
+        EngineConfig(
+            max_slots=1, slot_buckets=(1,), min_prompt_bucket=8,
+            block_size=8, fused=fused,
+        ),
+        model_id=f"boom-{program}",
     )
     try:
-        original = eng.programs.paged_prefill
+        builder = getattr(eng.programs, program)
+        calls = []
 
-        def boom(bucket):
-            raise RuntimeError("injected device failure")
+        def failing(*key):
+            fn = builder(*key)
 
-        # the paged program is the default admission path
-        eng.programs.paged_prefill = boom
+            def run_then_raise(*args):
+                fn(*args)
+                calls.append(key)
+                raise RuntimeError("injected device failure")
+
+            return run_then_raise
+
+        setattr(eng.programs, program, failing)
         with pytest.raises(E.PyGridError, match="engine error"):
-            eng.submit(np.array([[1, 2]]), 2, timeout=30)
-        eng.programs.paged_prefill = original
-        got = eng.submit(np.array([[1, 2]]), 2, timeout=60)
-        np.testing.assert_array_equal(got, _ref(params, [[1, 2]], 2))
+            eng.submit(np.array([[1, 2]]), 4, timeout=30)
+        setattr(eng.programs, program, builder)
+        assert len(calls) == 1, "the named program is the one that failed"
+        for arr in (eng._k, eng._v, eng._pos):
+            assert not arr.is_deleted()
+            assert not np.asarray(jnp.abs(arr).sum())
+        stats = eng.stats()
+        assert stats["live_slots"] == 0 and stats["queue_depth"] == 0
+        assert stats["kv_blocks_free"] == stats["kv_blocks_total"]
+        got = eng.submit(np.array([[1, 2]]), 4, timeout=60)
+        np.testing.assert_array_equal(got, _ref(params, [[1, 2]], 4))
+        led = eng.ledger()
+        assert led["drained"] and led["balanced"], led
+    finally:
+        eng.close()
+
+
+def test_compiled_surface_is_buckets_plus_two_programs_a_width(params):
+    """What the engine ever compiles, counted: one prefill program per
+    prompt bucket asked for, and per decode width one step program and
+    one fused scan. Eight distinct ``n_new`` afterwards add none —
+    ``n_new`` is a host loop bound, never a shape."""
+    widths = (1, 2, 4)
+    eng = GenerationEngine(
+        CFG,
+        params,
+        EngineConfig(max_slots=4, slot_buckets=widths, min_prompt_bucket=8),
+        model_id="surface",
+    )
+    try:
+        eng.warmup(prompt_lens=(3, 8, 12, 30))  # buckets 8, 8, 16, 32
+        assert eng.compile_count() == 3 + 2 * len(widths)
+        built = eng.programs
+        assert (
+            len(built._paged_prefill),
+            len(built._paged_decode),
+            len(built._paged_fused),
+        ) == (3, len(widths), len(widths))
+        for n_new in (1, 2, 3, 5, 7, 9, 11, 13):
+            got = eng.submit(np.array([[4, 2, 6]]), n_new)
+            np.testing.assert_array_equal(
+                got, _ref(params, [[4, 2, 6]], n_new)
+            )
+        assert eng.compile_count() == 3 + 2 * len(widths)
+        assert eng.programs.trace_count() == eng.compile_count()
     finally:
         eng.close()
 
@@ -275,3 +340,95 @@ def test_serving_telemetry_families_flow(engine):
         "serving_batch_occupancy",
     ):
         assert family in hists, family
+
+
+# ── what a model family has to write, and the options ratchet ────────────
+
+#: everything the node and the serving package ask of a family's module
+FAMILY_CONTRACT = {
+    "PagedCache", "init_paged_cache", "paged_prefill_chunk",
+    "paged_decode_step", "kv_layers", "kv_heads", "kv_kernel",
+    "state_bytes_per_slot", "cache_elements", "RECURRENT",
+}
+
+_PACKAGE = Path(__file__).resolve().parents[2] / "pygrid_tpu"
+
+
+def _family_attributes_used(path: Path) -> set[str]:
+    """Attributes read off a family module in ``path``: off
+    ``self._family``, off ``decode.family_of(...)``, or off a local name
+    bound to either in the enclosing function."""
+
+    def is_family(node) -> bool:
+        if isinstance(node, ast.Call):
+            node = node.func
+            return isinstance(node, ast.Attribute) and node.attr == "family_of"
+        return isinstance(node, ast.Attribute) and node.attr == "_family"
+
+    used: set[str] = set()
+    tree = ast.parse(path.read_text())
+    for fn in ast.walk(tree):
+        if not isinstance(fn, ast.FunctionDef):
+            continue
+        aliases: set[str] = set()
+        for node in ast.walk(fn):
+            if not isinstance(node, ast.Assign):
+                continue
+            for target in node.targets:
+                pairs = (
+                    zip(target.elts, node.value.elts)
+                    if isinstance(target, ast.Tuple)
+                    and isinstance(node.value, ast.Tuple)
+                    else [(target, node.value)]
+                )
+                aliases |= {
+                    t.id for t, v in pairs
+                    if isinstance(t, ast.Name) and is_family(v)
+                }
+        for node in ast.walk(fn):
+            if isinstance(node, ast.Attribute) and (
+                is_family(node.value)
+                or isinstance(node.value, ast.Name)
+                and node.value.id in aliases
+            ):
+                used.add(node.attr)
+    return used
+
+
+@pytest.mark.parametrize("family", ["decode", "jamba"])
+def test_a_family_module_is_these_ten_names_and_nothing_else(family):
+    """What a third family has to write, pinned: the module exposes the
+    contract, and the node and the serving package (read from their
+    source) reach a family through no other attribute."""
+    import importlib
+
+    module = importlib.import_module(f"pygrid_tpu.models.{family}")
+    missing = {n for n in FAMILY_CONTRACT if not hasattr(module, n)}
+    assert not missing, f"models/{family}.py lacks {sorted(missing)}"
+    assert module.PagedCache._fields[:3] == ("k", "v", "pos")
+    used = set().union(*(
+        _family_attributes_used(p)
+        for d in ("serving", "node") for p in (_PACKAGE / d).glob("*.py")
+    ))
+    assert used == FAMILY_CONTRACT, sorted(used ^ FAMILY_CONTRACT)
+
+
+def test_ratchet_environment_knobs_only_fall():
+    """The distinct ``PYGRID_*`` names the package reads. The number
+    only falls: a PR that needs a new one takes an old one out, or says
+    in ROADMAP.md (debt D3) why the ceiling moves."""
+    names = sorted({
+        name
+        for p in _PACKAGE.rglob("*.py")
+        for name in re.findall(r"PYGRID_[A-Z0-9_]+", p.read_text())
+    })
+    assert len(names) <= 36, f"{len(names)} knobs: {names}"
+
+
+def test_ratchet_engine_config_fields_only_fall():
+    """``EngineConfig``'s independently settable fields. The number only
+    falls (ROADMAP.md debt D3)."""
+    import dataclasses
+
+    fields = [f.name for f in dataclasses.fields(EngineConfig)]
+    assert len(fields) <= 13, f"{len(fields)} fields: {fields}"

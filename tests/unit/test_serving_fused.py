@@ -1,16 +1,13 @@
-"""Fused multi-step decode + self-speculative decoding contracts
-(docs/SERVING.md §Fused multi-step & speculative decode).
+"""Fused multi-step decode contracts (docs/SERVING.md §Fused
+multi-step decode).
 
-The invariants that matter, in both modes: (1) greedy output is
-BIT-IDENTICAL to single-request ``decode.generate`` — fusing a quantum
-of steps into one ``lax.scan`` (or verifying a draft's proposals in one
-wide pass) must not move a single bit, including for rows that finish
+The invariants that matter: (1) greedy output is BIT-IDENTICAL to
+single-request ``decode.generate`` — fusing a quantum of steps into one
+``lax.scan`` must not move a single bit, including for rows that finish
 mid-scan and freeze; (2) sampling stays reproducible per (seed, row)
 and seed-sensitive; (3) the compiled surface stays fixed — shape
 variety within the bucket set triggers ZERO recompiles at both the
-builder counter and the jit cache layer; (4) speculative acceptance
-telemetry is honest (proposed/accepted counted per model, acceptance
-clipped to tokens the row could actually use).
+builder counter and the jit cache layer.
 """
 
 from __future__ import annotations
@@ -25,12 +22,7 @@ import jax
 from pygrid_tpu.models import decode
 from pygrid_tpu.models import transformer as T
 from pygrid_tpu.serving import EngineConfig, GenerationEngine
-from pygrid_tpu.serving.pagedkv import (
-    fused_enabled,
-    resolve_spec_k,
-    resolve_spec_layers,
-    spec_enabled,
-)
+from pygrid_tpu.serving.pagedkv import fused_enabled
 
 CFG = T.TransformerConfig(
     vocab=31, d_model=16, n_heads=2, n_layers=2, d_ff=32, max_len=32
@@ -67,16 +59,6 @@ def test_knob_resolution(monkeypatch):
     monkeypatch.setenv("PYGRID_FUSED_DECODE", "off")
     assert fused_enabled() is False
     assert fused_enabled(True) is True  # explicit config wins
-    assert spec_enabled() is False  # spec is OPT-IN
-    monkeypatch.setenv("PYGRID_SPEC_DECODE", "on")
-    assert spec_enabled() is True
-    assert resolve_spec_k() == 4
-    assert resolve_spec_k(999) == 16  # clamped
-    monkeypatch.setenv("PYGRID_SPEC_K", "2")
-    assert resolve_spec_k() == 2
-    assert resolve_spec_layers(4) == 2  # default: half the stack
-    assert resolve_spec_layers(4, 9) == 3  # strict truncation
-    assert resolve_spec_layers(2) == 1
 
 
 # ── fused multi-step decode ──────────────────────────────────────────────
@@ -167,190 +149,10 @@ def test_fused_off_env_reverts_to_per_step(params, monkeypatch):
         eng.close()
 
 
-# ── self-speculative decoding ────────────────────────────────────────────
-
-
-def test_spec_greedy_bit_identical_to_generate(params):
-    """The speculative contract: the target's argmax arbitrates every
-    emitted token, so greedy output equals plain greedy decode exactly
-    — acceptance rate only moves THROUGHPUT."""
-    eng = _engine(params, "spec", spec_decode=True, spec_k=3)
-    try:
-        for p, n in (
-            ([[3, 5, 2, 9, 11]], 6), ([[1, 2]], 3), ([[7, 8, 9]], 11),
-            ([[4]], 1),
-        ):
-            got = eng.submit(np.array(p), n)
-            np.testing.assert_array_equal(got, _ref(params, p, n))
-        stats = eng.stats()
-        assert stats["spec"] is True
-        assert stats["spec_draft_layers"] == 1
-        assert stats["spec_proposed"] > 0
-        assert 0 <= stats["spec_accepted"] <= stats["spec_proposed"]
-        assert stats["spec_acceptance"] is not None
-    finally:
-        eng.close()
-
-
-def test_spec_concurrent_mixed_requests_match_reference(params):
-    eng = _engine(params, "spec-mix", spec_decode=True, spec_k=4)
-    try:
-        cases = [
-            (np.array([[2 + i, 5, 1, 7][: 1 + i % 4]]), 1 + (i * 3) % 9)
-            for i in range(10)
-        ]
-        results: list = [None] * len(cases)
-
-        def go(i):
-            prompt, n = cases[i]
-            results[i] = eng.submit(prompt, n)
-
-        threads = [
-            threading.Thread(target=go, args=(i,))
-            for i in range(len(cases))
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        for (prompt, n), got in zip(cases, results):
-            np.testing.assert_array_equal(got, _ref(params, prompt, n))
-    finally:
-        eng.close()
-
-
-def test_spec_sampling_reproducible_per_seed_row(params):
-    """Accept/reject sampling is keyed from the row's per-position key
-    schedule: same (seed, row) → same tokens, different seeds can
-    differ, multi-row prompts sample independently per row."""
-    eng = _engine(params, "spec-rng", spec_decode=True, spec_k=3)
-    try:
-        prompt = np.array([[3, 5, 2]])
-        a = eng.submit(prompt, 8, temperature=0.9, seed=123)
-        b = eng.submit(prompt, 8, temperature=0.9, seed=123)
-        np.testing.assert_array_equal(a, b)
-        assert (a >= 0).all() and (a < CFG.vocab).all()
-        outs = {
-            tuple(eng.submit(prompt, 8, temperature=0.9, seed=s)[0])
-            for s in range(6)
-        }
-        assert len(outs) > 1
-        multi = np.array([[3, 5, 2], [3, 5, 2]])
-        m1 = eng.submit(multi, 6, temperature=0.9, seed=7)
-        m2 = eng.submit(multi, 6, temperature=0.9, seed=7)
-        np.testing.assert_array_equal(m1, m2)
-        assert not np.array_equal(m1[0], m1[1]), (
-            "rows must sample independently"
-        )
-    finally:
-        eng.close()
-
-
-def test_spec_zero_recompiles_under_shape_variety(params):
-    eng = _engine(params, "spec-rc", spec_decode=True, spec_k=3)
-    try:
-        eng.warmup(prompt_lens=(1, 8))
-        before = eng.compile_count()
-        for i, (p_len, n) in enumerate(
-            [(1, 2), (3, 9), (5, 4), (8, 1), (2, 7)]
-        ):
-            temp = 0.0 if i % 2 == 0 else 0.7
-            eng.submit(
-                np.full((1, p_len), 1 + i % 7), n,
-                temperature=temp, seed=i,
-            )
-        assert eng.compile_count() == before
-        assert eng.programs.trace_count() == eng.compile_count()
-    finally:
-        eng.close()
-
-
-def test_spec_prefix_sharing_still_bit_identical(params):
-    """Prefix hits map shared pages into BOTH caches (the draft's pool
-    rides the same block ids): a request continuing after a shared
-    prefix must produce the same tokens as a cold one — the draft reads
-    prefix k/v it did not compute."""
-    eng = _engine(
-        params, "spec-prefix", spec_decode=True, spec_k=3, max_slots=2,
-        slot_buckets=(1, 2),
-    )
-    try:
-        sys_prompt = np.arange(1, 17, dtype=np.int32)  # 2 full pages
-        cases = [
-            np.concatenate([sys_prompt, np.array([20 + i], np.int32)])[
-                None, :
-            ]
-            for i in range(3)
-        ]
-        first = eng.submit(cases[0], 5)
-        np.testing.assert_array_equal(first, _ref(params, cases[0], 5))
-        for prompt in cases[1:]:
-            got = eng.submit(prompt, 5)
-            np.testing.assert_array_equal(got, _ref(params, prompt, 5))
-        assert eng.stats()["prefix_hits"] >= 2
-    finally:
-        eng.close()
-
-
-def test_spec_disabled_on_single_layer_model():
-    """A 1-layer model cannot strictly truncate — the engine falls back
-    to non-speculative decode instead of building a same-depth draft."""
-    cfg1 = T.TransformerConfig(
-        vocab=31, d_model=16, n_heads=2, n_layers=1, d_ff=32, max_len=32
-    )
-    params1 = T.init(jax.random.PRNGKey(1), cfg1)
-    eng = GenerationEngine(
-        cfg1, params1,
-        EngineConfig(
-            max_slots=2, slot_buckets=(1, 2), min_prompt_bucket=8,
-            spec_decode=True,
-        ),
-        model_id="shallow",
-    )
-    try:
-        assert eng.stats()["spec"] is False
-        got = eng.submit(np.array([[3, 5]]), 4)
-        ref = np.asarray(
-            decode.generate(
-                params1, np.array([[3, 5]], np.int32), 4, cfg1
-            )
-        )
-        np.testing.assert_array_equal(got, ref)
-    finally:
-        eng.close()
-
-
-def test_spec_recovers_after_device_loop_failure(params):
-    """The failure path reallocates the DRAFT cache too — a consumed
-    draft buffer must not brick the engine."""
-    from pygrid_tpu.utils import exceptions as E
-
-    eng = _engine(params, "spec-boom", spec_decode=True, spec_k=3)
-    try:
-        original = eng.programs.spec_verify
-
-        def boom(width, k):
-            raise RuntimeError("injected device failure")
-
-        eng.programs.spec_verify = boom
-        with pytest.raises(E.PyGridError, match="engine error"):
-            eng.submit(np.array([[1, 2]]), 4, timeout=30)
-        eng.programs.spec_verify = original
-        got = eng.submit(np.array([[1, 2]]), 4, timeout=60)
-        np.testing.assert_array_equal(got, _ref(params, [[1, 2]], 4))
-    finally:
-        eng.close()
-
-
-def test_fused_and_spec_telemetry_families_flow(params):
+def test_fused_telemetry_families_flow(params):
     from pygrid_tpu import telemetry
 
     eng = _engine(params, "tele-f", fused=True)
-    try:
-        eng.submit(np.array([[1, 2, 3]]), 9)
-    finally:
-        eng.close()
-    eng = _engine(params, "tele-s", spec_decode=True, spec_k=3)
     try:
         eng.submit(np.array([[1, 2, 3]]), 9)
     finally:
@@ -359,7 +161,5 @@ def test_fused_and_spec_telemetry_families_flow(params):
     for family in (
         "serving_fused_scans_total",
         "serving_fused_steps_total",
-        "serving_spec_verifies_total",
-        "serving_spec_proposed_total",
     ):
         assert family in counters, family
