@@ -225,3 +225,137 @@ def test_plugs_into_transformer_attn_fn():
     np.testing.assert_allclose(
         np.asarray(flash_logits), np.asarray(ref_logits), atol=1e-4
     )
+
+
+# ── what a block holds: heads that share a tile, sequences that share a step ──
+
+
+def _loss_and_grads(fn, q, k, v):
+    def loss(q, k, v):
+        return jnp.sum(jnp.tanh(fn(q, k, v).astype(jnp.float32)))
+
+    return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+#: (B, L, H, D), the flash kernel's block arguments, dtype, tolerance
+PACKED_CASES = {
+    # the benchmark's two training cells, one client's attention
+    "fed-device-short": ((2, 128, 12, 64), {}, jnp.float32, 2e-5),
+    "fed-silo-docs": (
+        (1, 2048, 12, 64),
+        dict(block_q=1024, block_k=1024, bwd_block_q=1024, bwd_block_k=1024),
+        jnp.float32, 5e-5,
+    ),
+    # two sequences a step and an odd head count: the last tile is half pad
+    "odd-heads": ((2, 128, 3, 64), {}, jnp.float32, 2e-5),
+    # four heads a tile, and a ragged length beside them
+    "four-a-tile": ((2, 200, 8, 32), {}, jnp.float32, 2e-5),
+    # a head that fills its tile: nothing shared, nothing padded
+    "full-tile": ((2, 128, 2, 128), {}, jnp.float32, 2e-5),
+    # a head that neither divides a tile nor fills one: padded, one a tile
+    "padded-head": ((1, 128, 2, 48), {}, jnp.float32, 2e-5),
+    # the cells' own operand type
+    "bf16": ((2, 128, 12, 64), {}, jnp.bfloat16, 4e-2),
+    # several tiles a sequence with two heads a tile
+    "multi-tile": (
+        (1, 384, 4, 64),
+        dict(block_q=128, block_k=128, bwd_block_q=128, bwd_block_k=256),
+        jnp.float32, 2e-5,
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PACKED_CASES))
+def test_forward_and_gradients_by_what_a_block_holds(case):
+    (B, L, H, D), blocks, dtype, atol = PACKED_CASES[case]
+    q, k, v = _qkv(B, L, L, H, D, dtype=dtype, seed=11)
+    as_f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    want, want_grads = _loss_and_grads(
+        lambda q, k, v: attention(q, k, v, causal=True), *as_f32
+    )
+    got, got_grads = _loss_and_grads(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=True, **blocks
+        ),
+        q, k, v,
+    )
+    np.testing.assert_allclose(float(got), float(want), rtol=max(atol, 1e-4))
+    for a, b in zip(want_grads, got_grads):
+        assert b.dtype == dtype
+        np.testing.assert_allclose(
+            np.asarray(b, dtype=np.float32), np.asarray(a), atol=atol
+        )
+
+
+def test_under_the_trainers_client_vmap():
+    """``make_fused_rounds`` maps the loss over a leading client axis:
+    the batching rule puts the clients' grid axis in front of the
+    kernels' own, whatever a step of theirs holds."""
+    clients, shape = 3, (2, 128, 4, 64)
+    q, k, v = (
+        jnp.stack(parts)
+        for parts in zip(*(_qkv(*shape[:2], shape[1], *shape[2:], seed=s)
+                           for s in range(clients)))
+    )
+
+    def per_client(fn):
+        return jax.vmap(
+            lambda q, k, v: _loss_and_grads(fn, q, k, v)
+        )(q, k, v)
+
+    want, want_grads = per_client(
+        lambda q, k, v: attention(q, k, v, causal=True)
+    )
+    got, got_grads = per_client(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=True)
+    )
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5)
+    for a, b in zip(want_grads, got_grads):
+        assert b.shape == (clients, *shape)
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=2e-5)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it, in order
+    (a kernel's own body left out)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_one_tile_sequences_are_read_as_the_model_lays_them_out():
+    """Untimed, on the program as traced: at the short cell's shape the
+    three kernels take ``[B, L, H·D]`` operands (a reshape of what the
+    model hands over: no pad, no transpose) and one grid step holds every
+    head of at least one sequence."""
+    B, L, H, D = 4, 128, 12, 64
+    q, k, v = _qkv(B, L, L, H, D, dtype=jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    eqns = list(_equations(jaxpr.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert "pad" not in names and "transpose" not in [
+        e.primitive.name for e in eqns
+        if any(getattr(x.aval, "dtype", None) == jnp.bfloat16 for x in e.invars)
+    ]
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert sorted(
+        e.params["name"] for e in calls
+    ) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    for call in calls:
+        grid = call.params["grid_mapping"].grid
+        assert int(np.prod(grid)) <= B, grid
+        streams = [
+            x.aval.shape for x in call.invars if x.aval.dtype == jnp.bfloat16
+        ]
+        assert streams and all(s == (B, L, H * D) for s in streams), streams
