@@ -12,7 +12,12 @@ writes every request's times and answer to the plan's ``out`` file.
 Open loop: a dispatcher hands each request to its door's workers when it
 is due, whether or not earlier ones have answered, and a request is timed
 from when it was due. Closed loop: each caller sends its next request when
-the last one answers, until the window closes.
+the last one answers, until the window closes; a caller that finds the
+list spent before then says so (``spent``), and the runner fails the run.
+
+A request's named fields (``lib/traffic.py``) go out beside ``n_new``, and
+whatever an answer holds beside its tokens is kept under the row's
+``answer``: the generator reads neither.
 """
 
 from __future__ import annotations
@@ -23,6 +28,7 @@ import queue
 import sys
 import threading
 import time
+from collections.abc import Mapping
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
@@ -31,8 +37,14 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 from lib import traffic as T  # noqa: E402
 
 
+def _plain(value):
+    """An array as lists, anything else as it is: what JSON can hold."""
+    return value.tolist() if hasattr(value, "tolist") else value
+
+
 class Door:
-    """One connection through one door; ``send`` returns the tokens."""
+    """One connection through one door; ``send`` returns the answer's
+    fields, ``tokens`` among them."""
 
     def __init__(self, kind: str, url: str, model_id: str, timeout: float,
                  token: str | None = None) -> None:
@@ -49,11 +61,14 @@ class Door:
             self.client = requests.Session()
             self.token = token
 
-    def send(self, prompt, n_new: int):
+    def send(self, prompt, n_new: int, **fields) -> dict:
         if self.kind == "ws":
-            return self.client.run_remote_generation(
-                self.model_id, prompt, n_new=n_new
-            ).tolist()
+            got = self.client.run_remote_generation(
+                self.model_id, prompt, n_new=n_new, **fields
+            )
+            # an array is the tokens; a mapping names them among its fields
+            got = got if isinstance(got, Mapping) else {"tokens": got}
+            return {k: _plain(v) for k, v in got.items()}
         from pygrid_tpu.serde import serialize
 
         resp = self.client.post(
@@ -64,12 +79,15 @@ class Door:
                 "data": base64.b64encode(serialize(prompt)).decode(),
                 "n_new": n_new,
                 "temperature": 0.0,
+                **fields,
             },
             timeout=self.timeout,
         )
         if resp.status_code != 200:
             raise RuntimeError(f"HTTP {resp.status_code}: {resp.text[:200]}")
-        return resp.json()["tokens"]
+        body = resp.json()
+        body.pop("success", None)
+        return body
 
     def close(self) -> None:
         self.client.close()
@@ -80,11 +98,14 @@ def _serve(door: Door, req: dict, prompts: dict, t_go: float, results: list) -> 
     row = {
         "i": req["i"], "door": door.kind, "prompt_len": req["prompt_len"],
         "n_new": req["n_new"], "due": req["due"], "counted": req["counted"],
-        "queued": req.get("queued"),
+        "queued": req.get("queued"), "fields": req["fields"],
     }
     row["sent"] = time.time() - t_go
     try:
-        row["tokens"] = door.send(prompts[req["i"]], req["n_new"])
+        answer = door.send(prompts[req["i"]], req["n_new"], **req["fields"])
+        row["tokens"] = answer.pop("tokens")
+        if answer:
+            row["answer"] = answer
         row["ok"] = True
     except Exception as err:  # noqa: BLE001 — a failed request is a result
         row["ok"], row["error"] = False, f"{type(err).__name__}: {err}"[:300]
@@ -92,7 +113,7 @@ def _serve(door: Door, req: dict, prompts: dict, t_go: float, results: list) -> 
     results.append(row)
 
 
-def run_open(plan: dict, doors: dict, built: dict, prompts: dict, t_go: float) -> list:
+def run_open(plan: dict, doors: dict, built: dict, prompts: dict, t_go: float) -> tuple:
     results: list = []
     queues = {kind: queue.Queue() for kind in doors}
 
@@ -121,19 +142,24 @@ def run_open(plan: dict, doors: dict, built: dict, prompts: dict, t_go: float) -
     deadline = time.time() + plan["traffic"]["drain_s"]
     for t in threads:
         t.join(max(0.0, deadline - time.time()))
-    return results
+    return results, {}
 
 
-def run_closed(plan: dict, doors: dict, built: dict, prompts: dict, t_go: float) -> list:
+def run_closed(plan: dict, doors: dict, built: dict, prompts: dict, t_go: float) -> tuple:
+    """The results, and how the list held: ``spent`` (a caller wanted a
+    request before the window closed and the list had none left: the run
+    then offered less load than the cell defines) and ``list_used``, the
+    share of the list that was sent."""
     results: list = []
     lock = threading.Lock()
-    cursor = [0]
+    cursor, spent = [0], [False]
     t_end = t_go + built["lead_in_s"] + plan["seconds"]
 
     def caller(door: Door) -> None:
         while time.time() < t_end:
             with lock:
                 if cursor[0] >= len(built["requests"]):
+                    spent[0] = True
                     return
                 req = built["requests"][cursor[0]]
                 cursor[0] += 1
@@ -148,7 +174,9 @@ def run_closed(plan: dict, doors: dict, built: dict, prompts: dict, t_go: float)
     deadline = t_end + plan["traffic"]["drain_s"]
     for t in threads:
         t.join(max(0.0, deadline - time.time()))
-    return results
+    return results, {
+        "spent": spent[0], "list_used": cursor[0] / len(built["requests"]),
+    }
 
 
 def connections(traffic: dict) -> dict:
@@ -187,7 +215,7 @@ def main(plan_path: str) -> int:
 
     probe = np.ones((1, traffic["prompt_len"].get("lo", 8)), np.int32)
     for ds in doors.values():
-        got = ds[0].send(probe, 2)
+        got = ds[0].send(probe, 2)["tokens"]
         assert len(got[0]) == 2, got
     print("READY", flush=True)
     line = sys.stdin.readline().split()
@@ -195,7 +223,7 @@ def main(plan_path: str) -> int:
         return 2
     t_go = float(line[1])
     run = run_open if built["loop"] == "open" else run_closed
-    results = run(plan, doors, built, prompts, t_go)
+    results, held = run(plan, doors, built, prompts, t_go)
     for ds in doors.values():
         for d in ds:
             try:
@@ -211,6 +239,7 @@ def main(plan_path: str) -> int:
     Path(plan["out"]).write_text(json.dumps({
         "lead_in_s": built["lead_in_s"], "loop": built["loop"],
         "connections": counts, "results": sorted(results, key=lambda r: r["i"]),
+        **held,
     }))
     return 0
 

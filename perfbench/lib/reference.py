@@ -6,6 +6,15 @@ cache, no batching tricks. The block itself, its weights from ``--seed``
 and what the program takes of them are the adapter's,
 ``models/<model_type>.py`` (``spec.load_model``); ``model`` below is that
 module. Nothing here imports the program or takes anything it made.
+
+``served_gaps`` here is the comparison of a CAUSAL decoder that makes one
+token a row a step: teacher forcing, shifted by one, one state a position.
+The runner never calls it; an adapter's own ``served_gaps`` does, where
+that is true of its architecture. Where a served token was chosen from
+another state (its own position's logits, in a block partly masked at the
+forward that revealed it), the adapter replays the states the answer
+names instead, in float32 at ``highest`` precision, and returns one gap a
+served token.
 """
 
 from __future__ import annotations
@@ -108,23 +117,25 @@ def int8_values(x, axis: int):
 
 def served_gaps(model, w, cfg: dict, prompt: np.ndarray, served: np.ndarray,
                 pad_to: int, control=None):
-    """For one served request, the gap at each served position between the
-    reference's best logit and the logit of the served token (teacher
-    forced on what was served). With ``control`` weights, also the gap of
-    the token the control puts first at each of those positions."""
+    """For one served request of a causal decoder (``model`` its adapter,
+    or the adapter's ``logits`` alone), the gap at each served position
+    between the reference's best logit and the logit of the served token
+    (teacher forced on what was served). With ``control`` weights, also the
+    gap of the token the control puts first at each of those positions."""
+    logits = getattr(model, "logits", model)
     p_len, n_new = prompt.shape[-1], served.shape[-1]
     seq = np.zeros((1, pad_to), np.int32)
     seq[0, :p_len] = prompt.reshape(-1)
     seq[0, p_len : p_len + n_new - 1] = served.reshape(-1)[:-1]
     # causal: padding after the sequence cannot reach back into it
     cfg_json = json.dumps(cfg, sort_keys=True)  # hashable, nested groups and all
-    rows = _logit_rows(w, jnp.asarray(seq), model.logits, cfg_json)
+    rows = _logit_rows(w, jnp.asarray(seq), logits, cfg_json)
     rows = np.asarray(rows[0, p_len - 1 : p_len - 1 + n_new])
     best = rows.max(-1)
     gaps = best - rows[np.arange(n_new), served.reshape(-1)]
     if control is None:
         return gaps, None
-    crow = _logit_rows(control, jnp.asarray(seq), model.logits, cfg_json)
+    crow = _logit_rows(control, jnp.asarray(seq), logits, cfg_json)
     picks = np.asarray(crow[0, p_len - 1 : p_len - 1 + n_new]).argmax(-1)
     return gaps, best - rows[np.arange(n_new), picks]
 

@@ -7,6 +7,15 @@ type they are served in; the bundle registered with the node's model
 store; the engine built and every shape of this cell's traffic warmed;
 the generator connected. Then the window, the drain, the device's memory
 peak — and only after the engine has given its pool back, the reference.
+
+What the runner assumes of an engine is ``models/gpt2.py``'s last
+paragraph and no more: what a request asks beside its length and what an
+answer says beside its tokens pass through as named fields, and the
+comparison that decides ``correct`` is the architecture's
+(``model.served_gaps``). A traced run also keeps the node's counters at
+the traced stretch's two ends (``ctx["snap_t0"]``, ``ctx["snap_t1"]``)
+beside every device operation's seconds and count (``ctx["trace"]["ops"]``):
+what a reader of one kernel's share of its roofline divides.
 """
 
 from __future__ import annotations
@@ -26,7 +35,7 @@ from pathlib import Path
 
 import numpy as np
 
-from lib import reference, spec, stats, traffic as T
+from lib import spec, stats, traffic as T
 from lib.readers import memory_peak
 from lib.trace import CompileCounter
 
@@ -174,10 +183,13 @@ def window_tokens(results: list, w0: float, w1: float) -> float:
 
 def check_served(cell: dict, model, seed: int, results: list, control: bool) -> dict:
     """``correct`` for a serving cell: a seeded sample of the requests the
-    window finished, the longest among them, each run once through the
-    reference with the tokens it was served; the number compared is the
-    widest gap by which a served token's logit lies below the reference's
-    best, and beside it the mean gap."""
+    window finished, the longest among them, each handed to the
+    architecture's own comparison (``model.served_gaps``) as it was sent
+    and as it was answered; the number compared is the widest gap by which
+    a served token's logit lies below the reference's best, and beside it
+    the mean gap. How a token came to be (from which state, in what order)
+    is the adapter's knowledge; the runner's is the sample, the shape and
+    range of an answer's tokens, and the limits."""
     cfg, check = cell["config"], cell["traffic"]["check"]
     done = [r for r in results if r.get("ok") and r.get("counted") is not False]
     if not done:
@@ -192,14 +204,24 @@ def check_served(cell: dict, model, seed: int, results: list, control: bool) -> 
     stored = cell["config"]["deployment"]["weights_dtype"]
     w = model.make_weights(seed, cfg, stored)
     ctrl = model.control_weights(w) if control else None
-    gaps, cgaps, bad_shape = [], [], 0
+    gaps, cgaps, malformed = [], [], []
     for r in sample:
-        prompt = T.prompt_tokens(cell["traffic"], seed, r, cfg["vocab_size"])
-        served = np.asarray(r["tokens"], np.int64)
+        request = {
+            "prompt": T.prompt_tokens(cell["traffic"], seed, r, cfg["vocab_size"]),
+            "n_new": r["n_new"], "fields": r.get("fields", {}),
+        }
+        answer = {"tokens": np.asarray(r["tokens"], np.int64), **r.get("answer", {})}
+        served = answer["tokens"]
         if served.shape != (1, r["n_new"]) or served.min() < 0 or served.max() >= cfg["vocab_size"]:
-            bad_shape += 1
+            malformed.append(f"#{r['i']}: tokens {served.shape}")
             continue
-        g, cg = reference.served_gaps(model, w, cfg, prompt, served, pad_to, ctrl)
+        try:
+            g, cg = model.served_gaps(w, cfg, request, answer, pad_to, ctrl)
+        except (KeyError, ValueError) as err:
+            # the adapter could not read the answer's fields: one more
+            # malformed answer, not the end of the run
+            malformed.append(f"#{r['i']}: {type(err).__name__}: {err}"[:200])
+            continue
         gaps.append(g)
         if cg is not None:
             cgaps.append(cg)
@@ -207,7 +229,7 @@ def check_served(cell: dict, model, seed: int, results: list, control: bool) -> 
     compared = [
         {"name": "served_gap_max", "value": float(flat.max()), "limit": check["gap_max_limit"]},
         {"name": "served_gap_mean", "value": float(flat.mean()), "limit": check["gap_mean_limit"]},
-        {"name": "malformed_answers", "value": float(bad_shape), "limit": 0.0},
+        {"name": "malformed_answers", "value": float(len(malformed)), "limit": 0.0},
     ]
     out = {
         "correct": all(c["value"] <= c["limit"] for c in compared) and bool(np.isfinite(flat).all()),
@@ -215,6 +237,8 @@ def check_served(cell: dict, model, seed: int, results: list, control: bool) -> 
         "sampled_requests": len(sample),
         "sampled_tokens": int(flat.size),
     }
+    if malformed:
+        out["malformed"] = malformed[:3]
     if cgaps:
         cflat = np.concatenate(cgaps)
         out["control"] = {
@@ -296,21 +320,26 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
         time.sleep(max(0.0, w0 - time.time()))
         snap1 = scrape(node)
         compiled_at_w0 = compiles.n
-        tracer = None
+        tracer, snap_t0, snap_t1 = None, None, None
         if trace:
             from lib.trace import Tracer
 
             tracer = Tracer(workdir / "trace")
             tracer.start()
+            # the counters over the traced stretch: read once the tracer
+            # runs and again just before it stops (its clock's two ends)
+            snap_t0 = scrape(node)
         trace_until = time.time() + float(tr["trace_s"])
         free_min = snap1["engine"]["kv_blocks_free"]
         while time.time() < w1 - 0.05:
             if tracer is not None and tracer.window_s == 0.0 and time.time() >= trace_until:
+                snap_t1 = scrape(node)
                 tracer.stop()
             time.sleep(min(1.0, max(0.0, w1 - time.time())))
             row = node.get("/telemetry/serving").json()["engines"][0]
             free_min = min(free_min, row["kv_blocks_free"])
         if tracer is not None and tracer.window_s == 0.0:
+            snap_t1 = scrape(node)
             tracer.stop()
         time.sleep(max(0.0, w1 - time.time()))
         snap2 = scrape(node)
@@ -330,6 +359,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
             child.kill()
             child.wait(30)
         node.stop()
+        shutil.rmtree(workdir, ignore_errors=True)
     # the engine's pool and the program's weights go before the reference
     # makes its own: the memory peak above stays the program's
     del engine, params
@@ -337,10 +367,19 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
     gc.collect()
 
     results = run["results"]
+    if run["loop"] == "closed":
+        n_list = len(built["requests"])
+        log(f"perfbench: list used {100.0 * run['list_used']:.0f}% of {n_list} requests")
+        if run["spent"]:
+            # fewer callers than the cell defines for part of the run: a
+            # rate read from it would be low for no fault of the engine
+            raise CellFailure(
+                f"the closed loop's list of {n_list} requests was spent before the "
+                "window closed: the mix's `cycles` is too small for this engine"
+            )
     t = time.time()
     verdict = check_served(cell, model, seed, results, control)
     phases["reference_s"] = time.time() - t
-    shutil.rmtree(workdir, ignore_errors=True)
 
     rel0, rel1 = lead_s, lead_s + seconds
     if run["loop"] == "open":
@@ -383,6 +422,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
         "kind": "serve", "loop": run["loop"], "seconds": seconds, "results": results,
         "late_ms": lat["late_ms"], "window": (rel0, rel1),
         "snap_go": snap0, "snap_w0": snap1, "snap_w1": snap2, "snap_end": snap3,
+        "snap_t0": snap_t0, "snap_t1": snap_t1,
         "kv_blocks_free_min": free_min, "engine": engine_row,
         "memory_peak_bytes": memory_peak(mem), "trace": traced,
     }

@@ -7,6 +7,14 @@ architecture one adapter, ``models/<model_type>.py``, named by the
 configuration's ``model_type`` (its contract: ``models/gpt2.py``). Adding a
 cell, a configuration, a mix, a metric or an architecture adds files and
 entries and edits nothing that exists: nothing here switches on a name.
+
+The comparison that decides a serving cell's ``correct`` is the adapter's
+too (``served_gaps``): the runner samples the requests and hands each over
+as it was sent and answered, named fields and all, and knows nothing of
+how a token came to be. A causal decoder's is one call of
+``lib/reference.served_gaps`` on its ``logits``; any other adapter replays
+the states the answer names, in float32 at ``highest`` precision, and
+returns one gap a served token.
 """
 
 from __future__ import annotations
@@ -25,7 +33,7 @@ SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 #: what an architecture's adapter defines (``models/gpt2.py`` says what each is)
 MODEL_CONTRACT = (
     "make_weights", "make_program_params", "to_reference", "logits",
-    "control_weights", "leaf_norms", "hosted", "train_loss",
+    "served_gaps", "control_weights", "leaf_norms", "hosted", "train_loss",
     "train_flops_per_token", "tiny",
 )
 

@@ -82,16 +82,21 @@ def reduce(per_device: dict, window_s: float, top: int = 10) -> dict:
     """Busy seconds (the union of operation intervals, averaged over the
     devices), and the breakdown: the ``top`` operations by summed time and
     the ``top`` idle-gap groups by the operation before the gap, both taken
-    over all devices."""
+    over all devices. ``ops`` is the table the operations were ranked
+    from, whole: ``{short name: [seconds, count]}`` for every device
+    operation of the traced stretch, for a reader of one kernel's time."""
     if not per_device:
         raise ValueError("the trace has no accelerator plane")
-    busy, op_time, gap_time = [], defaultdict(float), defaultdict(float)
+    busy, gap_time = [], defaultdict(float)
+    ops: dict = defaultdict(lambda: [0.0, 0])
     for events in per_device.values():
         merged = merge(events)
         busy.append(sum(end - start for start, end, _ in merged))
         for name, _start, dur in events:
             if not CONTAINERS.match(name):
-                op_time[short_name(name)] += dur
+                entry = ops[short_name(name)]
+                entry[0] += dur
+                entry[1] += 1
         for (_, end, last), (start, _, _) in zip(merged, merged[1:]):
             gap_time["after:" + short_name(last)] += start - end
     busy_s = sum(busy) / len(busy)
@@ -106,9 +111,10 @@ def reduce(per_device: dict, window_s: float, top: int = 10) -> dict:
         "window_s": float(window_s),
         "idle_pct": 100.0 * (1.0 - busy_s / window_s),
         "breakdown": {
-            "device_ops": ranked(op_time),
+            "device_ops": ranked({k: v[0] for k, v in ops.items()}),
             "idle_gaps": ranked(gap_time),
         },
+        "ops": dict(ops),
     }
 
 

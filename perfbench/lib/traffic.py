@@ -8,6 +8,13 @@ offsets and (elsewhere) the weights. What it never changes is the
 multiset of lengths or the number of requests due in a window: every run
 of a cell is given the same work in another order.
 
+A mix may give its requests named fields, ``"fields": {name: value}``,
+which go out with each request as they are named (a keyword of the WS
+client's call, a key of the HTTP body) and come back to the adapter's
+comparison: a constant, or ``{"values": [...], "weights": [...]}`` dealt
+in proportion (``deal``). They are drawn after every other draw and only
+where the key is present, so a mix without it is the mix it was.
+
 Arrivals are pre-drawn before anything is sent, as
 ``pygrid_tpu/storm/loadgen.py`` ``arrival_times`` does (exponential gaps
 from a seeded generator, open loop: an arrival never waits for a
@@ -60,6 +67,39 @@ def arrival_offsets(traffic: dict, rng: np.random.Generator, n: int) -> np.ndarr
     raise ValueError(f"unknown arrival process {kind!r}")
 
 
+def deal(values: list, weights: list, n: int, rng: np.random.Generator) -> list:
+    """``n`` of ``values`` in proportion to ``weights``, the same counts
+    for every seed (the remainder goes to the largest fractions, the
+    earlier value first), in an order the seed gives."""
+    if len(values) != len(weights) or not values or min(weights) < 0 or sum(weights) <= 0:
+        raise ValueError(f"field values {values!r} and weights {weights!r} do not pair")
+    exact = n * np.asarray(weights, float) / float(sum(weights))
+    counts = np.floor(exact).astype(int)
+    for j in np.argsort(-(exact - counts), kind="stable")[: n - int(counts.sum())]:
+        counts[j] += 1
+    dealt = [v for v, c in zip(values, counts) for _ in range(c)]
+    return [dealt[j] for j in rng.permutation(n)]
+
+
+def _fields(traffic: dict, groups: list, rng: np.random.Generator) -> list[dict]:
+    """Each request's named fields: a weighted value dealt anew within
+    every group (a closed loop's grid, an open loop's lead-in and
+    window), so that any stretch of the list holds the mix's shares."""
+    total = sum(n for n, _ in groups)
+    out: list[dict] = [{} for _ in range(total)]
+    for name, value in sorted(traffic.get("fields", {}).items()):
+        if isinstance(value, dict):
+            column = [
+                v for n, _ in groups
+                for v in deal(value["values"], value["weights"], n, rng)
+            ]
+        else:
+            column = [value] * total
+        for fields, v in zip(out, column):
+            fields[name] = v
+    return out
+
+
 def _doors(traffic: dict, n: int) -> list[str]:
     """Doors in a fixed rotation by their weights: ``{"ws": 3, "http": 1}``
     sends every fourth request through HTTP."""
@@ -83,8 +123,9 @@ def build(traffic: dict, seed: int, seconds: float) -> dict:
     """The requests of one run: ``{"requests": [...], "lead_in_s",
     "loop"}``. Each request has ``i``, ``prompt_len``, ``n_new``, ``door``,
     ``due`` (seconds from the start of sending; None in a closed loop),
-    ``counted`` (due inside the window) and ``prefix`` (index of the
-    shared prefix it opens with, or None)."""
+    ``counted`` (due inside the window), ``prefix`` (index of the shared
+    prefix it opens with, or None) and ``fields`` (the mix's named fields
+    of this request; empty where the mix names none)."""
     rng = np.random.default_rng([int(seed), 0x7A11])
     loop = traffic["loop"]
     if loop == "open":
@@ -102,7 +143,12 @@ def build(traffic: dict, seed: int, seconds: float) -> dict:
             n_lead = len(due) - n_win
         groups = [(n_lead, False), (n_win, True)]
     elif loop == "closed":
-        # more than any run can finish: whole grids, one permutation each
+        # more than any run can finish: whole grids, one permutation each,
+        # drawn grid after grid from one stream (a longer list opens with
+        # the shorter one's requests). A caller that finds the list spent
+        # before the window closes fails the run (``lib/serving.py``), so
+        # size ``cycles`` to hold at least four times what the fastest
+        # engine the ledger has seen would finish in lead-in plus window
         grid = int(traffic["grid"])
         cycles = int(traffic.get("cycles", 8))
         groups = [(grid, None)] * cycles
@@ -126,6 +172,7 @@ def build(traffic: dict, seed: int, seconds: float) -> dict:
         ranks = np.arange(1, int(share["prompts"]) + 1, dtype=float)
         p = ranks ** -float(share.get("zipf", 1.0))
         prefixes = rng.choice(len(ranks), size=total, p=p / p.sum()).tolist()
+    fields = _fields(traffic, groups, rng)  # the last draw: see the docstring
     requests = [
         {
             "i": i,
@@ -135,6 +182,7 @@ def build(traffic: dict, seed: int, seconds: float) -> dict:
             "due": None if due is None else float(due[i]),
             "counted": counted[i],
             "prefix": prefixes[i],
+            "fields": fields[i],
         }
         for i in range(total)
     ]

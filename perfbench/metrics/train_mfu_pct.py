@@ -1,6 +1,7 @@
 """Model FLOP/s utilisation: the operations a token's forward and backward
-need (``lib/peaks.py``; recomputation not counted) times tokens per second,
-over the device's published bf16 peak, 0-100."""
+need (the architecture's adapter counts them, ``train_flops_per_token``;
+recomputation not counted) times tokens per second, over the device's
+published bf16 peak (``lib/peaks.py``), 0-100."""
 from lib.peaks import peak
 
 

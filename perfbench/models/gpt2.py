@@ -13,8 +13,24 @@ of ``spec.MODEL_CONTRACT``:
   cfg)`` (the program's form as the reference's);
 - the mathematics: ``logits(w, tokens, cfg, dtype=float32) -> [B, T,
   vocab]`` in plain ``jax.numpy`` at ``highest`` precision, importing
-  nothing of the program. ``lib/reference.py`` builds the loss, the SGD
-  steps and the served gaps on it;
+  nothing of the program. ``lib/reference.py`` builds the loss and the SGD
+  steps on it;
+- the comparison that decides a serving cell's ``correct``:
+  ``served_gaps(w, cfg, request, answer, pad_to, control=None) -> (gaps,
+  control_gaps | None)``, one gap a served token (the reference's best
+  logit less the served token's, in the state that token was chosen from)
+  and, with ``control`` weights, the gap of the token the control puts
+  first in each of those states. ``request`` is what was sent: ``prompt``
+  (``int32[1, P]``), ``n_new`` and ``fields``, the mix's named fields of
+  this request; ``answer`` what came back: ``tokens`` (``int64[1, n_new]``,
+  shape and range already checked) and every other field the door
+  returned. ``pad_to`` is a common length the runner's sample fits in, so
+  that one compiled program serves it. A ``KeyError`` or ``ValueError``
+  over the answer's fields counts the answer as malformed. A causal
+  decoder that makes one token a row a step calls
+  ``reference.served_gaps`` on its ``logits`` (below); any other replays
+  the states the answer names (which positions were known at the forward
+  that revealed each token), in float32 at ``highest`` precision;
 - the controls: ``control_weights(w)`` (the serving control's weights: which
   leaves are matrices is the architecture's knowledge) and ``leaf_norms(w)``
   (which leaves are stacked by layer);
@@ -176,6 +192,14 @@ def logits(w: dict, tokens, cfg: dict, dtype=jnp.float32):
     """``[B, T, vocab]`` next-token logits (tied head)."""
     with jax.default_matmul_precision("highest"):
         return hidden(w, tokens, cfg, dtype) @ w["embed"].astype(dtype).T
+
+
+def served_gaps(w, cfg: dict, request: dict, answer: dict, pad_to: int, control=None):
+    """A causal decoder, one token a row a step: the shifted-by-one
+    comparison of ``lib/reference.served_gaps`` on ``logits``."""
+    return reference.served_gaps(
+        logits, w, cfg, request["prompt"], answer["tokens"], pad_to, control
+    )
 
 
 # ── the controls ─────────────────────────────────────────────────────────

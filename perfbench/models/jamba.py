@@ -304,6 +304,14 @@ def logits(w: dict, tokens, cfg: dict, dtype=jnp.float32):
         return hidden(w, tokens, cfg, dtype) @ w["embed"].astype(dtype).T
 
 
+def served_gaps(w, cfg: dict, request: dict, answer: dict, pad_to: int, control=None):
+    """A causal decoder, one token a row a step: the shifted-by-one
+    comparison of ``lib/reference.served_gaps`` on ``logits``."""
+    return reference.served_gaps(
+        logits, w, cfg, request["prompt"], answer["tokens"], pad_to, control
+    )
+
+
 # ── the controls ─────────────────────────────────────────────────────────
 
 

@@ -6,9 +6,11 @@ Runs one cell of ``BENCHMARK.json`` on the accelerator this machine holds
 and prints, as the last line of standard output, one JSON object:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
 metrics with ``--trace 0``, its per-layer metrics with ``--trace 1``),
-``device`` and, traced, ``breakdown``. Everything else (how set-up splits,
-the generator's lateness, requests by door, compiles inside the window,
-each number compared beside its limit) goes on earlier lines.
+``device`` and, traced, ``breakdown``; its last key, ``compared``, holds each
+number compared as ``[value, limit]``, and the same go out as the last lines
+of standard error. Everything else (how set-up splits, the generator's
+lateness, requests by door, compiles inside the window) goes on earlier
+lines.
 
 It exits non-zero, with no result line, when JAX finds no TPU or fewer
 chips than the cell asks for, and where the program is not beside it.
@@ -22,6 +24,7 @@ T_START = time.time()
 
 import argparse  # noqa: E402
 import json  # noqa: E402
+import math  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 from pathlib import Path  # noqa: E402
@@ -93,6 +96,19 @@ def result_line(bench: dict, cell: dict, run: dict, device: dict, trace: bool) -
     return line
 
 
+def compared_last(line: dict, verdict: dict) -> dict:
+    """The line with each number compared beside its limit, ``{name:
+    [value, limit]}``, as its last key: with the last lines of standard
+    error, what the driver keeps of a run that is not correct."""
+    line = {k: v for k, v in line.items() if k != "compared"}
+    # a number that is not finite has no place in JSON: null, and not correct
+    line["compared"] = {
+        c["name"]: [c["value"] if math.isfinite(c["value"]) else None, c["limit"]]
+        for c in verdict["compared"]
+    }
+    return line
+
+
 def run_cell(cell: dict, seed: int, seconds: float, trace: bool, control: bool = False,
              **hooks) -> dict:
     """Dispatch on the traffic file's ``kind`` (``serve`` or ``train``)."""
@@ -145,6 +161,11 @@ def main(argv=None) -> int:
     if args.trace:
         # the other group too, for the builder's eye; the driver ignores it
         line["end_to_end_seen"] = run["e2e"]
+    line = compared_last(line, run["verdict"])
+    for c in run["verdict"]["compared"]:
+        print(f"perfbench: compared {c['name']} = {c['value']:.6g} (limit {c['limit']:.6g})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

@@ -30,12 +30,24 @@ def rehearse(name, seed, seconds=2.0, control=False, **hooks):
 
 @pytest.mark.parametrize("name", [w["name"] for w in BENCH["workloads"]])
 def test_cell_rehearses(name):
-    cell, run = rehearse(name, seed=2**31 + 77)
+    said = []
+    cell, run = rehearse(name, seed=2**31 + 77, log=said.append)
     assert run["verdict"]["correct"] is True, run["verdict"]
+    # every closed loop says how much of its list it used; the rehearsal's
+    # list (tiny.py) is long enough that no caller finds it spent
+    used = [line for line in said if "list used" in line]
+    assert len(used) == (cell["traffic"].get("loop") == "closed")
+    assert all(0 < float(line.split("list used ")[1].split("%")[0]) < 50 for line in used)
     assert run["compiles_in_window"] == 0
     assert run["failed"] == 0 and run["attempted"] > 0
     line = runner.result_line(BENCH, cell, run, CPU, trace=False)
     assert set(line) == {"correct", "attempted", "failed", "metrics", "device"}
+    # what main() prints: the numbers compared, each beside its limit, last
+    full = runner.compared_last({**line, "compiles_in_window": 0}, run["verdict"])
+    assert list(full)[-1] == "compared" and list(full)[:5] == list(line)
+    assert full["compared"] and all(
+        len(pair) == 2 and pair[0] <= pair[1] for pair in full["compared"].values()
+    )
     want = {m["name"] for m in spec.metrics_for(BENCH, "end_to_end", name)}
     assert set(line["metrics"]) == want and "setup_s" in want
     for m in line["metrics"].values():
@@ -54,6 +66,59 @@ def test_cell_rehearses(name):
     # ... and nothing is printed under a device metric's name from a CPU
     with pytest.raises((TypeError, KeyError)):
         runner.result_line(BENCH, cell, run, CPU, trace=True)
+
+
+def test_a_closed_loop_that_runs_dry_fails_the_run():
+    from lib import serving
+
+    cell = tiny_cell("batch-saturate")
+    cell["traffic"]["cycles"] = 1  # 16 requests for 8 callers and 3 seconds
+    said = []
+    runner.T_START = time.time()
+    with pytest.raises(serving.CellFailure, match="spent before the window closed"):
+        runner.run_cell(cell, 2**31 + 78, 2.0, False, log=said.append)
+    assert [line for line in said if "list used" in line] == [
+        "perfbench: list used 100% of 16 requests"
+    ]
+
+
+def test_a_traced_run_keeps_the_counters_at_the_traced_stretch_s_two_ends(monkeypatch):
+    from lib import serving, trace
+
+    calls = []
+
+    class FakeTracer:  # the CPU has no device plane to reduce: fake the tracer alone
+        def __init__(self, out_dir):
+            self.window_s = 0.0
+
+        def start(self):
+            calls.append("start")
+
+        def stop(self):
+            calls.append("stop")
+            self.window_s = 1.0
+
+        def result(self):
+            return {"busy_s": 0.5, "window_s": 1.0, "breakdown": {}, "ops": {"op": [0.5, 3]}}
+
+    scrape = serving.scrape
+    monkeypatch.setattr(trace, "Tracer", FakeTracer)
+    monkeypatch.setattr(serving, "scrape", lambda node: calls.append("scrape") or scrape(node))
+    cell = tiny_cell("batch-saturate")
+    runner.T_START = time.time()
+    run = runner.run_cell(cell, 2**31 + 79, 3.0, True)
+    # go, window opens, tracer starts, [t0 ... t1], tracer stops, window closes, end
+    assert calls == ["scrape", "scrape", "start", "scrape", "scrape", "stop", "scrape", "scrape"]
+    ctx = run["ctx"]
+    key = 'pygrid_serving_tokens_total{model="bench"}'
+    made = [ctx[k].get(key, 0.0) for k in ("snap_w0", "snap_t0", "snap_t1", "snap_w1")]
+    assert made == sorted(made) and made[2] > made[1], made
+    assert ctx["snap_t0"]["engine"]["model_id"] == "bench"
+    assert ctx["trace"]["ops"] == {"op": [0.5, 3]}
+    # an untraced run has neither
+    assert {"snap_t0", "snap_t1"} <= set(ctx)
+    _, plain = rehearse("batch-saturate", seed=2**31 + 79)
+    assert plain["ctx"]["snap_t0"] is None and plain["ctx"]["snap_t1"] is None
 
 
 def test_same_seed_same_traffic_open_loop_count():

@@ -41,6 +41,9 @@ def logits(w, tokens, cfg, dtype=jnp.float32):
         h = h + jax.nn.silu(h @ w["gate"].astype(dtype)) * (h @ w["up"].astype(dtype))
         return h @ e.T
 
+def served_gaps(w, cfg, request, answer, pad_to, control=None):
+    return reference.served_gaps(logits, w, cfg, request["prompt"], answer["tokens"], pad_to, control)
+
 def control_weights(w):
     return {n: reference.int8_values(x, -1 if n == "embed" else -2) for n, x in w.items()}
 
@@ -179,10 +182,12 @@ def test_one_of_each_is_added_as_new_files_only(tmp_path):
     for t in range(9, 14):  # greedy, through the same logits
         served.append(int(np.asarray(model.logits(w, jnp.asarray(seq), cfg))[0, t - 1].argmax()))
         seq[0, t] = served[-1]
-    gaps, cgaps = reference.served_gaps(
-        model, w, cfg, prompt, np.asarray([served]), 16, model.control_weights(w)
-    )
+    request = {"prompt": prompt, "n_new": 5, "fields": {}}
+    answer = {"tokens": np.asarray([served])}
+    gaps, cgaps = model.served_gaps(w, cfg, request, answer, 16, model.control_weights(w))
     assert gaps.shape == cgaps.shape == (5,) and float(np.abs(gaps).max()) < 1e-5
+    direct, _ = reference.served_gaps(model, w, cfg, prompt, answer["tokens"], 16)
+    assert np.array_equal(gaps, direct)  # the module or its logits: one comparison
     X = jnp.asarray(rng.integers(0, 64, (4, 12)))
     w3, losses = reference.sgd_steps(model, w, X, jnp.roll(X, -1, -1), 0.5, cfg, 3, 2)
     assert len(losses) == 3 and losses[2] < losses[1] < losses[0]
@@ -206,7 +211,13 @@ def test_a_model_type_with_no_adapter_names_the_missing_file(tmp_path):
     assert [b for b in spec.check_rules(bench, tmp_path) if "models/mamba9.py" in b]
     (tmp_path / "perfbench/models/mamba9.py").write_text("def logits(w, tokens, cfg):\n    pass\n")
     (bad,) = spec.check_rules(bench, tmp_path)
-    assert "make_weights" in bad and "tiny" in bad and "logits" not in bad.rsplit("does not define", 1)[1]
+    missing = bad.rsplit("does not define", 1)[1]
+    assert "make_weights" in bad and "tiny" in bad and "logits" not in missing
+    # eleven names, the comparison that decides a served cell's ``correct``
+    # among them: an adapter without it fails as one without ``logits`` does
+    assert len(spec.MODEL_CONTRACT) == len(set(spec.MODEL_CONTRACT)) == 11
+    assert "served_gaps" in missing
+    assert {n.strip() for n in missing.split(",")} == set(spec.MODEL_CONTRACT) - {"logits"}
 
 
 def test_the_runners_name_no_key_of_a_block_and_import_no_model():

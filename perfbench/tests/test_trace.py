@@ -74,6 +74,11 @@ def test_reduction_of_a_hand_written_trace(tmp_path):
     assert ops["fusion_f32_4"] == pytest.approx(1e-3)
     assert ops["fusion"] == pytest.approx(6e-3)
     assert ops["copy"] == pytest.approx(1e-3) and ops["convert"] == pytest.approx(0.5e-3)
+    # the whole table, with counts: what the breakdown was ranked from
+    assert {k: v[0] for k, v in out["ops"].items()} == ops
+    assert {k: v[1] for k, v in out["ops"].items()} == {
+        "fusion_bf16_8_16": 1, "fusion_f32_4": 1, "fusion": 1, "copy": 1, "convert": 1,
+    }
     gaps = dict(out["breakdown"]["idle_gaps"])
     assert gaps == {"after:while": pytest.approx(6e-3), "after:fusion_f32_4": pytest.approx(0.5e-3)}
     with pytest.raises(ValueError, match="no accelerator plane"):
@@ -91,3 +96,22 @@ def test_reduction_of_the_recorded_v5e_trace():
     assert 0.0 < out["idle_pct"] < 100.0
     assert out["breakdown"] == expected["breakdown"]
     assert len(out["breakdown"]["device_ops"]) <= 10
+
+
+@pytest.mark.parametrize("name", ["small", "engine"])
+def test_ops_holds_every_operation_of_a_recorded_trace(name):
+    expected = json.loads((FIXTURES / f"{name}.expected.json").read_text())
+    per_device = trace.device_events(FIXTURES / f"{name}.xplane.pb")
+    out = trace.reduce(per_device, expected["window_s"])
+    assert {k: list(v) for k, v in out["ops"].items()} == expected["ops"]
+    events = [e for evs in per_device.values() for e in evs if not trace.CONTAINERS.match(e[0])]
+    assert set(out["ops"]) == {trace.short_name(e[0]) for e in events}
+    assert sum(n for _, n in out["ops"].values()) == len(events)
+    assert sum(s for s, _ in out["ops"].values()) == pytest.approx(sum(e[2] for e in events))
+    # its ten largest are the breakdown's list, name for name and second for second
+    ten = sorted(out["ops"].items(), key=lambda kv: -kv[1][0])[:10]
+    assert [[k, v[0]] for k, v in ten] == out["breakdown"]["device_ops"]
+    assert out["breakdown"]["device_ops"] == expected.get(
+        "device_ops", expected.get("breakdown", {}).get("device_ops")
+    )
+    assert set(out) == {"busy_s", "window_s", "idle_pct", "breakdown", "ops"}

@@ -1,7 +1,10 @@
 """The stratified generator: the same multiset of lengths and the same
 count per window for every seed; order, pairing, arrivals and contents
-from the seed."""
+from the seed; named fields dealt in proportion; and every mix the
+benchmark has making what it made before fields came (PR 32)."""
 
+import collections
+import hashlib
 import json
 
 import numpy as np
@@ -62,7 +65,7 @@ def test_closed_loop_cycles_whole_grids():
     tr = mix("batch-saturate")
     a, b = traffic.build(tr, 1, 45.0), traffic.build(tr, 2, 45.0)
     grid = tr["grid"]
-    assert len(a["requests"]) == grid * tr["cycles"]
+    assert len(a["requests"]) == grid * tr["cycles"] == 8192
     assert all(r["due"] is None for r in a["requests"])
     for run in (a, b):
         for c in range(tr["cycles"]):
@@ -87,3 +90,95 @@ def test_free_arrivals_and_shared_prefixes_are_data_only():
     gam = traffic.build(dict(mix("chat-steady"), arrivals="gamma", arrival_cv=3.0), 5, 40.0)
     gaps = np.diff([r["due"] for r in gam["requests"]])
     assert gaps.std() / gaps.mean() > 1.5
+
+
+# ── what every mix made at PR 31, byte for byte ──────────────────────────
+
+RECORDED = json.loads((spec.BENCH_DIR / "tests/fixtures/traffic.sha256.json").read_text())
+
+
+def requests_digest(built, first=None):
+    """sha256 of a built list's requests as PR 31 knew them (every key but
+    ``fields``), of its first ``first`` where the list has grown since."""
+    rows = [{k: v for k, v in r.items() if k != "fields"} for r in built["requests"][:first]]
+    blob = json.dumps(
+        {"requests": rows, "lead_in_s": built["lead_in_s"], "loop": built["loop"]},
+        sort_keys=True,
+    )
+    return hashlib.sha256(blob.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2**31])
+@pytest.mark.parametrize("name", sorted(RECORDED["digests"]))
+def test_each_mix_makes_what_the_parent_made(name, seed):
+    bench = spec.load_benchmark()
+    (cell_name,) = [w["name"] for w in bench["workloads"] if w["traffic"] == name]
+    cell = spec.cell(bench, cell_name)
+    tr = cell["traffic"]
+    assert "fields" not in tr, "no mix the benchmark had gains the key"
+    if tr["kind"] == "serve":
+        built = traffic.build(tr, seed, RECORDED["seconds"])
+        assert all(r["fields"] == {} for r in built["requests"])
+        # a list that has grown since opens with the parent's requests
+        first = RECORDED.get(f"{name}_requests")
+        assert first is None or len(built["requests"]) >= first
+        got = requests_digest(built, first)
+    else:
+        from lib import training
+
+        X, y = training.make_batch(cell, seed)
+        got = hashlib.sha256(
+            np.asarray(X, np.int32).tobytes() + np.asarray(y, np.int32).tobytes()
+        ).hexdigest()
+    assert got == RECORDED["digests"][name][str(seed)]
+
+
+def test_a_closed_list_holds_four_times_what_the_ledger_has_seen():
+    # the sizing rule of lib/traffic.py. The fastest engines seen: 1,390.4
+    # tokens/s in batch-saturate (chip runs of PR 32: the 800 of the ledger's
+    # PRs 25-31 was the old list of 1,024 requests running dry), 4,652.8 in
+    # reason-saturate (ledger, PR 29, refused); lead-in 10 s + window 51 s
+    for name, fastest in (("batch-saturate", 1390.4), ("reason-saturate", 4652.8)):
+        tr = mix(name)
+        tokens = sum(r["n_new"] for r in traffic.build(tr, 0, 51.0)["requests"])
+        assert tokens / (fastest * (tr["lead_in_s"] + 51.0)) >= 3.99, (name, tokens)
+
+
+# ── named fields ─────────────────────────────────────────────────────────
+
+
+def test_weighted_fields_are_dealt_in_proportion_in_every_group():
+    steps = {"values": [1, 2, 4], "weights": [1, 2, 1]}
+    tr = dict(mix("batch-saturate"), cycles=3, fields={"steps": steps, "mode": "block"})
+    plain = traffic.build(dict(mix("batch-saturate"), cycles=3), 9, 51.0)
+    a, b = traffic.build(tr, 9, 51.0), traffic.build(tr, 10, 51.0)
+    # the last draw: everything else is what the mix without fields makes
+    assert requests_digest(a) == requests_digest(plain)
+    grid = tr["grid"]
+    for run in (a, b):
+        assert all(r["fields"]["mode"] == "block" for r in run["requests"])
+        for c in range(tr["cycles"]):
+            dealt = collections.Counter(
+                r["fields"]["steps"] for r in run["requests"][c * grid:(c + 1) * grid]
+            )
+            assert dealt == {1: 32, 2: 64, 4: 32}
+    order = lambda run: [r["fields"]["steps"] for r in run["requests"]]  # noqa: E731
+    assert order(a) != order(b) and order(a)[:grid] != order(a)[grid:2 * grid]
+    assert a == traffic.build(tr, 9, 51.0)
+    # an open loop deals within the lead-in and within the window
+    tr = dict(mix("chat-steady"), fields={"steps": {"values": [1, 4], "weights": [3, 1]}})
+    run = traffic.build(tr, 3, 51.0)
+    assert requests_digest(run) == requests_digest(traffic.build(mix("chat-steady"), 3, 51.0))
+    for flag in (False, True):
+        got = collections.Counter(
+            r["fields"]["steps"] for r in run["requests"] if r["counted"] is flag
+        )
+        n = sum(got.values())
+        assert abs(got[1] - 0.75 * n) <= 1 and got[1] + got[4] == n
+    # counts that do not divide: the remainder goes to the largest fractions
+    rng = np.random.default_rng(0)
+    assert collections.Counter(traffic.deal(["a", "b", "c"], [1, 1, 1], 8, rng)) == {
+        "a": 3, "b": 3, "c": 2,
+    }
+    with pytest.raises(ValueError, match="do not pair"):
+        traffic.deal([1, 2], [1], 4, rng)
