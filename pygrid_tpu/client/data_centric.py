@@ -198,11 +198,16 @@ class DataCentricFLClient:
         n_new: int = 16,
         temperature: float = 0.0,
         seed: int | None = None,
+        **fields: Any,
     ) -> Any:
         """Autoregressive generation from a hosted transformer bundle
         (``models.decode.bundle``): int prompt [B, P] → int tokens
         [B, n_new]. Greedy at ``temperature=0``, else sampled (``seed``
-        makes the server's sampling reproducible)."""
+        makes the server's sampling reproducible). ``fields`` go out
+        beside them as they are named (a block-diffusion model's
+        ``denoising_steps``); where the answer names more than its
+        tokens (such a model's ``reveal_step``), the whole answer comes
+        back as a mapping of arrays, ``tokens`` among them."""
         payload = {
             MSG_FIELD.MODEL_ID: model_id,
             MSG_FIELD.DATA: base64.b64encode(
@@ -213,10 +218,15 @@ class DataCentricFLClient:
         }
         if seed is not None:
             payload["seed"] = int(seed)
+        payload.update(fields)
         response = self.ws.send_json(REQUEST_MSG.RUN_GENERATION, **payload)
         if not response.get("success"):
             raise PyGridError(response.get("error", "generation failed"))
-        return np.asarray(response["tokens"])
+        answer = {
+            k: np.asarray(v) for k, v in response.items()
+            if k not in ("success", MSG_FIELD.REQUEST_ID)
+        }
+        return answer if len(answer) > 1 else answer["tokens"]
 
     def delete_model(self, model_id: str) -> dict:
         return self.ws.send_json(

@@ -66,15 +66,40 @@ def bundle(
     }
 
 
-def from_bundle(spec: dict) -> tuple[Any, Any]:
-    """Inverse of a family's ``bundle`` (validates the family tag):
-    ``(config, parameters)`` as the serving engine takes them."""
-    if isinstance(spec, dict) and spec.get("family") == "jamba":
-        from pygrid_tpu.models import jamba
+#: the served families, one registry: the bundle's ``family`` tag and the
+#: config's type name, each to the module that serves it (imported on
+#: first use: this module is the transformer's)
+FAMILIES = {
+    "transformer": ("TransformerConfig", "pygrid_tpu.models.decode"),
+    "jamba": ("JambaConfig", "pygrid_tpu.models.jamba"),
+    "sdar_moe": ("SdarConfig", "pygrid_tpu.models.sdar_moe"),
+}
 
-        return jamba.from_bundle(spec)
-    if not isinstance(spec, dict) or spec.get("family") != "transformer":
+
+def _family_module(tag: str) -> Any:
+    import importlib
+
+    return importlib.import_module(FAMILIES[tag][1])
+
+
+def family_of(cfg) -> Any:
+    """The module that serves configurations of ``cfg``'s type."""
+    name = type(cfg).__name__
+    for tag, (config_type, _) in FAMILIES.items():
+        if config_type == name:
+            return _family_module(tag)
+    raise ValueError(f"no served family has configurations of type {name}")
+
+
+def from_bundle(spec: dict) -> tuple[Any, Any]:
+    """Inverse of a family's ``bundle`` (by its ``family`` tag):
+    ``(config, parameters)`` as the serving engine takes them."""
+    tag = spec.get("family") if isinstance(spec, dict) else None
+    if not isinstance(tag, str) or tag not in FAMILIES:
         raise ValueError("not a generative transformer bundle")
+    module = _family_module(tag)
+    if module is not sys.modules[__name__]:
+        return module.from_bundle(spec)
     cfg = TransformerConfig(*[int(v) for v in spec["cfg"]])
     params = [jnp.asarray(p) for p in spec["params"]]
     expect = 2 + PARAMS_PER_LAYER * cfg.n_layers + 2
@@ -91,18 +116,19 @@ def from_bundle(spec: dict) -> tuple[Any, Any]:
 # family's module (:func:`family_of`): ``init_paged_cache``,
 # ``paged_prefill_chunk`` and ``paged_decode_step`` over a cache whose
 # first three fields are ``k, v, pos``, and the facts below. This module
-# is the transformer's; :mod:`pygrid_tpu.models.jamba` the hybrid's.
+# is the transformer's; :mod:`pygrid_tpu.models.jamba` the hybrid's and
+# :mod:`pygrid_tpu.models.sdar_moe` the block-diffusion decoder's.
 
 #: no recurrent state, so prefix pages can be shared: that sharing is the
 #: one thing a family switches off by saying True here
 RECURRENT = False
 
 
-def family_of(cfg) -> Any:
-    """The module that serves configurations of ``cfg``'s type."""
-    from pygrid_tpu.models import jamba
-
-    return jamba if isinstance(cfg, jamba.JambaConfig) else sys.modules[__name__]
+#: positions a row's forward carries: one, and every forward yields one
+#: token a row. A family that says more (a diffusion block) has a step
+#: that takes the block and its mask flags, and a forward of it may
+#: reveal none: the engine then holds the block's state between forwards
+BLOCK_LEN = 1
 
 
 def kv_layers(cfg: TransformerConfig) -> int:

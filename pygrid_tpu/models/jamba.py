@@ -108,6 +108,9 @@ class JambaConfig(NamedTuple):
 #: (a second request would need a snapshot of the state at the page edge)
 RECURRENT = True
 
+#: one position a row a forward, one token out of each
+BLOCK_LEN = 1
+
 
 def kv_layers(cfg: JambaConfig) -> int:
     """Layers that hold keys and values in the block pool."""

@@ -15,6 +15,13 @@ needs for sparse scaling. GShard-style top-1 routing with capacity:
 With enough capacity (no drops) the expert-parallel output equals the
 dense compute-every-expert reference bit-for-bit up to float
 reassociation — that is what the tests pin.
+
+The second half of the module is the SERVED expert layer
+(:func:`routed_experts`): a top-k softmax router without drops or
+capacity and a grouped gated-SiLU FFN that reads each touched expert's
+weights once — what :mod:`pygrid_tpu.models.sdar_moe` runs in every
+layer of every forward. The training-only top-1 layer above is untouched
+by it.
 """
 
 from __future__ import annotations
@@ -24,6 +31,8 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax, shard_map
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -133,3 +142,190 @@ def apply_expert_parallel(
         in_specs=(P(), P(axis), P(axis), P(axis), P(axis), P(axis)),
         out_specs=P(axis),
     )(*params, x)
+
+
+# ── the served layer: top-k routing, no drops, grouped experts ───────────
+#
+# ``T`` tokens each choose ``k`` of ``E`` experts: ``N = T·k`` assignments.
+# They are sorted by expert into a row layout in which every expert's
+# rows start on a tile boundary (``ROW_TILE`` rows), so one grid step of
+# the kernel is one tile of one expert: ``N/ROW_TILE + E`` tiles at most,
+# whatever the routing. Consecutive tiles of one expert find its weights
+# already in VMEM, tiles past the last live one do nothing and move
+# nothing, and an expert that received no row is never read: a forward's
+# expert traffic is the touched experts' weights, once each.
+
+#: rows of one grid step: two bfloat16 sublane tiles, so that the ~16
+#: rows an expert receives at 64 slots x 4 positions x 8 of 128 fit one
+#: step (a second step would push the expert's weights through the MXU
+#: again)
+ROW_TILE = 32
+
+#: VMEM the grouped kernel asks for: one expert's three matrices, double
+#: buffered (2 x 9.4 MB at 2048 x 768 in bfloat16), and the row tiles
+VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def route_topk(x: jax.Array, w_router: jax.Array, k: int):
+    """``softmax(x W_r)`` in float32 at full precision, the ``k`` largest
+    and their probabilities renormalised to sum to one: (``idx`` [T, k]
+    int32, ``p`` [T, k] float32). The product is 2048 x 128 a token:
+    float32 costs nothing and keeps the choice the reference's but at
+    true ties."""
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(
+            x.astype(jnp.float32), w_router.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST,
+        )
+        probs, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        return idx.astype(jnp.int32), probs / probs.sum(-1, keepdims=True)
+
+
+def grouped_layout(expert_ids: jax.Array, n_experts: int, tile: int):
+    """Where each of ``N`` assignments goes in the tile-aligned layout.
+    Returns ``dest`` [N] (its row), ``tile_expert`` [tiles] (the expert
+    whose weights a tile multiplies; a dead tile repeats the last live
+    one's, so it fetches nothing), ``n_live`` (tiles that hold rows) and
+    ``sizes`` [E] (rows an expert received)."""
+    n = expert_ids.shape[0]
+    n_tiles = -(-n // tile) + n_experts
+    sizes = jnp.zeros((n_experts,), jnp.int32).at[expert_ids].add(1)
+    tiles = -(-sizes // tile)
+    tile_end = jnp.cumsum(tiles)
+    n_live = tile_end[-1]
+    order = jnp.argsort(expert_ids, stable=True)
+    sorted_ids = expert_ids[order]
+    rank = jnp.arange(n, dtype=jnp.int32) - (jnp.cumsum(sizes) - sizes)[sorted_ids]
+    dest_sorted = (tile_end - tiles)[sorted_ids] * tile + rank
+    dest = jnp.zeros((n,), jnp.int32).at[order].set(dest_sorted)
+    t = jnp.arange(n_tiles, dtype=jnp.int32)
+    tile_expert = jnp.searchsorted(
+        tile_end, jnp.minimum(t, n_live - 1), side="right"
+    ).astype(jnp.int32)
+    return dest, tile_expert, n_live.astype(jnp.int32), sizes
+
+
+def _grouped_kernel(te_ref, live_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
+    @pl.when(pl.program_id(0) < live_ref[0])
+    def _():
+        prec = (
+            lax.Precision.HIGHEST if wg_ref.dtype == jnp.float32 else None
+        )
+        x = x_ref[...]
+        gate = jnp.dot(
+            x, wg_ref[0], preferred_element_type=jnp.float32, precision=prec
+        )
+        up = jnp.dot(
+            x, wu_ref[0], preferred_element_type=jnp.float32, precision=prec
+        )
+        hidden = (jax.nn.silu(gate) * up).astype(wd_ref.dtype)
+        o_ref[...] = jnp.dot(
+            hidden, wd_ref[0], preferred_element_type=jnp.float32,
+            precision=prec,
+        )
+
+
+def grouped_expert_ffn(
+    x_rows: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    tile_expert: jax.Array,
+    n_live: jax.Array,
+    interpret: bool = False,
+) -> jax.Array:
+    """``(silu(x W_gate,e) * x W_up,e) W_down,e`` for every row of the
+    tile-aligned layout (:func:`grouped_layout`), float32 out. ``x_rows``
+    [tiles·ROW_TILE, d] in the weights' dtype; the weights ``[E, d, f]``,
+    ``[E, d, f]``, ``[E, f, d]`` stay in HBM and one expert's three
+    matrices are in VMEM at a time. Rows of dead tiles are left
+    unwritten."""
+    n_rows, d = x_rows.shape
+    _, _, f = w_gate.shape
+    n_tiles = n_rows // ROW_TILE
+
+    def rows(t, te, live):
+        return (jnp.minimum(t, live[0] - 1), 0)
+
+    def expert(t, te, live):
+        return (te[t], 0, 0)
+
+    return pl.pallas_call(
+        _grouped_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_tiles,),
+            in_specs=[
+                pl.BlockSpec((ROW_TILE, d), rows),
+                pl.BlockSpec((1, d, f), expert),
+                pl.BlockSpec((1, d, f), expert),
+                pl.BlockSpec((1, f, d), expert),
+            ],
+            out_specs=pl.BlockSpec((ROW_TILE, d), rows),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n_rows, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=VMEM_LIMIT,
+        ),
+        interpret=interpret,
+        name="grouped_expert_ffn",
+    )(tile_expert, jnp.reshape(n_live, (1,)), x_rows, w_gate, w_up, w_down)
+
+
+def grouped_eligible(w_gate: jax.Array) -> bool:
+    """True where the expert FFN takes the kernel: on a TPU, with widths
+    that fill whole 128-lane rows. Everywhere else (the CPU, tier-1's
+    tiny widths) the same sorted rows go through ``lax.ragged_dot``."""
+    _, d, f = w_gate.shape
+    return jax.default_backend() == "tpu" and d % 128 == 0 and f % 128 == 0
+
+
+def routed_experts(
+    x: jax.Array,
+    w_router: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    k: int,
+    kernel: bool | None = None,
+    interpret: bool = False,
+):
+    """The served expert layer over ``x`` [T, d] (float32, normed):
+    ``sum_{e in top-k} p_e · W_down,e (silu(W_gate,e x) * W_up,e x)`` with
+    ``p`` the router's softmax renormalised over the ``k`` chosen; no
+    drops, no capacity, no shared expert. Returns (``y`` [T, d] float32,
+    the number of experts that received a row, int32: what the forward
+    had to read of this layer's experts)."""
+    n_experts = w_gate.shape[0]
+    idx, p = route_topk(x, w_router, k)
+    with jax.named_scope("moe.experts"):
+        ids = idx.reshape(-1)
+        token = jnp.arange(ids.shape[0], dtype=jnp.int32) // k
+        xw = x.astype(w_gate.dtype)
+        if grouped_eligible(w_gate) if kernel is None else kernel:
+            dest, tile_expert, n_live, sizes = grouped_layout(
+                ids, n_experts, ROW_TILE
+            )
+            x_rows = jnp.zeros(
+                (tile_expert.shape[0] * ROW_TILE, x.shape[1]), xw.dtype
+            ).at[dest].set(xw[token])
+            y = grouped_expert_ffn(
+                x_rows, w_gate, w_up, w_down, tile_expert, n_live,
+                interpret=interpret,
+            )[dest]
+        else:
+            sizes = jnp.zeros((n_experts,), jnp.int32).at[ids].add(1)
+            order = jnp.argsort(ids, stable=True)
+            xs = xw[token[order]]
+            dot = lambda a, w: lax.ragged_dot(  # noqa: E731
+                a, w, sizes, preferred_element_type=jnp.float32,
+                precision=(
+                    lax.Precision.HIGHEST if w.dtype == jnp.float32 else None
+                ),
+            )
+            hidden = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
+            ys = dot(hidden.astype(w_down.dtype), w_down)
+            y = jnp.zeros_like(ys).at[order].set(ys)
+        y = (y.reshape(-1, k, y.shape[-1]) * p[..., None]).sum(1)
+        return y, jnp.sum(sizes > 0).astype(jnp.int32)

@@ -31,6 +31,10 @@ class TransformerConfig(NamedTuple):
     d_ff: int = 256
     max_len: int = 256
 
+    @property
+    def head_dim(self) -> int:
+        return self.d_model // self.n_heads
+
 
 PARAMS_PER_LAYER = 12  # ln1(2) + attn(4) + ln2(2) + mlp(4)
 N_GLOBAL = 4  # embed, pos, ln_f scale/bias

@@ -577,13 +577,29 @@ def host_model(ctx: NodeContext, message: dict, conn: Connection) -> dict:
 _MAX_GENERATION_CACHE_ELEMENTS = 1 << 28
 
 
+def _json_integer(raw) -> bool:
+    """The wire contract of a run-generation count: a JSON integer —
+    bools, strings ("8" would int()-coerce) and fractional or
+    non-finite floats all bounce."""
+    import math
+
+    return (
+        not isinstance(raw, bool)
+        and isinstance(raw, (int, float))
+        and not (isinstance(raw, float) and not math.isfinite(raw))
+        and int(raw) == raw
+    )
+
+
 def _prepare_generation(ctx: NodeContext, message: dict):
     """Validate a run-generation message end to end. Returns either an
     error-response dict or ``(hosted, prompt, n_new, temperature,
-    seed)`` with the hosted bundle parsed into
-    ``hosted.generation_cache``. Shared by the WS handler and the async
-    HTTP route so the two doors cannot drift on the typed-error
-    contract."""
+    seed, fields)`` with the hosted bundle parsed into
+    ``hosted.generation_cache``; ``fields`` holds what the request names
+    beside those that the hosted family takes (a block family's
+    ``denoising_steps``), as keywords of the engine's ``enqueue``.
+    Shared by the WS handler and the async HTTP route so the two doors
+    cannot drift on the typed-error contract."""
     import math
 
     import numpy as np
@@ -618,7 +634,8 @@ def _prepare_generation(ctx: NodeContext, message: dict):
     # plans/translators.py. The batch engine's cache is allocated per
     # SLOT, not per request, but the same cap bounds how many rows one
     # frame may enqueue.
-    cache_elems = decode.family_of(cfg).cache_elements(cfg, prompt.shape[0])
+    family = decode.family_of(cfg)
+    cache_elems = family.cache_elements(cfg, prompt.shape[0])
     if cache_elems > _MAX_GENERATION_CACHE_ELEMENTS:
         return {
             SUCCESS: False,
@@ -634,14 +651,7 @@ def _prepare_generation(ctx: NodeContext, message: dict):
             ERROR: f"prompt token out of range [0, {cfg.vocab})",
         }
     raw_n_new = message.get("n_new", 16)
-    # same wire contract as temperature below: a JSON integer — bools,
-    # strings ("8" would int()-coerce) and fractional floats all bounce
-    if (
-        isinstance(raw_n_new, bool)
-        or not isinstance(raw_n_new, (int, float))
-        or (isinstance(raw_n_new, float) and not math.isfinite(raw_n_new))
-        or int(raw_n_new) != raw_n_new
-    ):
+    if not _json_integer(raw_n_new):
         return {SUCCESS: False, ERROR: "n_new must be a JSON integer"}
     n_new = int(raw_n_new)
     if n_new < 1:
@@ -667,12 +677,7 @@ def _prepare_generation(ctx: NodeContext, message: dict):
         return {SUCCESS: False, ERROR: "temperature must be finite and >= 0"}
     seed = message.get("seed")
     if seed is not None:
-        if (
-            isinstance(seed, bool)
-            or not isinstance(seed, (int, float))
-            or (isinstance(seed, float) and not math.isfinite(seed))
-            or int(seed) != seed
-        ):
+        if not _json_integer(seed):
             return {SUCCESS: False, ERROR: "seed must be a JSON integer"}
         seed = int(seed)
         # PRNGKey overflows int64 with an uncaught OverflowError —
@@ -682,14 +687,54 @@ def _prepare_generation(ctx: NodeContext, message: dict):
                 SUCCESS: False,
                 ERROR: "seed must be in [0, 2**63)",
             }
-    return hosted, prompt, n_new, temperature, seed
+    fields = {}
+    block_len = int(family.BLOCK_LEN)
+    raw_steps = message.get("denoising_steps") if block_len > 1 else None
+    if raw_steps is not None:
+        # a block family's own field, held to n_new's wire contract (a
+        # causal family answers as it always has: it reads no such key)
+        if not _json_integer(raw_steps):
+            return {
+                SUCCESS: False, ERROR: "denoising_steps must be a JSON integer",
+            }
+        if int(raw_steps) < 1 or block_len % int(raw_steps):
+            return {
+                SUCCESS: False,
+                ERROR: (
+                    f"denoising_steps must divide the block length "
+                    f"({block_len})"
+                ),
+            }
+        fields["denoising_steps"] = int(raw_steps)
+    if block_len > 1 and temperature > 0.0:
+        return {
+            SUCCESS: False,
+            ERROR: "this model reveals its blocks greedily: temperature must be 0",
+        }
+    return hosted, prompt, n_new, temperature, seed, fields
+
+
+def generation_answer(result) -> dict:
+    """A finished request as both doors answer it: ``tokens``, and beside
+    them whatever else the engine's future named (a block family's
+    ``reveal_step`` and dropped tail), each as nested lists."""
+    import numpy as np
+
+    if not isinstance(result, dict):
+        result = {"tokens": result}
+    return {
+        SUCCESS: True, **{k: np.asarray(v).tolist() for k, v in result.items()}
+    }
 
 
 def run_generation(ctx: NodeContext, message: dict, conn: Connection) -> dict:
     """Autoregressive generation from a hosted transformer bundle —
     the serving twin of ``run_inference`` for the generative model
     family. Message fields: ``model_id``, ``data`` (serialized int
-    prompt [B, P]), ``n_new``, optional ``temperature`` + ``seed``.
+    prompt [B, P]), ``n_new``, optional ``temperature`` + ``seed``, and
+    for a block-diffusion family ``denoising_steps``; the answer holds
+    ``tokens`` and, from such a family, ``reveal_step`` (and the last
+    block's ``dropped_tokens`` / ``dropped_reveal_step``) beside them.
     Gated by the same ``allow_remote_inference`` flag.
 
     The handler is a thin enqueue-and-await wrapper over the serving
@@ -702,18 +747,17 @@ def run_generation(ctx: NodeContext, message: dict, conn: Connection) -> dict:
     reference ``decode.generate`` (bit for bit on the CPU at f32; up to
     rounding ties on the TPU — docs/SERVING.md)."""
     _authenticated(conn)
-    import numpy as np
-
     try:
         prep = _prepare_generation(ctx, message)
         if isinstance(prep, dict):
             return prep
-        hosted, prompt, n_new, temperature, seed = prep
+        hosted, prompt, n_new, temperature, seed, fields = prep
         engine = ctx.serving.engine_for(
             str(message[MSG_FIELD.MODEL_ID]), hosted
         )
-        toks = engine.submit(prompt, n_new, temperature, seed)
-        return {SUCCESS: True, "tokens": np.asarray(toks).tolist()}
+        return generation_answer(
+            engine.submit(prompt, n_new, temperature, seed, **fields)
+        )
     except E.ServerBusyError as err:
         return {SUCCESS: False, "busy": True, ERROR: str(err)}
     except (E.PyGridError, ValueError, TypeError) as err:

@@ -665,12 +665,13 @@ async def dc_run_generation(request: web.Request) -> web.Response:
     engine future directly, so a slow generation holds no executor
     thread at all. Body mirrors the WS ``run-generation`` event
     (``model_id``, base64 ``data``, ``n_new``, ``temperature``,
-    ``seed``); session token via the ``token`` header. A full queue is
+    ``seed``, a block family's ``denoising_steps``), and so does the
+    answer; session token via the ``token`` header. A full queue is
     503, validation defects are 400 — same typed messages as the WS
     twin (both doors share ``_prepare_generation``)."""
     import asyncio
 
-    from pygrid_tpu.node.events import _prepare_generation
+    from pygrid_tpu.node.events import _prepare_generation, generation_answer
 
     ctx = _ctx(request)
     try:
@@ -681,18 +682,16 @@ async def dc_run_generation(request: web.Request) -> web.Response:
         prep = await _off_loop(_prepare_generation, ctx, body)
         if isinstance(prep, dict):
             return web.json_response(prep, status=400)
-        hosted, prompt, n_new, temperature, seed = prep
+        hosted, prompt, n_new, temperature, seed, fields = prep
         engine = ctx.serving.engine_for(
             str(body[MSG_FIELD.MODEL_ID]), hosted
         )
-        future = engine.enqueue(prompt, n_new, temperature, seed)
-        tokens = await asyncio.wait_for(
+        future = engine.enqueue(prompt, n_new, temperature, seed, **fields)
+        result = await asyncio.wait_for(
             asyncio.wrap_future(future),
             timeout=engine.config.default_timeout_s,
         )
-        return web.json_response(
-            {"success": True, "tokens": tokens.tolist()}
-        )
+        return web.json_response(generation_answer(result))
     except asyncio.TimeoutError:
         return _json_error(
             E.PyGridError("generation timed out awaiting the batch engine"),

@@ -38,7 +38,14 @@ the node generates:
   ``PYGRID_FUSED_DECODE=off``): per-row token budgets freeze rows that
   finish mid-scan (their writes trash-route, their positions park), so
   the host pays one dispatch + one token fetch per quantum instead of
-  per step — the dominant cost of small/medium-model decode.
+  per step — the dominant cost of small/medium-model decode;
+- a family whose forward carries a BLOCK of positions (``BLOCK_LEN`` >
+  1: generation by diffusion over blocks) takes the same step loop with
+  one program a width, ``paged_block_step``: a row holds its current
+  block (tokens, which positions are still masked, the forward's index)
+  on the host between forwards, a forward reveals nought to
+  ``BLOCK_LEN`` of its tokens, and the row's position moves only when
+  the block is committed. Rows in different phases share a dispatch.
 
 Every instant of the worker thread belongs to one of six phases of a
 :class:`~pygrid_tpu.telemetry.loopclock.LoopClock` — ``idle`` (nothing
@@ -140,10 +147,15 @@ class _Row:
     __slots__ = (
         "pending", "row", "batch", "prompt", "n_new", "temperature",
         "seed", "keys", "out", "last_token", "enqueued_at", "admitted_at",
-        "pages", "shared_pages", "start", "demand",
+        "pages", "shared_pages", "start", "demand", "denoising_steps",
+        "blk_pos", "blk_tokens", "blk_masked", "blk_step", "blk_forward",
+        "reveal", "dropped",
     )
 
-    def __init__(self, pending, row, batch, prompt, n_new, temperature, seed):
+    def __init__(
+        self, pending, row, batch, prompt, n_new, temperature, seed,
+        denoising_steps=None,
+    ):
         self.pending = pending
         self.row = row
         self.batch = batch
@@ -167,6 +179,20 @@ class _Row:
         self.shared_pages = 0
         self.start = 0
         self.demand = 0
+        #: a block family's row: how many denoising forwards reveal a
+        #: whole block; the current block (its first position, its
+        #: tokens, which are still masked, the forward that revealed
+        #: each, the next forward's index); and, beside ``out``, the
+        #: forward that revealed each token, with the (token, forward)
+        #: pairs the last block made past ``n_new``
+        self.denoising_steps = denoising_steps
+        self.blk_pos = 0
+        self.blk_tokens = None
+        self.blk_masked = None
+        self.blk_step = None
+        self.blk_forward = 0
+        self.reveal: list[int] = []
+        self.dropped: list[tuple[int, int]] = []
 
 
 class _Pending:
@@ -180,15 +206,31 @@ class _Pending:
         self.request_id = uuid.uuid4().hex[:16]
         self.future: Future = Future()
         self.tokens = np.zeros((batch, n_new), np.int32)
+        #: what a family answers beside the tokens, a list a name with
+        #: one entry a row
+        self.extras: dict[str, list] = {}
         self.remaining = batch
 
-    def finish_row(self, row: int, toks: list[int]) -> None:
+    def finish_row(self, row: int, toks: list[int], **extras) -> None:
+        """The row's tokens and, where its family names more, each
+        further field's row. The future resolves to the tokens alone, or
+        to a mapping ``{"tokens": ..., name: [B, ...]}``."""
         self.tokens[row] = toks
+        for name, value in extras.items():
+            rows = self.extras.setdefault(name, [None] * len(self.tokens))
+            rows[row] = value
         self.remaining -= 1
         if self.remaining == 0 and not self.future.done():
             # done() covers both a waiter's cancel AND a racing
             # _fail_all that already set an exception
-            self.future.set_result(self.tokens)
+            self.future.set_result(
+                {
+                    "tokens": self.tokens,
+                    **{k: np.asarray(v, np.int32) for k, v in self.extras.items()},
+                }
+                if self.extras
+                else self.tokens
+            )
 
 
 class GenerationEngine:
@@ -217,7 +259,15 @@ class GenerationEngine:
         #: K/V can be mapped into a second request, the state that ran
         #: over the same tokens cannot
         self._recurrent = bool(self._family.RECURRENT)
-        self._fused = pagedkv.fused_enabled(self.config.fused)
+        #: positions a row's forward carries (1: a token a row a step)
+        self._block_len = int(self._family.BLOCK_LEN)
+        # the fused scan carries one token a row from step to step ON
+        # the device; a block's state between forwards (which positions
+        # are revealed) lives on the host, so a family that says
+        # BLOCK_LEN > 1 has nothing the scan could carry
+        self._fused = (
+            pagedkv.fused_enabled(self.config.fused) and self._block_len == 1
+        )
         self.programs = ProgramSet(
             cfg,
             compute_dtype=self.config.compute_dtype,
@@ -344,14 +394,33 @@ class GenerationEngine:
         n_new: int,
         temperature: float = 0.0,
         seed: int | None = None,
+        denoising_steps: int | None = None,
     ) -> Future:
         """Queue a [B, P] int prompt for generation; resolves to int32
-        tokens [B, n_new]. Raises :class:`ServerBusyError` when the
-        queue is at depth — callers translate it to the typed wire
-        error. Validation (shape, vocab range, cache caps, temperature/
-        seed domains) is the caller's job: this is the hot path."""
+        tokens [B, n_new] (a block family: to a mapping that names
+        ``reveal_step`` and the last block's dropped tail beside them).
+        ``denoising_steps`` is a block family's: how many forwards
+        reveal a whole block (a divisor of ``BLOCK_LEN``, which is the
+        default); a causal family takes no notice of it. Raises
+        :class:`ServerBusyError` when the queue is at depth — callers
+        translate it to the typed wire error. Validation (shape, vocab
+        range, cache caps, temperature/seed domains) is the caller's
+        job: this is the hot path."""
         prompt = np.asarray(prompt, np.int32)
         batch, p_len = prompt.shape
+        if self._block_len > 1:
+            if denoising_steps is None:
+                denoising_steps = self._block_len
+            if (
+                denoising_steps < 1
+                or self._block_len % int(denoising_steps)
+                or float(temperature) > 0.0
+            ):
+                raise E.PyGridError(
+                    f"denoising_steps ({denoising_steps}) must divide the "
+                    f"block length ({self._block_len}), and a block is "
+                    "revealed greedily (temperature 0)"
+                )
         if p_len + n_new > self.cfg.max_len:
             raise E.PyGridError(
                 f"prompt ({p_len}) + n_new ({n_new}) exceeds max_len "
@@ -375,7 +444,7 @@ class GenerationEngine:
         rows = [
             _Row(
                 pending, b, batch, prompt[b], n_new, float(temperature),
-                seed,
+                seed, denoising_steps,
             )
             for b in range(batch)
         ]
@@ -383,7 +452,7 @@ class GenerationEngine:
         # prefix cache ALREADY holds for this prompt (a probe —
         # admission re-matches for real; an eviction in between just
         # parks the row until blocks free)
-        pages_per_row = -(-(p_len + n_new) // self._block)
+        pages_per_row = -(-self._span(p_len, n_new) // self._block)
         if pages_per_row > self._pool.usable:
             raise E.PyGridError(
                 f"request needs {pages_per_row} KV blocks of "
@@ -436,11 +505,14 @@ class GenerationEngine:
         temperature: float = 0.0,
         seed: int | None = None,
         timeout: float | None = None,
+        denoising_steps: int | None = None,
     ) -> np.ndarray:
         """Blocking :meth:`enqueue` — the WS handler's enqueue-and-await
         wrapper (handler threads wait here; the device loop stays on the
         engine thread)."""
-        future = self.enqueue(prompt, n_new, temperature, seed)
+        future = self.enqueue(
+            prompt, n_new, temperature, seed, denoising_steps
+        )
         try:
             return future.result(
                 timeout if timeout is not None
@@ -662,6 +734,13 @@ class GenerationEngine:
                 "this TPU (pool %s %s does not tile for the paged kernel)",
                 self.model_id, self._k.dtype, tuple(self._k.shape),
             )
+
+        def run(fn, *inputs):
+            self._swap_cache(fn(
+                self.params, self._k, self._v, self._pos, *self._state,
+                self._table(), *inputs,
+            ))
+
         zero_key = jnp.zeros((2,), jnp.uint32)
         seen = set()
         for p_len in prompt_lens or (1,):
@@ -669,31 +748,31 @@ class GenerationEngine:
             if bucket in seen:
                 continue
             seen.add(bucket)
-            fn = self.programs.paged_prefill(bucket)
-            _tok, self._k, self._v, self._pos, *self._state = fn(
-                self.params, self._k, self._v, self._pos,
-                *self._state, self._table(), jnp.int32(0),
+            run(
+                self.programs.paged_prefill(bucket), jnp.int32(0),
                 jnp.zeros((bucket,), jnp.int32), jnp.int32(0),
                 jnp.int32(1), jnp.float32(0.0), zero_key,
             )
         for w in self._widths:
-            fn = self.programs.paged_decode(w)
-            _toks, self._k, self._v, self._pos, *self._state = fn(
-                self.params, self._k, self._v, self._pos,
-                *self._state, self._table(), jnp.zeros((w,), jnp.int32),
-                jnp.zeros((w,), jnp.float32),
-                jnp.zeros((w, 2), jnp.uint32),
+            if self._block_len > 1:
+                # a block family's one program a width; with an all-zero
+                # table every row writes to trash and nothing advances
+                run(
+                    self.programs.paged_block_step(w),
+                    jnp.zeros((w, self._block_len), jnp.int32),
+                    jnp.zeros((w, self._block_len), bool),
+                    jnp.zeros((w,), jnp.int32), jnp.zeros((w,), bool),
+                )
+                continue
+            run(
+                self.programs.paged_decode(w), jnp.zeros((w,), jnp.int32),
+                jnp.zeros((w,), jnp.float32), jnp.zeros((w, 2), jnp.uint32),
             )
             if self._fused:
                 # zero budgets: every row frozen, nothing advances
-                fn = self.programs.paged_decode_fused(
-                    w, self.config.quantum
-                )
-                _e, self._k, self._v, self._pos, *self._state = fn(
-                    self.params, self._k, self._v, self._pos,
-                    *self._state, self._table(),
-                    jnp.zeros((w,), jnp.int32),
-                    jnp.zeros((w,), jnp.int32),
+                run(
+                    self.programs.paged_decode_fused(w, self.config.quantum),
+                    jnp.zeros((w,), jnp.int32), jnp.zeros((w,), jnp.int32),
                     jnp.zeros((w,), jnp.float32),
                     jnp.zeros((self.config.quantum, w, 2), jnp.uint32),
                 )
@@ -806,10 +885,14 @@ class GenerationEngine:
                 )
             request_id = row.pending.request_id
             t0 = time.perf_counter()
-            chunk_len = len(row.prompt) - row.start
+            # where the prompt's whole blocks end: the prompt itself for
+            # a causal family; a block family's prompt tail opens the
+            # first generated block instead of being prefilled
+            prompt_end = len(row.prompt) // self._block_len * self._block_len
+            chunk_len = prompt_end - row.start
             bucket = self._prompt_bucket(chunk_len)
             padded = np.zeros(bucket, np.int32)
-            padded[:chunk_len] = row.prompt[row.start :]
+            padded[:chunk_len] = row.prompt[row.start : prompt_end]
             # tokens the prefill program computes over against the
             # prompt's own: the bucket's padding is real device work
             telemetry.incr_many(
@@ -826,24 +909,32 @@ class GenerationEngine:
             # ``admit``: ``prefill`` begins at the program's call
             args = (
                 self._table(), jnp.int32(slot), jnp.asarray(padded),
-                jnp.int32(row.start), jnp.int32(len(row.prompt)),
+                jnp.int32(row.start), jnp.int32(prompt_end),
                 jnp.float32(row.temperature), self._key_for(row, 0),
             )
             fn = self.programs.paged_prefill(bucket)
             clock.enter("prefill", request_id=request_id, bucket=bucket)
             # the cache buffers are single-writer: only the engine
             # thread swaps _k/_v/_pos between lock epochs
-            # gridlint: disable-next=GL202
-            tok, self._k, self._v, self._pos, *self._state = fn(
+            tok, *counted = self._swap_cache(fn(
                 self.params, self._k, self._v, self._pos,
                 *self._state, *args,
-            )
+            ))
             # publish the full-prompt pages for future prefix hits
             # (first prefill wins; a matched chain is only touched)
             # gridlint: disable-next=GL202 — PrefixCache takes its own lock; only the engine thread mutates it
             self._prefix.insert(row.prompt, row.pages)
             first = int(tok)
             clock.enter("emit")
+            if self._block_len > 1:
+                # no token comes of a block family's prefill: the row's
+                # first block opens where the prompt's whole blocks end
+                telemetry.observe(
+                    "serving_prefill_seconds", time.perf_counter() - t0
+                )
+                self._note_expert_bytes("prefill", counted[0])
+                self._open_block(row, prompt_end)
+                continue
             telemetry.observe(
                 "serving_ttft_seconds", time.perf_counter() - row.enqueued_at
             )
@@ -859,7 +950,9 @@ class GenerationEngine:
         private pages for the rest of prompt + n_new, evicting LRU
         prefix entries under pressure. False = pool exhausted, caller
         parks the row. Engine thread only."""
-        total_pages = -(-(len(row.prompt) + row.n_new) // self._block)
+        total_pages = -(
+            -self._span(len(row.prompt), row.n_new) // self._block
+        )
         shared = self._prefix.match(row.prompt)
         need = total_pages - len(shared)
         priv = self._pool.alloc(need)
@@ -926,34 +1019,26 @@ class GenerationEngine:
         return live, next(w for w in self._widths if w > live[-1][0])
 
     def _step(self) -> bool:
-        """One batched decode step over every live slot; returns True if
-        any slot freed (a finished request left the batch)."""
-        import jax.numpy as jnp
-
+        """One batched forward over every live slot (a decode step; a
+        block family's block step); returns True if any slot freed (a
+        finished request left the batch)."""
         clock = self._clock
         clock.enter("build")
         live, width = self._live_snapshot()
         if not live:
             return False
         clock.annotate(path="step", width=width, live=len(live), steps=1)
-        tokens = np.zeros(width, np.int32)
-        temps = np.zeros(width, np.float32)
-        keys = np.zeros((width, 2), np.uint32)
-        for i, row in live:
-            tokens[i] = row.last_token
-            temps[i] = row.temperature
-            if row.keys is not None:
-                keys[i] = row.keys[len(row.out)]
+        build = self._block_inputs if self._block_len > 1 else self._token_inputs
+        fn, inputs, take = build(width, live)
         t0 = time.perf_counter()
-        fn = self.programs.paged_decode(width)
-        # gridlint: disable-next=GL202 — cache buffers are engine-thread-confined
-        toks, self._k, self._v, self._pos, *self._state = fn(
+        # the ONE decode dispatch site of the per-step path, whatever
+        # the family
+        head = self._swap_cache(fn(
             self.params, self._k, self._v, self._pos, *self._state,
-            self._table(),
-            jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys),
-        )
+            self._table(), *inputs,
+        ))
         clock.enter("fetch")
-        toks = np.asarray(toks)
+        head = [np.asarray(x) for x in head]
         dt = time.perf_counter() - t0
         clock.enter("emit")
         self._note_dispatch("step", width, live, 1, dt)
@@ -962,11 +1047,128 @@ class GenerationEngine:
             bounds=_OCCUPANCY_BOUNDS,
         )
         freed = False
-        for i, row in live:
+        for (i, row), tokens in zip(live, take(live, *head)):
             telemetry.observe("serving_token_seconds", dt)
-            if self._emit(i, row, int(toks[i])):
+            if self._emit(i, row, tokens):
                 freed = True
         return freed
+
+    def _swap_cache(self, result: tuple) -> tuple:
+        """Take a program's returned cache buffers (its last outputs, in
+        the cache's own order) in place of the donated ones; returns what
+        the program answered before them. Engine thread only."""
+        n = 3 + len(self._state)
+        self._k, self._v, self._pos, *self._state = result[-n:]
+        return result[:-n]
+
+    def _token_inputs(self, width: int, live: list[tuple[int, "_Row"]]):
+        """A decode step's program, its inputs after the table, and how
+        its answer reads: one token a live row."""
+        import jax.numpy as jnp
+
+        tokens = np.zeros(width, np.int32)
+        temps = np.zeros(width, np.float32)
+        keys = np.zeros((width, 2), np.uint32)
+        for i, row in live:
+            tokens[i] = row.last_token
+            temps[i] = row.temperature
+            if row.keys is not None:
+                keys[i] = row.keys[len(row.out)]
+        return (
+            self.programs.paged_decode(width),
+            (jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys)),
+            lambda live, toks: [int(toks[i]) for i, _ in live],
+        )
+
+    def _block_inputs(self, width: int, live: list[tuple[int, "_Row"]]):
+        """A block step's program, its inputs after the table, and how
+        its answer reads. A row whose block still has masked positions
+        takes a DENOISING forward that reveals ``BLOCK_LEN /
+        denoising_steps`` of them (or those that are left); a row whose
+        block is whole takes the COMMIT forward, which reveals none and
+        moves its position on."""
+        import jax.numpy as jnp
+
+        L = self._block_len
+        tokens = np.zeros((width, L), np.int32)
+        masked = np.zeros((width, L), bool)
+        n_reveal = np.zeros(width, np.int32)
+        advance = np.zeros(width, bool)
+        for i, row in live:
+            tokens[i] = row.blk_tokens
+            masked[i] = row.blk_masked
+            left = int(row.blk_masked.sum())
+            n_reveal[i] = min(L // row.denoising_steps, left)
+            advance[i] = left == 0
+        commits = int(advance.sum())
+        telemetry.incr_many(
+            "serving_block_forwards_total", "kind",
+            {"denoise": len(live) - commits, "commit": commits},
+        )
+
+        def take(live, toks, chosen, expert_bytes):
+            self._note_expert_bytes("step", expert_bytes)
+            return [
+                self._close_forward(row, toks[i], chosen[i], advance[i])
+                for i, row in live
+            ]
+
+        return (
+            self.programs.paged_block_step(width),
+            tuple(map(jnp.asarray, (tokens, masked, n_reveal, advance))),
+            take,
+        )
+
+    @staticmethod
+    def _note_expert_bytes(path: str, read) -> None:
+        """Bytes of expert weights one forward had to read, as the block
+        family's program counted them (touched (layer, expert) pairs x
+        one expert's matrices), under the path that ran it."""
+        telemetry.incr(
+            "serving_expert_bytes_total", float(read), kind="read", path=path
+        )
+
+    def _open_block(self, row: _Row, pos: int) -> None:
+        """Start the row's block at position ``pos``: what of it is
+        prompt is known, the rest masked."""
+        at = pos + np.arange(self._block_len)
+        known = at < len(row.prompt)
+        row.blk_pos = pos
+        row.blk_tokens = np.where(
+            known, row.prompt[np.minimum(at, len(row.prompt) - 1)], 0
+        ).astype(np.int32)
+        row.blk_masked = ~known
+        row.blk_step = np.full(self._block_len, -1, np.int32)
+        row.blk_forward = 0
+
+    def _close_forward(self, row: _Row, toks, chosen, committed) -> tuple:
+        """Take one forward's answer into the row's block; returns the
+        tokens the forward yields the row, in position order: the
+        block's own once its last masked position is revealed (those
+        past ``n_new`` go to ``row.dropped``), none before, none from a
+        commit (which opens the next block)."""
+        if committed:
+            self._open_block(row, row.blk_pos + self._block_len)
+            return ()
+        row.blk_tokens[chosen] = toks[chosen]
+        row.blk_step[chosen] = row.blk_forward
+        row.blk_masked &= ~chosen
+        row.blk_forward += 1
+        if row.blk_masked.any():
+            return ()
+        at = row.blk_pos + np.arange(self._block_len)
+        end = len(row.prompt) + row.n_new
+        past = at >= end
+        kept = (at >= len(row.prompt)) & ~past
+        row.reveal.extend(row.blk_step[kept].tolist())
+        row.dropped.extend(
+            zip(row.blk_tokens[past].tolist(), row.blk_step[past].tolist())
+        )
+        if not row.out:
+            telemetry.observe(
+                "serving_ttft_seconds", time.perf_counter() - row.enqueued_at
+            )
+        return tuple(row.blk_tokens[kept].tolist())
 
     def _fused_scan(self) -> None:
         """Up to ``quantum`` decode steps for every live slot in ONE
@@ -1091,6 +1293,10 @@ class GenerationEngine:
         pages up to its length at each step (parked once the row has its
         tokens, as its position is), one trash page for each free slot
         inside the width."""
+        if self._block_len > 1:
+            # a block step attends over the row's block and all before
+            ends = np.array([r.blk_pos + self._block_len for _, r in live])
+            return int((-(-ends // self._block)).sum()) + width - len(live)
         base = np.array([len(r.prompt) + len(r.out) for _, r in live])
         need = np.array([r.n_new - len(r.out) for _, r in live])
         lengths = base[:, None] + np.minimum(np.arange(steps), need[:, None])
@@ -1098,23 +1304,36 @@ class GenerationEngine:
         pages = -(-np.minimum(lengths, rows) // self._block)
         return int(pages.sum()) + (width - len(live)) * steps
 
-    def _emit(self, slot: int, row: _Row, token: int) -> bool:
-        """Append one generated token to a row; retire the row (freeing
-        its slot) when it has its n_new tokens. Returns True if freed."""
-        row.out.append(token)
-        row.last_token = token
-        with self._lock:
-            # stats() reads this counter under the lock from other
-            # threads — the engine thread must not += it lock-free
-            self._tokens_out += 1
-        telemetry.incr("serving_tokens_total", model=self.model_id)
+    def _emit(self, slot: int, row: _Row, token) -> bool:
+        """Append what one forward yielded a row: a causal family's one
+        token (an int), or the tuple of nought to ``BLOCK_LEN`` tokens a
+        block family's forward made final; retire the row (freeing its
+        slot) when it has its n_new tokens. Returns True if freed."""
+        tokens = token if isinstance(token, tuple) else (token,)
+        if tokens:
+            row.out.extend(tokens)
+            row.last_token = tokens[-1]
+            with self._lock:
+                # stats() reads this counter under the lock from other
+                # threads — the engine thread must not += it lock-free
+                self._tokens_out += len(tokens)
+            telemetry.incr(
+                "serving_tokens_total", len(tokens), model=self.model_id
+            )
         if len(row.out) < row.n_new:
             return False
         with self._lock:
             self._slots[slot] = None
             self._live = max(0, self._live - 1)
         self._release_row(slot, row)
-        row.pending.finish_row(row.row, row.out)
+        # a block family names, beside its tokens, the forward that
+        # revealed each and what its last block made past n_new
+        extras = {
+            "reveal_step": row.reveal,
+            "dropped_tokens": [t for t, _ in row.dropped],
+            "dropped_reveal_step": [f for _, f in row.dropped],
+        } if self._block_len > 1 else {}
+        row.pending.finish_row(row.row, row.out, **extras)
         if row.pending.remaining == 0:
             telemetry.incr(
                 "serving_requests_total", outcome="ok",
@@ -1230,6 +1449,11 @@ class GenerationEngine:
                 logger.exception("flight-recorder capture failed")
 
     # ── helpers ─────────────────────────────────────────────────────────
+
+    def _span(self, p_len: int, n_new: int) -> int:
+        """Positions a row's pages must cover: prompt and new tokens, up
+        to the end of the block the last one falls in."""
+        return -(-(p_len + n_new) // self._block_len) * self._block_len
 
     def _prompt_bucket(self, p_len: int) -> int:
         for b in self._prompt_buckets:

@@ -126,9 +126,8 @@ def block_bytes(cfg, block: int, dtype: Any) -> int:
     from pygrid_tpu.models import decode
 
     model = decode.family_of(cfg)
-    dh = cfg.d_model // cfg.n_heads
     return int(
-        2 * model.kv_layers(cfg) * block * model.kv_heads(cfg) * dh
+        2 * model.kv_layers(cfg) * block * model.kv_heads(cfg) * cfg.head_dim
         * jnp.dtype(dtype).itemsize
     )
 

@@ -8,7 +8,9 @@ surface is fixed up front:
   true length traced) — admission cost is O(#buckets) compiles ever;
 - one **decode-step** program per slot-width *bucket*, and one
   **fused** ``quantum``-step scan per width — the steady-state loop is
-  O(#width buckets) compiles ever;
+  O(#width buckets) compiles ever; a family whose forward carries a
+  block of positions (``BLOCK_LEN`` > 1) has one **block-step** program
+  per width in their place;
 - ``n_new`` never appears in any trace: it is a host-side loop bound.
 
 Temperature and the PRNG key are traced arguments (the greedy/sampled
@@ -78,6 +80,7 @@ class ProgramSet:
         self._paged_prefill: dict[int, Callable] = {}
         self._paged_decode: dict[int, Callable] = {}
         self._paged_fused: dict[tuple[int, int], Callable] = {}
+        self._paged_block: dict[int, Callable] = {}
         self._compiles = 0
 
     def compile_count(self) -> int:
@@ -94,6 +97,7 @@ class ProgramSet:
                 *self._paged_prefill.values(),
                 *self._paged_decode.values(),
                 *self._paged_fused.values(),
+                *self._paged_block.values(),
             ]
         )
 
@@ -116,6 +120,30 @@ class ProgramSet:
                 temp > 0.0, sampled, jnp.argmax(logits, axis=-1)
             ).astype(jnp.int32)
 
+    @staticmethod
+    def _reveal(logits, masked, n_reveal):
+        """What one forward of a block reveals. ``logits`` [w, L, vocab]:
+        each position's own; ``masked`` [w, L] bool; ``n_reveal`` [w]:
+        how many of a row's masked positions to reveal (0: none, a
+        commit). A position's token is its argmax and its confidence the
+        largest probability of its softmax; the ``n_reveal`` most
+        confident masked positions are chosen, the earlier at a tie.
+        Returns (tokens [w, L] int32, chosen [w, L] bool)."""
+        import jax
+        import jax.numpy as jnp
+
+        with jax.named_scope("sample.reveal"):
+            top = jnp.max(logits, axis=-1)
+            conf = 1.0 / jnp.sum(jnp.exp(logits - top[..., None]), axis=-1)
+            conf = jnp.where(masked, conf, -1.0)
+            at = jnp.arange(conf.shape[-1])
+            ahead = (conf[:, None, :] > conf[:, :, None]) | (
+                (conf[:, None, :] == conf[:, :, None])
+                & (at[None, None, :] < at[None, :, None])
+            )
+            chosen = masked & (ahead.sum(-1) < n_reveal[:, None])
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), chosen
+
     # One compile per chunk/width bucket ever, with the block TABLE a
     # plain traced argument (constant [S, max_pages] shape — table
     # content changes at admission without retracing) and ``start``/
@@ -129,7 +157,9 @@ class ProgramSet:
         prefix of ``start`` tokens; first token picked on-device. A
         family whose cache has more arrays than ``k, v, pos`` (a
         recurrent state) takes and returns them after ``pos``, donated
-        like the rest: so for every paged program below."""
+        like the rest: so for every paged program below. What a family's
+        prefill answers after its cache (a block family's expert bytes)
+        comes back between the token and the cache."""
         fn = self._paged_prefill.get(bucket)
         if fn is None:
             import jax
@@ -139,12 +169,12 @@ class ProgramSet:
 
             def _paged_prefill(params, *args):
                 table, slot, chunk, start, length, temp, key = args[n:]
-                logits, cache = model.paged_prefill_chunk(
+                logits, cache, *counted = model.paged_prefill_chunk(
                     params, cache_of(args[:n]), table, slot, chunk, start,
                     length, cfg, cd,
                 )
                 tok = self._pick(logits, temp, key)
-                return (tok, *cache)
+                return (tok, *counted, *cache)
 
             fn = telemetry.profiler.wrap(
                 jax.jit(_paged_prefill, donate_argnums=self._donated),
@@ -239,4 +269,40 @@ class ProgramSet:
             )
             self._paged_decode[width] = fn
             self._count("paged_decode")
+        return fn
+
+    def paged_block_step(self, width: int) -> Callable:
+        """``fn(params, k, v, pos, table, tokens[w, L], masked[w, L],
+        n_reveal[w], advance[w]) -> (tokens[w, L], chosen[w, L],
+        expert_bytes, k, v, pos)`` — one forward of the current block of
+        the first ``w`` slots of a family whose forward carries ``L =
+        BLOCK_LEN`` positions. Rows in different phases share the
+        dispatch: a denoising row reveals ``n_reveal`` of its masked
+        positions (``chosen``, each with the token beside it), a
+        committing row reveals none and its position moves on
+        (``advance``). ``expert_bytes`` is what the forward read of the
+        experts' weights, as the family counts it."""
+        fn = self._paged_block.get(width)
+        if fn is None:
+            import jax
+
+            model, cfg, cd = self._family, self.cfg, self.compute_dtype
+            cache_of, n = self._cache_of, self._cache_arrays
+
+            def _paged_block_step(params, *args):
+                table, tokens, masked, n_reveal, advance = args[n:]
+                logits, cache, expert_bytes = model.paged_decode_step(
+                    params, cache_of(args[:n]), table, tokens, cfg, cd,
+                    active=advance, masked=masked,
+                )
+                toks, chosen = self._reveal(logits, masked, n_reveal)
+                return (toks, chosen, expert_bytes, *cache)
+
+            fn = telemetry.profiler.wrap(
+                jax.jit(_paged_block_step, donate_argnums=self._donated),
+                kind="paged_block_step", bucket=width,
+                model_id=self.model_id,
+            )
+            self._paged_block[width] = fn
+            self._count("paged_block_step")
         return fn

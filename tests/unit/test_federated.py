@@ -641,9 +641,14 @@ def test_deadline_with_zero_diffs_closes_cycle_without_checkpoint(fresh_db):
             break
         time.sleep(0.05)
     assert ctl.cycle_manager._cycles.first(id=first.id).is_completed
-    # model untouched, next cycle spawned
+    # model untouched, next cycle spawned (the timer thread marks the cycle
+    # complete a few queries before it creates the next one)
     model = ctl.model_manager.get(fl_process_id=1)
     assert ctl.model_manager.load(model_id=model.id, alias="latest").number == 1
+    while time.monotonic() < deadline:
+        if ctl.cycle_manager._cycles.first(fl_process_id=1, is_completed=False):
+            break
+        time.sleep(0.05)
     assert ctl.cycle_manager.last(1).sequence == 2
 
 

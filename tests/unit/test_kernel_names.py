@@ -157,3 +157,85 @@ def test_decode_step_at_the_cells_width_holds_nothing_pool_sized(
     one_layer, tables = "768,64,16,128]", f"{w * MAX_PAGES},64,16,128]"
     assert not re.search(rf"= \w+\[({re.escape(one_layer)}|{re.escape(tables)})", text)
     assert not re.search(r"= \w+\[24,768,64,16,128\][^ ]* copy\(", text)
+
+
+def test_grouped_expert_ffn_lowers_for_the_v5e_under_its_name(
+    one_chip, no_compile_cache
+):
+    """The served expert layer at the block-diffusion cell's widths (64
+    rows x 4 positions, 8 of 128 experts of 2048 x 768, bfloat16): one
+    kernel, one expert's three matrices in VMEM at a time, under the name
+    the roofline reader sums (``perfbench/metrics/expert_ffn_roofline_pct.
+    blockdiff.py``)."""
+    from pygrid_tpu.models import moe
+
+    def arg(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = (
+        jax.jit(lambda *a: moe.routed_experts(*a, 8, kernel=True))
+        .lower(
+            arg((256, 2048), jnp.float32), arg((2048, 128)),
+            arg((128, 2048, 768)), arg((128, 2048, 768)), arg((128, 768, 2048)),
+        ).compile()
+    )
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "grouped_expert_ffn" in text
+    # 256 x 8 assignments in tiles of 32 rows, and a tile more an expert:
+    # the rows the kernel writes, whatever the routing
+    assert f"f32[{(256 * 8 // 32 + 128) * 32},2048]" in text
+    # nothing of the size of a layer's experts is copied or converted
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 1024 * 1024
+
+
+def test_block_step_gathers_its_pages_from_the_pool_where_it_lies(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """The block-diffusion family's width-64 step at the cell's K/V
+    shapes (4 K/V heads of 128, 1,024 blocks of 64; two layers and few
+    experts, to keep the compile short): each layer's two gathers read
+    the pool itself. Indexing the layer out first (``pool[layer][table]``)
+    made XLA copy that layer, 67 MB, before every gather (PERF.md §6, PR
+    34); a reshape of the pool made it copy the whole pool."""
+    import re
+
+    from pygrid_tpu.models import sdar_moe
+    from pygrid_tpu.serving.programs import ProgramSet
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = sdar_moe.SdarConfig(
+        vocab=1024, d_model=512, n_heads=32, n_kv_heads=4, head_dim=128,
+        n_layers=2, n_experts=8, top_k=2, d_expert=128, max_len=1024,
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda p: arg(p.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: sdar_moe.init(jax.random.PRNGKey(0), cfg)),
+    )
+    w, blocks, pages = 64, 1024, 16
+    pool = arg((cfg.n_layers, blocks, 64, 4, 128), jnp.bfloat16)
+    step = ProgramSet(cfg).paged_block_step(w)
+    while not hasattr(step, "lower"):  # the profiler's wrapper
+        step = step.__wrapped__
+    text = step.lower(
+        params, pool, pool, arg((w,), jnp.int32),
+        arg((w, pages), jnp.int32), arg((w, 4), jnp.int32),
+        arg((w, 4), jnp.bool_), arg((w,), jnp.int32), arg((w,), jnp.bool_),
+    ).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    gathers = re.findall(
+        r"= bf16\[1024,64,4,128\]\S* fusion\((%[\w.-]+),[^\n]*attn\.block/gather", entry
+    )
+    assert len(gathers) == 2 * cfg.n_layers
+    # every gather's operand is the pool as the scatter before it left it
+    for operand in gathers:
+        assert re.search(
+            rf"{re.escape(operand)} = bf16\[{cfg.n_layers},1024,64,4,128\]", entry
+        ), operand
+    # and nothing else of a layer's size (the gathers' results are: 64
+    # rows x 16 pages), or of the pool's, is made on the way
+    assert len(re.findall(r"= bf16\[1024,64,4,128\]", entry)) == len(gathers)
+    assert not re.search(r"= bf16\[\d+,64,4,128\]\S* copy\(", entry)

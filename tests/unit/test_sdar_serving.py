@@ -1,0 +1,377 @@
+"""The block-diffusion family with routed experts (``pygrid_tpu/models/
+sdar_moe.py``) through the serving engine, against the plain reference that
+the benchmark keeps (``perfbench/models/sdar_moe.py``: float32, ``highest``,
+every expert over every position, no cache, nothing of the program
+imported).
+
+Size (``tiny``): 2 layers, 8 experts top-2, hidden 64, 4 query heads on 2
+K/V heads of 16, vocabulary 128, the mask token id 127; float32 weights on
+the CPU. The program's prefill and block steps are compared with the
+reference on LOGITS; the engine's answers with a plain-Python generate over
+the reference, token for token and ``reveal_step`` for ``reveal_step``.
+
+``TOL``: program and reference run the same float32 mathematics in another
+order (``rsqrt`` norms, the experts grouped instead of one after another,
+K/V read back through the paged pool); logits of size ~3 differ by at most
+3.4e-6 over the cases below (my CPU runs, PR 34). 5e-5 is the limit the
+jamba family's tests hold, fifteen times that, and thousands of times under
+what a fault does (a K/V row written to the wrong page moves a logit by
+0.1 and more).
+"""
+
+from __future__ import annotations
+
+import json
+import sys
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from pygrid_tpu import telemetry
+from pygrid_tpu.models import decode, jamba, moe, sdar_moe
+from pygrid_tpu.models import transformer as T
+from pygrid_tpu.serving import EngineConfig, GenerationEngine, ProgramSet
+from pygrid_tpu.utils import exceptions as E
+
+ROOT = Path(__file__).resolve().parents[2]
+if str(ROOT / "perfbench") not in sys.path:
+    sys.path.append(str(ROOT / "perfbench"))
+from lib import spec  # noqa: E402
+
+TOL = 5e-5
+PAGE = 16
+PAD = 64
+
+
+@pytest.fixture(scope="module")
+def model():
+    return spec.load_model("sdar_moe")
+
+
+@pytest.fixture(scope="module")
+def cfg(model):
+    cfg = json.loads((ROOT / "perfbench/configs/sdar-30b-a3b-chat.json").read_text())
+    cfg.update(model.tiny(cfg))
+    return cfg
+
+
+@pytest.fixture(scope="module")
+def scfg(model, cfg):
+    return model.sdar_config(cfg)
+
+
+@pytest.fixture(scope="module")
+def params(model, cfg):
+    return model.make_program_params(3, cfg, "float32")
+
+
+@pytest.fixture(scope="module")
+def weights(model, cfg):
+    return model.make_weights(3, cfg, "float32")
+
+
+def _tokens(seed, n, vocab=128):
+    return np.random.default_rng(seed).integers(0, vocab, n).astype(np.int32)
+
+
+def _engine(scfg, params, **over):
+    kw = dict(max_slots=4, slot_buckets=(1, 4), min_prompt_bucket=8, block_size=PAGE)
+    kw.update(over)
+    return GenerationEngine(scfg, params, EngineConfig(**kw), model_id="sdar")
+
+
+def _count(name, **labels):
+    return sum(
+        v for (n, lab), v in telemetry.counters().items()
+        if n == name and labels.items() <= dict(lab).items()
+    )
+
+
+# ── the registry and the family's facts ──────────────────────────────────
+
+
+def test_one_registry_finds_a_family_by_config_type_and_by_bundle_tag(scfg, params):
+    assert decode.family_of(scfg) is sdar_moe
+    assert decode.family_of(jamba.JambaConfig()) is jamba
+    assert decode.family_of(T.TransformerConfig()) is decode
+    with pytest.raises(ValueError, match="no served family"):
+        decode.family_of(("not", "a", "config"))
+    back_cfg, back = decode.from_bundle(sdar_moe.bundle(scfg, params))
+    assert back_cfg == scfg
+    assert jax.tree.all(jax.tree.map(lambda a, b: bool((a == b).all()), back, params))
+    tparams = T.init(jax.random.PRNGKey(0), T.TransformerConfig())
+    assert decode.from_bundle(decode.bundle(T.TransformerConfig(), tparams))[0] == T.TransformerConfig()
+    for bad in ({"family": "nobody"}, {"family": ["sdar_moe"]}, [1, 2]):
+        with pytest.raises(ValueError, match="not a generative"):
+            decode.from_bundle(bad)
+    wrong = sdar_moe.bundle(scfg, params)
+    wrong["params"]["layers"][0].pop("router")
+    with pytest.raises(ValueError, match="layer 0"):
+        decode.from_bundle(wrong)
+
+
+def test_the_family_says_what_the_engine_asks(scfg):
+    assert sdar_moe.BLOCK_LEN == 4 and decode.BLOCK_LEN == jamba.BLOCK_LEN == 1
+    assert not sdar_moe.RECURRENT and sdar_moe.kv_heads(scfg) == 2
+    from pygrid_tpu.serving import pagedkv
+
+    # k and v, 2 layers, a page of 16, 2 K/V heads of 16 (NOT d_model / n_heads), float32
+    assert pagedkv.block_bytes(scfg, 16, jnp.float32) == 2 * 2 * 16 * 2 * 16 * 4
+    with pytest.raises(ValueError, match="block boundary"):
+        sdar_moe.init_paged_cache(scfg, 2, 4, 6)
+
+
+# ── the expert layer ─────────────────────────────────────────────────────
+
+
+def _dense_experts(x, lp, k):
+    """Every expert over every token; the router's top-k as a weight."""
+    with jax.default_matmul_precision("highest"):
+        r = jax.nn.softmax(x @ lp["router"], -1)
+        p, idx = jax.lax.top_k(r, k)
+        p = p / p.sum(-1, keepdims=True)
+        hidden = jax.nn.silu(jnp.einsum("td,edf->tef", x, lp["w_gate"]))
+        hidden = hidden * jnp.einsum("td,edf->tef", x, lp["w_up"])
+        every = jnp.einsum("tef,efd->ted", hidden, lp["w_down"])
+        return (jnp.take_along_axis(every, idx[..., None], 1) * p[..., None]).sum(1), idx
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["pallas-interpreted", "ragged-dot"])
+@pytest.mark.parametrize("tokens", [1, 16, 100])
+def test_grouped_experts_equal_dense_compute_every_expert(params, kernel, tokens):
+    lp = params["layers"][1]
+    x = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, 64))
+    want, idx = _dense_experts(x, lp, 2)
+    got, touched = jax.jit(
+        lambda x: moe.routed_experts(
+            x, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"], 2,
+            kernel=kernel, interpret=True,
+        )
+    )(x)
+    assert float(jnp.abs(got - want).max()) <= 2e-6
+    # the count the roofline reader divides by: experts that received a row
+    assert int(touched) == len(np.unique(np.asarray(idx)))
+
+
+def test_grouped_layout_starts_every_expert_on_a_tile_and_drops_nothing():
+    ids = jnp.asarray([5, 0, 5, 5, 2, 0, 5, 5, 5], jnp.int32)
+    dest, tile_expert, n_live, sizes = moe.grouped_layout(ids, 8, tile=4)
+    assert sizes.tolist() == [2, 0, 1, 0, 0, 6, 0, 0]
+    # expert 0: one tile, expert 2: one, expert 5: two; the rest of the
+    # 9/4 + 8 tiles are dead and repeat the last live tile's expert
+    assert int(n_live) == 4
+    assert tile_expert.tolist()[:4] == [0, 2, 5, 5]
+    assert set(tile_expert.tolist()[4:]) == {5}
+    assert sorted(dest.tolist()) == [0, 1, 4, 8, 9, 10, 11, 12, 13]
+    assert len(set(dest.tolist())) == 9
+    for a, row in zip(ids.tolist(), dest.tolist()):
+        assert tile_expert[row // 4] == a
+
+
+# ── the program against the reference, on logits ─────────────────────────
+
+
+def _cache_after_prefill(scfg, params, prompt, pages):
+    cache = sdar_moe.init_paged_cache(scfg, 2, 12, PAGE)
+    table = jnp.zeros((2, 128 // PAGE), jnp.int32).at[1, : len(pages)].set(
+        jnp.asarray(pages, jnp.int32)
+    )
+    whole = len(prompt) // 4 * 4
+    chunk = np.zeros(32, np.int32)
+    chunk[:whole] = prompt[:whole]
+    _, cache, read = sdar_moe.paged_prefill_chunk(
+        params, cache, table, jnp.int32(1), jnp.asarray(chunk), jnp.int32(0),
+        jnp.int32(whole), scfg,
+    )
+    return cache, table, whole, float(read)
+
+
+@pytest.mark.parametrize("p_len", [8, 21, 30])
+def test_prefill_and_block_steps_agree_with_the_full_forward(
+    model, cfg, scfg, params, weights, p_len
+):
+    """The prompt's whole blocks through prefill, then every later block
+    twice through the one step function: a denoising forward with two of
+    its positions masked, then the commit forward with all four known.
+    Each forward's logits are the reference's for that state; the commit
+    forwards' K/V are what the next block reads."""
+    seq = _tokens(p_len, 40)
+    seq[3], seq[p_len // 4 * 4 + 1] = 127, 127  # the mask token's id, as a real token
+    cache, table, whole, read = _cache_after_prefill(scfg, params, seq[:p_len], [3, 7, 5])
+    assert int(cache.pos[1]) == whole
+    # the last layer's experts feed nothing a prefill returns: one layer's are read
+    assert 0 < read <= 8 * sdar_moe.expert_bytes(params)
+    step = jax.jit(lambda cache, tok, masked, active: sdar_moe.paged_decode_step(
+        params, cache, table, tok, scfg, active=active, masked=masked,
+    ))
+    worst = 0.0
+    for a in range(whole, 40, 4):
+        masked = np.array([False, True, False, True])
+        for flags, commit in ((masked, False), (np.zeros(4, bool), True)):
+            block = np.where(flags, 0, seq[a : a + 4])  # what a masked slot says is ignored
+            state = np.concatenate([seq[:a], np.where(flags, 127, seq[a : a + 4])])
+            want = np.asarray(model.logits(weights, jnp.asarray(state[None]), cfg)[0, a:])
+            got, cache, read = step(
+                cache, jnp.asarray(np.stack([np.zeros(4, np.int32), block])),
+                jnp.asarray(np.stack([np.zeros(4, bool), flags])),
+                jnp.asarray([False, commit]),
+            )
+            worst = max(worst, float(np.abs(np.asarray(got[1]) - want).max()))
+            assert int(cache.pos[1]) == (a + 4 if commit else a)
+            assert float(read) % sdar_moe.expert_bytes(params) == 0
+    assert worst <= TOL, worst
+
+
+def test_a_shared_prefix_page_serves_a_second_prompt(model, cfg, scfg, params, weights):
+    """A page ends on a block boundary, so its K/V depend on nothing after
+    it: a second prompt that opens with the same 16 tokens is prefilled
+    from position 16 on over the first one's page."""
+    first, second = _tokens(1, 24), _tokens(2, 28)
+    second[:16] = first[:16]
+    cache, table, _, _ = _cache_after_prefill(scfg, params, first, [3, 7])
+    table = table.at[0, :2].set(jnp.asarray([3, 9], jnp.int32))  # page 3 shared, 9 its own
+    chunk = np.zeros(16, np.int32)
+    chunk[:12] = second[16:28]
+    _, cache, _ = sdar_moe.paged_prefill_chunk(
+        params, cache, table, jnp.int32(0), jnp.asarray(chunk), jnp.int32(16),
+        jnp.int32(28), scfg,
+    )
+    block = _tokens(3, 4)
+    got, _, _ = sdar_moe.paged_decode_step(
+        params, cache, table, jnp.asarray(block[None]), scfg,
+        active=jnp.asarray([False]), masked=jnp.zeros((1, 4), bool),
+    )
+    state = np.concatenate([second, block])
+    want = np.asarray(model.logits(weights, jnp.asarray(state[None]), cfg)[0, 28:])
+    assert float(np.abs(np.asarray(got[0]) - want).max()) <= TOL
+
+
+def test_the_reveal_rule_takes_the_most_confident_masked_positions():
+    logits = jnp.log(jnp.asarray([[
+        [0.7, 0.2, 0.1], [0.1, 0.5, 0.4], [0.05, 0.05, 0.9], [0.3, 0.6, 0.1],
+    ]] * 3))
+    masked = jnp.asarray([[True, True, True, True], [True, True, False, True], [False] * 4])
+    toks, chosen = ProgramSet._reveal(logits, masked, jnp.asarray([2, 1, 4]))
+    assert toks.tolist() == [[0, 1, 2, 1]] * 3
+    assert chosen.tolist() == [
+        [True, False, True, False],  # 0.9 and 0.7
+        [True, False, False, False],  # 0.9 is known already: 0.7
+        [False] * 4,  # nothing masked, nothing revealed, whatever was asked
+    ]
+    # a tie goes to the earlier position
+    same = jnp.zeros((1, 4, 3))
+    assert ProgramSet._reveal(same, jnp.ones((1, 4), bool), jnp.asarray([1]))[1].tolist() == [
+        [True, False, False, False]
+    ]
+
+
+# ── the engine against the plain generate ────────────────────────────────
+
+CASES = [  # prompt length, n_new, denoising_steps
+    (9, 10, 4), (16, 8, 2), (3, 5, 1), (21, 12, 4), (8, 7, 2), (30, 1, 1), (13, 16, 1),
+]
+
+
+@pytest.fixture(scope="module")
+def served(model, cfg, scfg, params, weights):
+    """Every case enqueued at once on a four-slot engine (rows in different
+    phases and with different ``denoising_steps`` share dispatches), and
+    the plain generate's answer beside each."""
+    telemetry.reset()
+    eng = _engine(scfg, params)
+    try:
+        prompts = [_tokens(100 + i, p) for i, (p, _, _) in enumerate(CASES)]
+        prompts[0][-1] = 127  # a prompt tail that IS the mask token's id
+        futures = [
+            eng.enqueue(prompt[None], n, denoising_steps=steps)
+            for prompt, (_, n, steps) in zip(prompts, CASES)
+        ]
+        got = [
+            {k: np.asarray(v).tolist() for k, v in f.result(300).items()} for f in futures
+        ]
+        stats, ledger = eng.stats(), eng.ledger()
+        # the bus is the process's: read it before another test's engine adds to it
+        stats["bus"] = {
+            "tokens": _count("serving_tokens_total"),
+            "fused": _count("serving_fused_scans_total"),
+            **{
+                kind: _count("serving_block_forwards_total", kind=kind)
+                for kind in ("denoise", "commit")
+            },
+            **{
+                path: _count("serving_expert_bytes_total", kind="read", path=path)
+                for path in ("step", "prefill")
+            },
+        }
+    finally:
+        eng.close()
+    want = [
+        model.generate(weights, cfg, prompt, n, steps, pad_to=PAD)
+        for prompt, (_, n, steps) in zip(prompts, CASES)
+    ]
+    return got, want, stats, ledger
+
+
+@pytest.mark.parametrize("case", range(len(CASES)), ids=[f"P{p}-n{n}-s{s}" for p, n, s in CASES])
+def test_engine_answers_the_plain_generate_s_tokens_and_reveal_steps(served, case):
+    got, want, _, _ = served
+    p_len, n_new, steps = CASES[case]
+    assert got[case] == want[case]
+    assert np.asarray(got[case]["tokens"]).shape == (1, n_new)
+    assert len(got[case]["dropped_tokens"][0]) == -(p_len + n_new) % 4
+    # denoising_steps forwards reveal a block: no later forward is named
+    assert max(got[case]["reveal_step"][0]) <= steps - 1
+
+
+def test_an_answer_may_hold_the_mask_token_s_id_as_a_real_token(
+    model, cfg, scfg, params, weights
+):
+    """Masked is a flag the engine holds, never ``token == mask_id``. With
+    the mask token's id moved to 68, a token these weights like to make,
+    this request's first block reveals a real 68 at its forward 0 and runs
+    a second forward with it standing there, known: an engine that read
+    the id as the flag would mask it again."""
+    cfg68 = dict(cfg, assumed=dict(cfg["assumed"], mask_token_id=68))
+    prompt = np.random.default_rng(6).integers(0, 128, 10).astype(np.int32)
+    eng = _engine(scfg._replace(mask_id=68), params)
+    try:
+        out = eng.submit(prompt[None], 9, denoising_steps=2, timeout=300)
+    finally:
+        eng.close()
+    got = {k: np.asarray(v).tolist() for k, v in out.items()}
+    assert got["tokens"][0][0] == 68 and got["reveal_step"][0][:2] == [0, 0]
+    assert got == model.generate(weights, cfg68, prompt, 9, 2, pad_to=PAD)
+
+
+def test_counters_and_the_ledger_after_the_cases(served):
+    got, _, stats, ledger = served
+    assert ledger["balanced"] and ledger["drained"]
+    tokens = sum(n for _, n, _ in CASES)
+    bus = stats["bus"]
+    assert stats["tokens_total"] == tokens == bus["tokens"]
+    # a block of four costs its denoising forwards and a commit; the last
+    # block of a row is not committed: nothing reads its K/V
+    denoise = sum(max(g["reveal_step"][0] + g["dropped_reveal_step"][0]) + 1 for g in got)
+    assert bus["denoise"] >= denoise
+    blocks = sum(-(-(p + n) // 4) - p // 4 for p, n, _ in CASES)
+    assert bus["commit"] == blocks - len(CASES)
+    assert not stats["fused"] and bus["fused"] == 0
+    per_expert = 3 * 64 * 32 * 4
+    for path in ("step", "prefill"):
+        assert bus[path] > 0 and bus[path] % per_expert == 0
+
+
+def test_typed_errors_at_the_engine(scfg, params):
+    eng = _engine(scfg, params)
+    try:
+        for bad in ({"denoising_steps": 3}, {"denoising_steps": 0}, {"temperature": 0.5}):
+            with pytest.raises(E.PyGridError, match="denoising_steps"):
+                eng.enqueue(_tokens(0, 8)[None], 4, **bad)
+        with pytest.raises(E.PyGridError, match="exceeds max_len"):
+            eng.enqueue(_tokens(0, 100)[None], 29)
+    finally:
+        eng.close()

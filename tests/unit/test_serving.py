@@ -348,7 +348,7 @@ def test_serving_telemetry_families_flow(engine):
 FAMILY_CONTRACT = {
     "PagedCache", "init_paged_cache", "paged_prefill_chunk",
     "paged_decode_step", "kv_layers", "kv_heads", "kv_kernel",
-    "state_bytes_per_slot", "cache_elements", "RECURRENT",
+    "state_bytes_per_slot", "cache_elements", "RECURRENT", "BLOCK_LEN",
 }
 
 _PACKAGE = Path(__file__).resolve().parents[2] / "pygrid_tpu"
@@ -395,11 +395,13 @@ def _family_attributes_used(path: Path) -> set[str]:
     return used
 
 
-@pytest.mark.parametrize("family", ["decode", "jamba"])
+@pytest.mark.parametrize("family", ["decode", "jamba", "sdar_moe"])
 def test_a_family_module_is_these_ten_names_and_nothing_else(family):
-    """What a third family has to write, pinned: the module exposes the
-    contract, and the node and the serving package (read from their
-    source) reach a family through no other attribute."""
+    """What a further family has to write, pinned: the module exposes the
+    contract (the ten names and, since the block-diffusion family,
+    ``BLOCK_LEN``: the positions a row's forward carries), and the node
+    and the serving package (read from their source) reach a family
+    through no other attribute."""
     import importlib
 
     module = importlib.import_module(f"pygrid_tpu.models.{family}")
