@@ -47,13 +47,32 @@ the node generates:
   ``BLOCK_LEN`` of its tokens, and the row's position moves only when
   the block is committed. Rows in different phases share a dispatch.
 
+**The loop dispatches ahead of what it has read.** Nothing the host
+decides between two steps of a causal family depends on the tokens:
+there is no stop token, so who is live, who ends and who is admitted at
+step n+1 follows from counts. A row therefore carries how many tokens
+are SCHEDULED for it (launched programs will make them) apart from how
+many have ARRIVED in ``row.out``; the loop plans by the first: a row
+leaves its slot, and its pages the pool, when its last token is
+scheduled. The device runs its programs in order, so a prefill into the
+same slot or pages queues behind the step that still writes them. The
+last tokens stay on the device (``programs``: ``last``), and what a
+launched program will answer the host waits in a FIFO of arrivals:
+``_step`` builds and launches step n+1 and only then fetches the
+arrivals up to step n, so the device has its next program while the host
+hands tokens out. Nothing stays in flight where nobody would collect it:
+the FIFO is drained when nothing is live any more (before the loop waits
+for work), when the thread leaves, and behind every fused scan. A block
+family's next forward is built from this forward's answer, so its
+``_step`` drains the FIFO before it returns (fetch, then build).
+
 Every instant of the worker thread belongs to one of six phases of a
 :class:`~pygrid_tpu.telemetry.loopclock.LoopClock` — ``idle`` (nothing
 queued, nothing live), ``admit`` (slot, pages, prompt padding, keys),
-``prefill`` (the prefill program's call until its first token is on the
-host), ``build`` (a decode dispatch's inputs and its enqueue), ``fetch``
-(blocked on the device for the tokens) and ``emit`` (tokens into rows,
-finished requests out). Each is seconds on
+``prefill`` (the prefill program's call, which returns once it is
+enqueued), ``build`` (a decode dispatch's inputs and its enqueue),
+``fetch`` (blocked on the device for the oldest arrival) and ``emit``
+(tokens into rows, finished requests out). Each is seconds on
 ``serving_loop_seconds_total{phase}`` and an ``engine.<phase>``
 annotation in a ``jax.profiler`` trace (docs/OBSERVABILITY.md §4, §6).
 
@@ -73,6 +92,7 @@ shared executor.
 
 from __future__ import annotations
 
+import functools
 import logging
 import threading
 import time
@@ -146,7 +166,7 @@ class _Row:
 
     __slots__ = (
         "pending", "row", "batch", "prompt", "n_new", "temperature",
-        "seed", "keys", "out", "last_token", "enqueued_at", "admitted_at",
+        "seed", "keys", "out", "scheduled", "enqueued_at", "admitted_at",
         "pages", "shared_pages", "start", "demand", "denoising_steps",
         "blk_pos", "blk_tokens", "blk_masked", "blk_step", "blk_forward",
         "reveal", "dropped",
@@ -167,8 +187,11 @@ class _Row:
         #: ENGINE thread at admission (PRNGKey/split are device calls;
         #: they must not run on an enqueueing event-loop thread)
         self.keys = None
+        #: tokens that have ARRIVED on the host, and how many launched
+        #: programs will have made (``scheduled`` >= ``len(out)``): the
+        #: loop plans by the second, a client is answered from the first
         self.out: list[int] = []
-        self.last_token = 0
+        self.scheduled = 0
         self.enqueued_at = time.perf_counter()
         self.admitted_at: float | None = None
         #: paged-KV bookkeeping — the row's block-table pages in page
@@ -231,6 +254,22 @@ class _Pending:
                 if self.extras
                 else self.tokens
             )
+
+
+class _Arrival:
+    """What one launched program will answer the host, and what it takes
+    to hand that out once it is fetched: ``hand(fetched, seconds)``.
+    ``rows`` are the rows that wait for it (``_fail_all`` fails them);
+    ``decode`` tells a step or a scan from a prefill."""
+
+    __slots__ = ("answer", "launched", "rows", "decode", "hand")
+
+    def __init__(self, answer, launched, rows, decode, hand) -> None:
+        self.answer = answer
+        self.launched = launched
+        self.rows = rows
+        self.decode = decode
+        self.hand = hand
 
 
 class GenerationEngine:
@@ -341,6 +380,16 @@ class GenerationEngine:
         # (a recurrent state; nothing for the transformer): it rides
         # every program call, donated like the rest
         self._k, self._v, self._pos, *self._state = cache
+        #: a causal family's last token a slot, on the device beside the
+        #: cache: the programs read and write it (``programs``), the
+        #: host never builds a step's input from tokens it has fetched
+        self._last = self._new_last()
+        #: what launched programs will answer, oldest first, and when
+        #: the newest fetch returned (engine thread only)
+        self._arrivals: deque[_Arrival] = deque()
+        self._fetched_at = 0.0
+        #: rows that have left their slots (engine thread only)
+        self._retired = 0
         #: whether decode attention reads live pages in place (the rule
         #: the decode programs themselves apply when they are traced)
         self._kv_kernel = self._family.kv_kernel(self._k, self._max_pages)
@@ -554,7 +603,7 @@ class GenerationEngine:
                 len(r.pages) for r in live_rows if r.pages is not None
             )
             used_tokens = sum(
-                len(r.prompt) + len(r.out) for r in live_rows
+                len(r.prompt) + r.scheduled for r in live_rows
             )
             out = {
                 "model_id": self.model_id,
@@ -735,12 +784,6 @@ class GenerationEngine:
                 self.model_id, self._k.dtype, tuple(self._k.shape),
             )
 
-        def run(fn, *inputs):
-            self._swap_cache(fn(
-                self.params, self._k, self._v, self._pos, *self._state,
-                self._table(), *inputs,
-            ))
-
         zero_key = jnp.zeros((2,), jnp.uint32)
         seen = set()
         for p_len in prompt_lens or (1,):
@@ -748,7 +791,7 @@ class GenerationEngine:
             if bucket in seen:
                 continue
             seen.add(bucket)
-            run(
+            self._call(
                 self.programs.paged_prefill(bucket), jnp.int32(0),
                 jnp.zeros((bucket,), jnp.int32), jnp.int32(0),
                 jnp.int32(1), jnp.float32(0.0), zero_key,
@@ -757,28 +800,30 @@ class GenerationEngine:
             if self._block_len > 1:
                 # a block family's one program a width; with an all-zero
                 # table every row writes to trash and nothing advances
-                run(
+                self._call(
                     self.programs.paged_block_step(w),
                     jnp.zeros((w, self._block_len), jnp.int32),
                     jnp.zeros((w, self._block_len), bool),
                     jnp.zeros((w,), jnp.int32), jnp.zeros((w,), bool),
+                    last=False,
                 )
                 continue
-            run(
-                self.programs.paged_decode(w), jnp.zeros((w,), jnp.int32),
+            self._call(
+                self.programs.paged_decode(w),
                 jnp.zeros((w,), jnp.float32), jnp.zeros((w, 2), jnp.uint32),
             )
             if self._fused:
                 # zero budgets: every row frozen, nothing advances
-                run(
+                self._call(
                     self.programs.paged_decode_fused(w, self.config.quantum),
-                    jnp.zeros((w,), jnp.int32), jnp.zeros((w,), jnp.int32),
-                    jnp.zeros((w,), jnp.float32),
+                    jnp.zeros((w,), jnp.int32), jnp.zeros((w,), jnp.float32),
                     jnp.zeros((self.config.quantum, w, 2), jnp.uint32),
                 )
 
     def close(self) -> None:
-        """Stop the worker thread; queued/live requests fail typed."""
+        """Stop the worker thread, which first collects what is in
+        flight (a row whose every token is scheduled is answered, not
+        failed); queued/live requests fail typed."""
         with self._work:
             self._running = False
             self._work.notify_all()
@@ -819,30 +864,46 @@ class GenerationEngine:
                     clock.enter("idle")
                     self._work.wait()
                 running = self._running
-            if not running:
-                clock.stop()
-                return
             clock.flush()
             try:
-                self._admit()
-                if self._fused and self._live and not self._queue:
-                    # no admission pending: burn the whole quantum in
-                    # ONE compiled scan — rows finishing mid-scan
-                    # freeze (wasted steps accepted; zero dispatches
-                    # saved per step is the whole point)
-                    self._fused_scan()
+                if running:
+                    self._pass()
                 else:
-                    steps = 0
-                    while steps < self.config.quantum and self._live:
-                        freed = self._step()
-                        steps += 1
-                        if freed and self._queue:
-                            break  # a slot opened and someone's waiting
+                    # what is in flight is answered before the thread
+                    # leaves: close() fails only what is left
+                    self._collect()
             except Exception as err:  # noqa: BLE001 — device-loop boundary
+                # a program that raised at its call, or one whose error
+                # surfaced when its answer was fetched, a dispatch later
                 logger.exception("serving engine step failed")
                 self._fail_all(
                     E.PyGridError(f"generation engine error: {err}")
                 )
+            if not running:
+                clock.stop()
+                return
+
+    def _pass(self) -> None:
+        """One pass of the loop: admissions, then a scan or up to a
+        quantum of steps."""
+        self._admit()
+        if self._fused and self._live and not self._queue:
+            # no admission pending: burn the whole quantum in ONE
+            # compiled scan — rows finishing mid-scan freeze (wasted
+            # steps accepted; zero dispatches saved per step is the
+            # whole point)
+            self._fused_scan()
+        else:
+            steps = 0
+            while steps < self.config.quantum and self._live:
+                freed = self._step()
+                steps += 1
+                if freed and self._queue:
+                    break  # a slot opened and someone's waiting
+        if not self._live:
+            # nothing left to launch behind what is in flight: an answer
+            # never waits for the next request
+            self._collect()
 
     def _admit(self) -> None:
         import jax.numpy as jnp
@@ -884,7 +945,6 @@ class GenerationEngine:
                     row.seed, row.row, row.batch, row.n_new
                 )
             request_id = row.pending.request_id
-            t0 = time.perf_counter()
             # where the prompt's whole blocks end: the prompt itself for
             # a causal family; a block family's prompt tail opens the
             # first generated block instead of being prefilled
@@ -908,40 +968,44 @@ class GenerationEngine:
             # the program's small arguments go to the device under
             # ``admit``: ``prefill`` begins at the program's call
             args = (
-                self._table(), jnp.int32(slot), jnp.asarray(padded),
+                jnp.int32(slot), jnp.asarray(padded),
                 jnp.int32(row.start), jnp.int32(prompt_end),
                 jnp.float32(row.temperature), self._key_for(row, 0),
             )
             fn = self.programs.paged_prefill(bucket)
+            self._table()  # edited above: rebuilt here, under ``admit``
             clock.enter("prefill", request_id=request_id, bucket=bucket)
-            # the cache buffers are single-writer: only the engine
-            # thread swaps _k/_v/_pos between lock epochs
-            tok, *counted = self._swap_cache(fn(
-                self.params, self._k, self._v, self._pos,
-                *self._state, *args,
+            launched = time.perf_counter()
+            answer = self._call(fn, *args)
+            clock.enter("admit")
+            self._arrivals.append(_Arrival(
+                answer, launched, [row], False,
+                functools.partial(self._hand_prefill, slot, row),
             ))
             # publish the full-prompt pages for future prefix hits
             # (first prefill wins; a matched chain is only touched)
             # gridlint: disable-next=GL202 — PrefixCache takes its own lock; only the engine thread mutates it
             self._prefix.insert(row.prompt, row.pages)
-            first = int(tok)
-            clock.enter("emit")
             if self._block_len > 1:
                 # no token comes of a block family's prefill: the row's
                 # first block opens where the prompt's whole blocks end
-                telemetry.observe(
-                    "serving_prefill_seconds", time.perf_counter() - t0
-                )
-                self._note_expert_bytes("prefill", counted[0])
                 self._open_block(row, prompt_end)
-                continue
-            telemetry.observe(
-                "serving_ttft_seconds", time.perf_counter() - row.enqueued_at
-            )
-            telemetry.observe(
-                "serving_prefill_seconds", time.perf_counter() - t0
-            )
-            self._emit(slot, row, first)
+            else:
+                # the first token is on its way (a row that asked for
+                # one leaves its slot to the next in the queue here)
+                self._schedule(slot, row, 1)
+
+    def _hand_prefill(self, slot: int, row: _Row, fetched, seconds) -> None:
+        """A prefill's answer, fetched: its seconds, and a causal
+        family's first token into the row."""
+        telemetry.observe("serving_prefill_seconds", seconds)
+        if self._block_len > 1:
+            self._note_expert_bytes("prefill", fetched[1])
+            return
+        telemetry.observe(
+            "serving_ttft_seconds", time.perf_counter() - row.enqueued_at
+        )
+        self._emit(slot, row, int(fetched[0]))
 
     def _assign_pages(self, slot: int, row: _Row) -> bool:
         """Map ``row`` into the block pool: match the longest cached
@@ -1000,7 +1064,10 @@ class GenerationEngine:
         if self._table_dirty or self._table_dev is None:
             import jax.numpy as jnp
 
-            self._table_dev = jnp.asarray(self._table_np)
+            # a copy the host never writes again: the transfer may read
+            # its source after this returns, and a program in flight
+            # reads its table while the mirror is edited for the next
+            self._table_dev = jnp.asarray(self._table_np.copy())
             self._table_dirty = False
         return self._table_dev
 
@@ -1020,63 +1087,119 @@ class GenerationEngine:
 
     def _step(self) -> bool:
         """One batched forward over every live slot (a decode step; a
-        block family's block step); returns True if any slot freed (a
-        finished request left the batch)."""
+        block family's block step), launched; then the arrivals before
+        it, collected. Returns True if any slot freed (a row's last
+        token was scheduled and it left the batch)."""
         clock = self._clock
         clock.enter("build")
         live, width = self._live_snapshot()
         if not live:
             return False
         clock.annotate(path="step", width=width, live=len(live), steps=1)
-        build = self._block_inputs if self._block_len > 1 else self._token_inputs
+        causal = self._block_len == 1
+        build = self._token_inputs if causal else self._block_inputs
         fn, inputs, take = build(width, live)
-        t0 = time.perf_counter()
+        retired = self._retired
+        launched = time.perf_counter()
         # the ONE decode dispatch site of the per-step path, whatever
         # the family
-        head = self._swap_cache(fn(
-            self.params, self._k, self._v, self._pos, *self._state,
-            self._table(), *inputs,
+        answer = self._call(fn, *inputs, last=causal)
+        self._note_dispatch("step", width, live, 1)
+        self._arrivals.append(_Arrival(
+            answer, launched, [row for _, row in live], True,
+            functools.partial(self._hand_step, width, live, take),
         ))
-        clock.enter("fetch")
-        head = [np.asarray(x) for x in head]
-        dt = time.perf_counter() - t0
-        clock.enter("emit")
-        self._note_dispatch("step", width, live, 1, dt)
-        telemetry.observe(
-            "serving_batch_occupancy", float(len(live)),
-            bounds=_OCCUPANCY_BOUNDS,
-        )
-        freed = False
-        for (i, row), tokens in zip(live, take(live, *head)):
-            telemetry.observe("serving_token_seconds", dt)
-            if self._emit(i, row, tokens):
-                freed = True
-        return freed
+        if causal:
+            for i, row in live:
+                self._schedule(i, row, 1)
+        # a causal family leaves this step in flight and hands out what
+        # came before it while the device runs it; a block family's next
+        # forward is built from this one's answer
+        self._collect(keep=1 if causal else 0)
+        return self._retired > retired
 
-    def _swap_cache(self, result: tuple) -> tuple:
-        """Take a program's returned cache buffers (its last outputs, in
-        the cache's own order) in place of the donated ones; returns what
-        the program answered before them. Engine thread only."""
+    def _hand_step(self, width: int, live, take, fetched, seconds) -> None:
+        """A step's answer, fetched: its seconds on the bus and what it
+        yielded each row that was live in it."""
+        telemetry.observe(
+            "serving_dispatch_seconds", seconds, path="step",
+            width=str(width),
+        )
+        for (i, row), tokens in zip(live, take(live, *fetched)):
+            telemetry.observe("serving_token_seconds", seconds)
+            self._emit(i, row, tokens)
+
+    def _collect(self, keep: int = 0) -> None:
+        """Fetch the oldest arrivals, until ``keep`` are left in flight,
+        and hand each out. The device runs its programs in order, so a
+        fetch waits for its own program and every one before it. An
+        arrival's seconds run from the later of its launch and the fetch
+        before it to its own fetch: the program's device time while the
+        device is never idle, and over any stretch the seconds of all
+        arrivals sum to at most the stretch, however many programs were
+        queued at once."""
+        clock = self._clock
+        while len(self._arrivals) > keep:
+            arrival = self._arrivals[0]
+            clock.enter("fetch")
+            fetched = [np.asarray(x) for x in arrival.answer]
+            now = time.perf_counter()
+            seconds = now - max(arrival.launched, self._fetched_at)
+            self._fetched_at = now
+            clock.enter("emit")
+            # off the FIFO only once fetched: a fetch that raises leaves
+            # the arrival's rows where _fail_all finds them
+            self._arrivals.popleft()
+            arrival.hand(fetched, seconds)
+
+    def _call(self, fn, *inputs, last: bool = True) -> tuple:
+        """Launch one program on the engine's cache: the donated buffers
+        in (``last`` after them, for every program but a block step),
+        the returned ones in their place. Returns what the program
+        answers before them, not fetched. Engine thread only — the cache
+        buffers are single-writer."""
+        carried = (self._last,) if last else ()
+        result = fn(
+            self.params, self._k, self._v, self._pos, *self._state,
+            *carried, self._table(), *inputs,
+        )
+        if last:
+            *result, self._last = result
         n = 3 + len(self._state)
         self._k, self._v, self._pos, *self._state = result[-n:]
-        return result[:-n]
+        return tuple(result[:-n])
+
+    def _schedule(self, slot: int, row: _Row, count: int) -> None:
+        """``count`` more of the row's tokens are on their way (launched
+        programs make them; a block row's count is known once its
+        forward has answered). With its last the row is retired: its
+        slot freed, its pages released and its table row zeroed, for
+        whatever is launched next."""
+        row.scheduled += count
+        if row.scheduled < row.n_new:
+            return
+        with self._lock:
+            self._slots[slot] = None
+            self._live = max(0, self._live - 1)
+        self._release_row(slot, row)
+        self._retired += 1
 
     def _token_inputs(self, width: int, live: list[tuple[int, "_Row"]]):
         """A decode step's program, its inputs after the table, and how
-        its answer reads: one token a live row."""
+        its answer reads: one token a live row. The step's tokens are
+        the device's (``last``); a row's key is the one of the token
+        being scheduled."""
         import jax.numpy as jnp
 
-        tokens = np.zeros(width, np.int32)
         temps = np.zeros(width, np.float32)
         keys = np.zeros((width, 2), np.uint32)
         for i, row in live:
-            tokens[i] = row.last_token
             temps[i] = row.temperature
             if row.keys is not None:
-                keys[i] = row.keys[len(row.out)]
+                keys[i] = row.keys[row.scheduled]
         return (
             self.programs.paged_decode(width),
-            (jnp.asarray(tokens), jnp.asarray(temps), jnp.asarray(keys)),
+            (jnp.asarray(temps), jnp.asarray(keys)),
             lambda live, toks: [int(toks[i]) for i, _ in live],
         )
 
@@ -1108,10 +1231,14 @@ class GenerationEngine:
 
         def take(live, toks, chosen, expert_bytes):
             self._note_expert_bytes("step", expert_bytes)
-            return [
-                self._close_forward(row, toks[i], chosen[i], advance[i])
-                for i, row in live
-            ]
+            yielded = []
+            for i, row in live:
+                tokens = self._close_forward(
+                    row, toks[i], chosen[i], advance[i]
+                )
+                self._schedule(i, row, len(tokens))
+                yielded.append(tokens)
+            return yielded
 
         return (
             self.programs.paged_block_step(width),
@@ -1177,7 +1304,9 @@ class GenerationEngine:
         writes trash-route, their position parks), the emitted
         [steps, w] matrix drains into pendings afterwards. Host cost
         per quantum: one dispatch + one device→host token fetch,
-        instead of ``quantum`` of each. Engine thread only."""
+        instead of ``quantum`` of each. A scan is collected as soon as
+        it is launched, behind whatever was in flight before it (an
+        admission's first token, a step's). Engine thread only."""
         import jax.numpy as jnp
 
         clock = self._clock
@@ -1189,45 +1318,30 @@ class GenerationEngine:
         clock.annotate(
             path="fused", width=width, live=len(live), steps=steps
         )
-        tokens = np.zeros(width, np.int32)
         temps = np.zeros(width, np.float32)
         budget = np.zeros(width, np.int32)
         keys = np.zeros((steps, width, 2), np.uint32)
+        counts = []
         for i, row in live:
-            tokens[i] = row.last_token
             temps[i] = row.temperature
-            need = row.n_new - len(row.out)
-            budget[i] = need
+            budget[i] = row.n_new - row.scheduled
+            take = min(steps, int(budget[i]))
+            counts.append(take)
             if row.keys is not None:
-                done = len(row.out)
-                take = min(steps, need)
-                keys[:take, i] = row.keys[done : done + take]
-        t0 = time.perf_counter()
-        fn = self.programs.paged_decode_fused(width, steps)
-        # gridlint: disable-next=GL202 — cache buffers are engine-thread-confined
-        toks, self._k, self._v, self._pos, *self._state = fn(
-            self.params, self._k, self._v, self._pos, *self._state,
-            self._table(),
-            jnp.asarray(tokens), jnp.asarray(budget), jnp.asarray(temps),
-            jnp.asarray(keys),
+                keys[:take, i] = row.keys[row.scheduled : row.scheduled + take]
+        launched = time.perf_counter()
+        answer = self._call(
+            self.programs.paged_decode_fused(width, steps),
+            jnp.asarray(budget), jnp.asarray(temps), jnp.asarray(keys),
         )
-        clock.enter("fetch")
-        toks = np.asarray(toks)  # [steps, width]
-        dt = time.perf_counter() - t0
-        clock.enter("emit")
-        self._note_dispatch("fused", width, live, steps, dt)
-        telemetry.observe(
-            "serving_batch_occupancy", float(len(live)),
-            bounds=_OCCUPANCY_BOUNDS,
-        )
-        drained = 0
-        for i, row in live:
-            need = min(steps, row.n_new - len(row.out))
-            drained += need
-            for j in range(need):
-                telemetry.observe("serving_token_seconds", dt / steps)
-                self._emit(i, row, int(toks[j, i]))
-        wasted = steps * len(live) - drained
+        self._note_dispatch("fused", width, live, steps)
+        self._arrivals.append(_Arrival(
+            answer, launched, [row for _, row in live], True,
+            functools.partial(self._hand_scan, width, steps, live, counts),
+        ))
+        for (i, row), count in zip(live, counts):
+            self._schedule(i, row, count)
+        wasted = steps * len(live) - sum(counts)
         with self._lock:
             self._fused_scans += 1
             self._fused_steps += steps
@@ -1241,6 +1355,23 @@ class GenerationEngine:
                 "serving_fused_wasted_steps_total", wasted,
                 model=self.model_id,
             )
+        self._collect()
+
+    def _hand_scan(
+        self, width: int, steps: int, live, counts, fetched, seconds
+    ) -> None:
+        """A scan's answer, fetched: its seconds on the bus and each
+        row's first ``count`` tokens of the [steps, w] matrix (a frozen
+        row's tail is its last token repeated)."""
+        (toks,) = fetched
+        telemetry.observe(
+            "serving_dispatch_seconds", seconds, path="fused",
+            width=str(width),
+        )
+        for (i, row), count in zip(live, counts):
+            for j in range(count):
+                telemetry.observe("serving_token_seconds", seconds / steps)
+                self._emit(i, row, int(toks[j, i]))
 
     def _note_dispatch(
         self,
@@ -1248,16 +1379,22 @@ class GenerationEngine:
         width: int,
         live: list[tuple[int, "_Row"]],
         steps: int,
-        dt: float,
     ) -> None:
-        """One decode dispatch on the bus, BEFORE its tokens are emitted
-        (the rows still hold the lengths the program ran at): its
-        seconds from the program's call to the tokens fetched, under its
-        path and width bucket; the row-steps it computed against those
-        that belonged to an occupied slot; and the KV pages its
-        attention read against the pages its block tables span."""
+        """One decode dispatch on the bus as it is launched, BEFORE its
+        tokens are scheduled (the rows still hold the lengths the
+        program runs at): whether it went out ahead of an earlier decode
+        dispatch whose tokens the host had not fetched; the rows it
+        carries; the row-steps it computes against those that belong to
+        an occupied slot; and the KV pages its attention reads against
+        the pages its block tables span. Its seconds follow when its
+        answer is fetched (``_collect``)."""
+        ahead = any(arrival.decode for arrival in self._arrivals)
+        telemetry.incr(
+            "serving_dispatches_total", ahead="yes" if ahead else "no"
+        )
         telemetry.observe(
-            "serving_dispatch_seconds", dt, path=path, width=str(width)
+            "serving_batch_occupancy", float(len(live)),
+            bounds=_OCCUPANCY_BOUNDS,
         )
         telemetry.incr_many(
             "serving_dispatch_rowsteps_total", "kind",
@@ -1289,30 +1426,30 @@ class GenerationEngine:
         self, width: int, live: list[tuple[int, "_Row"]], steps: int
     ) -> int:
         """Pages ``paged_attention``'s kernel reads over one dispatch of
-        ``steps`` steps, from the host's own row state: a live row's
-        pages up to its length at each step (parked once the row has its
-        tokens, as its position is), one trash page for each free slot
-        inside the width."""
+        ``steps`` steps, from the host's own counts: a live row's pages
+        up to its length at each step (what is scheduled for it so far;
+        parked once the row has its tokens, as its position is), one
+        trash page for each free slot inside the width."""
         if self._block_len > 1:
             # a block step attends over the row's block and all before
             ends = np.array([r.blk_pos + self._block_len for _, r in live])
             return int((-(-ends // self._block)).sum()) + width - len(live)
-        base = np.array([len(r.prompt) + len(r.out) for _, r in live])
-        need = np.array([r.n_new - len(r.out) for _, r in live])
+        base = np.array([len(r.prompt) + r.scheduled for _, r in live])
+        need = np.array([r.n_new - r.scheduled for _, r in live])
         lengths = base[:, None] + np.minimum(np.arange(steps), need[:, None])
         rows = self._max_pages * self._block
         pages = -(-np.minimum(lengths, rows) // self._block)
         return int(pages.sum()) + (width - len(live)) * steps
 
-    def _emit(self, slot: int, row: _Row, token) -> bool:
-        """Append what one forward yielded a row: a causal family's one
-        token (an int), or the tuple of nought to ``BLOCK_LEN`` tokens a
-        block family's forward made final; retire the row (freeing its
-        slot) when it has its n_new tokens. Returns True if freed."""
+    def _emit(self, slot: int, row: _Row, token) -> None:
+        """What one forward yielded a row, arrived on the host: a causal
+        family's one token (an int), or the tuple of nought to
+        ``BLOCK_LEN`` tokens a block family's forward made final. With
+        its last token the row is answered (it left ``slot`` when that
+        token was scheduled: ``_schedule``)."""
         tokens = token if isinstance(token, tuple) else (token,)
         if tokens:
             row.out.extend(tokens)
-            row.last_token = tokens[-1]
             with self._lock:
                 # stats() reads this counter under the lock from other
                 # threads — the engine thread must not += it lock-free
@@ -1321,11 +1458,7 @@ class GenerationEngine:
                 "serving_tokens_total", len(tokens), model=self.model_id
             )
         if len(row.out) < row.n_new:
-            return False
-        with self._lock:
-            self._slots[slot] = None
-            self._live = max(0, self._live - 1)
-        self._release_row(slot, row)
+            return
         # a block family names, beside its tokens, the forward that
         # revealed each and what its last block made past n_new
         extras = {
@@ -1339,7 +1472,6 @@ class GenerationEngine:
                 "serving_requests_total", outcome="ok",
                 model=self.model_id,
             )
-        return True
 
     def _release_row(self, slot: int, row: _Row) -> None:
         """Return a retired row's pages to the pool (shared pages just
@@ -1381,9 +1513,15 @@ class GenerationEngine:
                 self.cfg, self.config.max_slots, self._num_blocks,
                 self._block, dtype=self._kv_dtype,
             )
+            self._last = self._new_last()
+        # rows that left their slots with tokens still in flight wait
+        # in the FIFO alone: they fail with the rest
+        in_flight = [row for arrival in self._arrivals for row in arrival.rows]
+        self._arrivals.clear()
         with self._lock:
             rows = [r for r in self._slots if r is not None]
             rows.extend(self._queue)
+            rows.extend(in_flight)
             self._queue.clear()
             self._slots = [None] * self.config.max_slots
             self._live = 0
@@ -1449,6 +1587,11 @@ class GenerationEngine:
                 logger.exception("flight-recorder capture failed")
 
     # ── helpers ─────────────────────────────────────────────────────────
+
+    def _new_last(self):
+        import jax.numpy as jnp
+
+        return jnp.zeros((self.config.max_slots,), jnp.int32)
 
     def _span(self, p_len: int, n_new: int) -> int:
         """Positions a row's pages must cover: prompt and new tokens, up

@@ -22,6 +22,15 @@ flat while request shapes vary within buckets.
 Cache buffers are donated (``donate_argnums``): the engine owns the only
 reference, so XLA may update the multi-megabyte k/v arrays in place
 instead of copying them every step.
+
+A causal family's LAST TOKENS live on the device: one int32 vector over
+the slots (``last``) rides after the cache arrays, donated like them. A
+prefill writes the admitted slot's first token into it, a decode step
+reads its first ``w`` entries as its input and writes the picked tokens
+back, a fused scan starts from it and leaves its carry there. So a
+step's input never waits for the host to have fetched the step before
+it; what a program answers the host (``tok``, ``toks``, ``emitted``) is
+an array of its own, which the next program does not consume.
 """
 
 from __future__ import annotations
@@ -77,6 +86,8 @@ class ProgramSet:
         self._cache_of = self._family.PagedCache._make
         self._cache_arrays = len(self._family.PagedCache._fields)
         self._donated = tuple(range(1, 1 + self._cache_arrays))
+        #: the programs that carry ``last`` donate it too
+        self._donated_last = (*self._donated, 1 + self._cache_arrays)
         self._paged_prefill: dict[int, Callable] = {}
         self._paged_decode: dict[int, Callable] = {}
         self._paged_fused: dict[tuple[int, int], Callable] = {}
@@ -151,15 +162,16 @@ class ProgramSet:
     # one program.
 
     def paged_prefill(self, bucket: int) -> Callable:
-        """``fn(params, k, v, pos, table, slot, chunk[bucket], start,
-        length, temp, key) -> (first_token, k, v, pos)`` — admission of
-        one request through its block table, continuing after a shared
-        prefix of ``start`` tokens; first token picked on-device. A
-        family whose cache has more arrays than ``k, v, pos`` (a
-        recurrent state) takes and returns them after ``pos``, donated
-        like the rest: so for every paged program below. What a family's
-        prefill answers after its cache (a block family's expert bytes)
-        comes back between the token and the cache."""
+        """``fn(params, k, v, pos, last, table, slot, chunk[bucket],
+        start, length, temp, key) -> (first_token, k, v, pos, last)`` —
+        admission of one request through its block table, continuing
+        after a shared prefix of ``start`` tokens; first token picked
+        on-device and left in ``last[slot]``. A family whose cache has
+        more arrays than ``k, v, pos`` (a recurrent state) takes and
+        returns them after ``pos``, donated like the rest: so for every
+        paged program below. What a family's prefill answers after its
+        cache (a block family's expert bytes) comes back between the
+        token and the cache."""
         fn = self._paged_prefill.get(bucket)
         if fn is None:
             import jax
@@ -168,16 +180,16 @@ class ProgramSet:
             cache_of, n = self._cache_of, self._cache_arrays
 
             def _paged_prefill(params, *args):
-                table, slot, chunk, start, length, temp, key = args[n:]
+                last, table, slot, chunk, start, length, temp, key = args[n:]
                 logits, cache, *counted = model.paged_prefill_chunk(
                     params, cache_of(args[:n]), table, slot, chunk, start,
                     length, cfg, cd,
                 )
                 tok = self._pick(logits, temp, key)
-                return (tok, *counted, *cache)
+                return (tok, *counted, *cache, last.at[slot].set(tok))
 
             fn = telemetry.profiler.wrap(
-                jax.jit(_paged_prefill, donate_argnums=self._donated),
+                jax.jit(_paged_prefill, donate_argnums=self._donated_last),
                 kind="paged_prefill", bucket=bucket,
                 model_id=self.model_id,
             )
@@ -186,11 +198,12 @@ class ProgramSet:
         return fn
 
     def paged_decode_fused(self, width: int, steps: int) -> Callable:
-        """``fn(params, k, v, pos, table, tokens[w], budget[w],
-        temps[w], keys[steps, w, 2]) -> (emitted[steps, w], k, v, pos)``
-        — up to ``steps`` block-table decode steps in ONE compiled
+        """``fn(params, k, v, pos, last, table, budget[w], temps[w],
+        keys[steps, w, 2]) -> (emitted[steps, w], k, v, pos, last)`` —
+        up to ``steps`` block-table decode steps in ONE compiled
         program (``lax.scan``), killing the per-step host→device
-        dispatch that dominates small-model decode.
+        dispatch that dominates small-model decode. The scan starts from
+        ``last[:w]`` and leaves its carry there.
 
         ``budget[i]`` is how many tokens row ``i`` still needs: the scan
         decrements it per step and FREEZES the row at zero (k/v write to
@@ -213,7 +226,8 @@ class ProgramSet:
             cache_of, n = self._cache_of, self._cache_arrays
 
             def _fused(params, *args):
-                table, tokens, budget, temps, keys = args[n:]
+                last, table, budget, temps, keys = args[n:]
+                width = budget.shape[0]
 
                 def body(carry, step_keys):
                     *arrays, tok, remaining = carry
@@ -229,13 +243,13 @@ class ProgramSet:
                     )
                     return carry, nxt
 
-                (*arrays, _, _), emitted = lax.scan(
-                    body, (*args[:n], tokens, budget), keys
+                (*arrays, tok, _), emitted = lax.scan(
+                    body, (*args[:n], last[:width], budget), keys
                 )
-                return (emitted, *arrays)
+                return (emitted, *arrays, last.at[:width].set(tok))
 
             fn = telemetry.profiler.wrap(
-                jax.jit(_fused, donate_argnums=self._donated),
+                jax.jit(_fused, donate_argnums=self._donated_last),
                 kind="paged_decode_fused", bucket=width,
                 model_id=self.model_id,
             )
@@ -244,9 +258,10 @@ class ProgramSet:
         return fn
 
     def paged_decode(self, width: int) -> Callable:
-        """``fn(params, k, v, pos, table, tokens[w], temps[w],
-        keys[w, 2]) -> (next_tokens[w], k, v, pos)`` — one block-table
-        step for the first ``w`` slots, each at its own position."""
+        """``fn(params, k, v, pos, last, table, temps[w], keys[w, 2]) ->
+        (next_tokens[w], k, v, pos, last)`` — one block-table step for
+        the first ``w`` slots, each at its own position, from the token
+        ``last`` holds for it to the one it leaves there."""
         fn = self._paged_decode.get(width)
         if fn is None:
             import jax
@@ -255,15 +270,18 @@ class ProgramSet:
             cache_of, n = self._cache_of, self._cache_arrays
 
             def _paged_decode_step(params, *args):
-                table, tokens, temps, keys = args[n:]
+                last, table, temps, keys = args[n:]
+                width = temps.shape[0]
                 logits, cache = model.paged_decode_step(
-                    params, cache_of(args[:n]), table, tokens, cfg, cd
+                    params, cache_of(args[:n]), table, last[:width], cfg, cd
                 )
                 toks = jax.vmap(self._pick)(logits, temps, keys)
-                return (toks, *cache)
+                return (toks, *cache, last.at[:width].set(toks))
 
             fn = telemetry.profiler.wrap(
-                jax.jit(_paged_decode_step, donate_argnums=self._donated),
+                jax.jit(
+                    _paged_decode_step, donate_argnums=self._donated_last
+                ),
                 kind="paged_decode", bucket=width,
                 model_id=self.model_id,
             )

@@ -117,7 +117,10 @@ _FAMILY_HELP: dict[str, str] = {
     "serving_compiles_total": "serving program compiles, by kind",
     "serving_ttft_seconds": "generation time-to-first-token (enqueue→token)",
     "serving_token_seconds": "per-token decode latency inside the batch",
-    "serving_prefill_seconds": "per-request slot prefill (admission) time",
+    "serving_prefill_seconds": (
+        "one prefill, from the later of its launch and the fetch before "
+        "it to its first token fetched"
+    ),
     "serving_queue_wait_seconds": "generation queue wait before a slot",
     "serving_batch_occupancy": "live slots per decode step",
     # the engine thread's loop clock (telemetry/loopclock.py): every
@@ -127,8 +130,13 @@ _FAMILY_HELP: dict[str, str] = {
         "fetch, emit) — the phases partition the thread's time"
     ),
     "serving_dispatch_seconds": (
-        "one decode dispatch (step or fused scan), from the "
-        "program's call to its tokens fetched, by path and width bucket"
+        "one decode dispatch (step or fused scan), from the later of its "
+        "launch and the fetch before it to its tokens fetched, by path "
+        "and width bucket"
+    ),
+    "serving_dispatches_total": (
+        "decode dispatches launched: ahead=yes while an earlier decode "
+        "dispatch's tokens had not been fetched, ahead=no otherwise"
     ),
     "serving_dispatch_rowsteps_total": (
         "decode row-steps per dispatch: kind=live (occupied rows × steps) "

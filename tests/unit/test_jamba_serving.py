@@ -289,6 +289,45 @@ def test_engine_serves_the_references_tokens_staggered_and_reused(
         eng.close()
 
 
+def test_dispatch_ahead_keeps_the_state_and_the_keys_in_step(
+    model, cfg, jcfg, params, weights
+):
+    """Seven requests queued on two slots before the loop starts, ``n_new``
+    of 1 and 2 among them: steps are launched ahead of the tokens read, a
+    slot's next prefill (which writes the slot's whole state) goes out
+    behind the step that still advances its last row. Greedy, each served
+    token is the reference's best to ``TOL``; sampled, each answer is the
+    one the same seed gets alone (the key follows the scheduled count)."""
+    shapes = [(5, 7), (11, 1), (19, 2), (8, 5), (13, 1), (30, 9), (3, 2)]
+    prompts = [_tokens(50 + i, p) for i, (p, _) in enumerate(shapes)]
+    telemetry.reset()
+    eng = _engine(jcfg, params, max_slots=2, slot_buckets=(1, 2), fused=False)
+    try:
+        alone = [
+            eng.submit(p[None], n, temperature=0.7, seed=9 + i, timeout=300)[0]
+            for i, (p, (_, n)) in enumerate(zip(prompts, shapes))
+        ]
+        assert _count("serving_dispatches_total", ahead="yes") > 0
+        for temperature in (0.0, 0.7):
+            ahead = _count("serving_dispatches_total", ahead="yes")
+            futures = [
+                eng.enqueue(p[None], n, temperature=temperature, seed=9 + i)
+                for i, (p, (_, n)) in enumerate(zip(prompts, shapes))
+            ]
+            served = [f.result(300)[0] for f in futures]
+            for prompt, toks, want, (_, n) in zip(prompts, served, alone, shapes):
+                assert toks.shape == (n,)
+                if temperature:
+                    np.testing.assert_array_equal(toks, want)
+                else:
+                    assert _gaps(model, weights, cfg, prompt, toks).max() <= TOL
+            assert _count("serving_dispatches_total", ahead="yes") > ahead
+        assert not eng._arrivals
+        assert eng.ledger()["balanced"] and eng.ledger()["drained"]
+    finally:
+        eng.close()
+
+
 def test_a_zeroed_state_between_dispatches_is_served_wrong(
     model, cfg, jcfg, params, weights
 ):

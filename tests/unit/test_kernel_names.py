@@ -148,8 +148,8 @@ def test_decode_step_at_the_cells_width_holds_nothing_pool_sized(
     while not hasattr(step, "lower"):  # the profiler's wrapper
         step = step.__wrapped__
     text = step.lower(
-        params, pool, pool, arg((16,), jnp.int32),
-        arg((16, MAX_PAGES), jnp.int32), arg((w,), jnp.int32),
+        params, pool, pool, arg((16,), jnp.int32), arg((16,), jnp.int32),
+        arg((16, MAX_PAGES), jnp.int32),
         arg((w,), jnp.float32), arg((w, 2), jnp.uint32),
     ).compile().as_text()
     assert text.count("tpu_custom_call") == cfg.n_layers

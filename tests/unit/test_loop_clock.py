@@ -168,6 +168,49 @@ def test_phases_partition_the_engine_threads_time(params, fused):
         assert not _dispatches(path="step")
 
 
+def _hist_sum(name):
+    return sum(
+        snap["sum"] for (n, _), snap in telemetry.histograms().items()
+        if n == name
+    )
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["step", "fused"])
+def test_no_second_is_counted_twice_with_programs_queued(params, fused):
+    """Nine requests on two slots, queued before the loop starts: steps
+    are launched ahead of the tokens read and prefills behind steps in
+    flight, so two or three programs are queued at once. A dispatch's
+    (and a prefill's) seconds run from the later of its launch and the
+    fetch before it to its own fetch: together they fit into the
+    thread's wall time, which the six phases still partition."""
+    eng = _engine(params, max_slots=2, slot_buckets=(1, 2), fused=fused)
+    go, wall = _gate(eng)
+    try:
+        futures = [
+            eng.enqueue(PROMPT[None, : 1 + i % 5], n)
+            for i, n in enumerate((6, 1, 2, 12, 3, 1, 9, 2, 5))
+        ]
+        go.set()
+        for f in futures:
+            f.result(120)
+    finally:
+        eng.close()
+    assert _counter("serving_dispatches_total", ahead="yes") > 0
+    assert _counter("serving_admitted_total") == 9
+    spans = _hist_sum("serving_dispatch_seconds") + _hist_sum(
+        "serving_prefill_seconds"
+    )
+    assert 0 < spans <= wall[0]
+    seconds = {p: _counter("serving_loop_seconds_total", phase=p) for p in PHASES}
+    assert sum(seconds.values()) == pytest.approx(wall[0], rel=0.02)
+    # every launched program was fetched: one observation each
+    counts = sum(
+        snap["count"] for (n, _), snap in telemetry.histograms().items()
+        if n in ("serving_dispatch_seconds", "serving_prefill_seconds")
+    )
+    assert counts == 9 + _counter("serving_dispatches_total")
+
+
 @pytest.mark.parametrize(
     "fused, n_long, dispatches, live, computed",
     [
@@ -324,5 +367,6 @@ def test_profile_carries_the_phases_with_their_arguments(params, tmp_path):
         int(b["steps"]) == 1 and 1 <= int(b["live"]) <= int(b["width"]) <= 4
         for b in builds
     )
+    # every launched program's answer is fetched once, a dispatch late
     fetches = [ev for ev in events if ev.name == "engine.fetch"]
-    assert len(fetches) == len(builds)
+    assert len(fetches) == len(builds) + len(prefills)

@@ -227,8 +227,8 @@ def test_fused_program_holds_the_kernel_only_when_eligible(
         cache = _filled_cache(4, [3, 8, 20, 5])
         return (
             params, cache.k, cache.v, cache.pos,
+            jnp.asarray([1, 2, 3, 4], jnp.int32),  # the slots' last tokens
             jnp.asarray(_private_table(w)),
-            jnp.asarray([1, 2, 3, 4], jnp.int32),
             jnp.asarray([2, 1, 2, 0], jnp.int32), jnp.zeros(w, jnp.float32),
             jnp.zeros((steps, w, 2), jnp.uint32),
         )
@@ -241,9 +241,10 @@ def test_fused_program_holds_the_kernel_only_when_eligible(
     text = str(jax.make_jaxpr(fused())(*args()))
     assert "pallas_call" in text and "paged_decode_attention" in text
     # and it runs (interpreted here): the same tokens as the gather's
-    emitted, k, _v, pos = fused()(*args())
+    emitted, k, _v, pos, last = fused()(*args())
     monkeypatch.setattr(paged_attention, "eligible", lambda *a: False)
-    want, ref_k, _rv, ref_pos = fused()(*args())
+    want, ref_k, _rv, ref_pos, ref_last = fused()(*args())
+    np.testing.assert_array_equal(last, ref_last)
     np.testing.assert_array_equal(emitted, want)
     np.testing.assert_array_equal(pos, ref_pos)
     np.testing.assert_allclose(k, ref_k, rtol=1e-4, atol=1e-4)

@@ -299,6 +299,11 @@ def served(model, cfg, scfg, params, weights):
             "tokens": _count("serving_tokens_total"),
             "fused": _count("serving_fused_scans_total"),
             **{
+                f"ahead_{answer}": _count("serving_dispatches_total", ahead=answer)
+                for answer in ("yes", "no")
+            },
+            "arrivals_left": len(eng._arrivals),
+            **{
                 kind: _count("serving_block_forwards_total", kind=kind)
                 for kind in ("denoise", "commit")
             },
@@ -363,6 +368,17 @@ def test_counters_and_the_ledger_after_the_cases(served):
     per_expert = 3 * 64 * 32 * 4
     for path in ("step", "prefill"):
         assert bus[path] > 0 and bus[path] % per_expert == 0
+
+
+def test_a_block_family_dispatches_nothing_ahead(served):
+    """The next forward's tokens and mask come from this forward's answer
+    through the host, so a block family fetches, then builds: with seven
+    requests on four slots every forward went out with no earlier decode
+    dispatch unread, and nothing was left in flight."""
+    _, _, stats, _ = served
+    bus = stats["bus"]
+    assert bus["ahead_yes"] == 0 and bus["ahead_no"] > 0
+    assert bus["arrivals_left"] == 0
 
 
 def test_typed_errors_at_the_engine(scfg, params):

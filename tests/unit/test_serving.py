@@ -224,6 +224,34 @@ def test_manager_rebuilds_engine_on_rehost():
         mgr.close()
 
 
+def _ahead(answer: str) -> float:
+    from pygrid_tpu import telemetry
+
+    return sum(
+        v for (name, labels), v in telemetry.counters().items()
+        if name == "serving_dispatches_total" and ("ahead", answer) in labels
+    )
+
+
+def _gated(eng):
+    """Hold the worker thread at its door: everything enqueued before
+    ``go`` is queued when the loop starts, so the backlog is the same in
+    every run."""
+    go = threading.Event()
+    loop = eng._loop
+    eng._loop = lambda: (go.wait(30), loop())
+    return go
+
+
+class _Poisoned:
+    """A program's answer whose error surfaces when the host fetches it:
+    what a device fault looks like to a loop that reads a dispatch late."""
+
+    def __array__(self, *args, **kwargs):
+        raise RuntimeError("injected device failure")
+
+
+@pytest.mark.parametrize("when", ["call", "fetch"])
 @pytest.mark.parametrize(
     "program, fused",
     [
@@ -232,12 +260,16 @@ def test_manager_rebuilds_engine_on_rehost():
         ("paged_decode_fused", True),
     ],
 )
-def test_engine_recovers_after_device_loop_failure(params, program, fused):
+def test_engine_recovers_after_device_loop_failure(params, program, fused, when):
     """Whichever of the engine's three programs fails: it RUNS (so the
-    donated cache buffers are consumed) and then raises, once. The
-    in-flight request fails typed, the engine holds a fresh zeroed cache
-    instead of the deleted arrays, the next request equals
-    ``generate()`` and the block ledger closes."""
+    donated cache buffers are consumed) and then raises at its call, or
+    hands back an answer that raises when it is fetched, a dispatch
+    later. Two requests are queued before the loop starts, of one token
+    and of two, so a row has left its slot with its token still in flight
+    when the failure lands: every pending that was not answered fails
+    typed (none hangs), an answer that was delivered is right, the engine
+    holds a fresh zeroed cache instead of the deleted arrays, the next
+    request equals ``generate()`` and the block ledger closes."""
     import jax.numpy as jnp
 
     eng = GenerationEngine(
@@ -247,7 +279,7 @@ def test_engine_recovers_after_device_loop_failure(params, program, fused):
             max_slots=1, slot_buckets=(1,), min_prompt_bucket=8,
             block_size=8, fused=fused,
         ),
-        model_id=f"boom-{program}",
+        model_id=f"boom-{program}-{when}",
     )
     try:
         builder = getattr(eng.programs, program)
@@ -256,19 +288,37 @@ def test_engine_recovers_after_device_loop_failure(params, program, fused):
         def failing(*key):
             fn = builder(*key)
 
-            def run_then_raise(*args):
-                fn(*args)
+            def run_then_fail(*args):
+                result = fn(*args)
                 calls.append(key)
-                raise RuntimeError("injected device failure")
+                if len(calls) > 1:  # it fails once
+                    return result
+                if when == "call":
+                    raise RuntimeError("injected device failure")
+                return (_Poisoned(), *result[1:])
 
-            return run_then_raise
+            return run_then_fail
 
         setattr(eng.programs, program, failing)
-        with pytest.raises(E.PyGridError, match="engine error"):
-            eng.submit(np.array([[1, 2]]), 4, timeout=30)
+        go = _gated(eng)
+        futures = [eng.enqueue(np.array([[1, 2]]), n) for n in (1, 2)]
+        go.set()
+        failed = 0
+        for future, n in zip(futures, (1, 2)):
+            try:
+                got = future.result(timeout=30)
+            except E.PyGridError as err:
+                assert "engine error" in str(err)
+                failed += 1
+            else:
+                np.testing.assert_array_equal(got, _ref(params, [[1, 2]], n))
+        # the request the failing program served never got its answer
+        assert failed >= 1 and not eng._arrivals
         setattr(eng.programs, program, builder)
-        assert len(calls) == 1, "the named program is the one that failed"
-        for arr in (eng._k, eng._v, eng._pos):
+        assert calls, "the named program is the one that failed"
+        if when == "call":
+            assert len(calls) == 1  # nothing was launched behind it
+        for arr in (eng._k, eng._v, eng._pos, eng._last):
             assert not arr.is_deleted()
             assert not np.asarray(jnp.abs(arr).sum())
         stats = eng.stats()
@@ -280,6 +330,175 @@ def test_engine_recovers_after_device_loop_failure(params, program, fused):
         assert led["drained"] and led["balanced"], led
     finally:
         eng.close()
+
+
+# ── dispatching ahead of what the host has read ──────────────────────────
+
+
+#: more requests than slots, ``n_new`` of 1 and 2 among them: a row that
+#: leaves its slot at its own prefill, one that leaves a step later
+BACKLOG = [
+    ([3, 5, 2, 9, 11], 6), ([1, 2], 1), ([7, 8, 9], 2), ([4], 9),
+    ([6, 6, 6, 1], 1), ([2, 4, 6, 8, 10, 12, 14], 5), ([9], 2), ([5, 3], 11),
+]
+
+
+@pytest.mark.parametrize("temperature", [0.0, 0.8], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("fused", [False, True], ids=["step", "fused"])
+def test_dispatch_ahead_serves_generates_tokens_under_a_backlog(
+    params, fused, temperature
+):
+    """Eight requests on two slots. Step n+1 is launched before step n's
+    tokens are on the host and a freed slot's prefill goes out behind a
+    step still in flight, so what decides each token (the device's last
+    token, the key of the token being SCHEDULED, the row's place) never
+    comes from ``row.out``. Every answer is bit-identical to
+    ``generate()`` alone, greedy and sampled per seed."""
+    eng = GenerationEngine(
+        CFG,
+        params,
+        EngineConfig(
+            max_slots=2, slot_buckets=(1, 2), min_prompt_bucket=8,
+            block_size=8, fused=fused,
+        ),
+        model_id="ahead",
+    )
+    try:
+        prefill = eng.programs.paged_prefill
+        behind_a_step = []
+
+        def watched(bucket):
+            fn = prefill(bucket)
+
+            def call(*args):
+                behind_a_step.append(any(a.decode for a in eng._arrivals))
+                return fn(*args)
+
+            return call
+
+        eng.programs.paged_prefill = watched
+        go = _gated(eng)
+        ahead = _ahead("yes")
+        futures = [
+            eng.enqueue(np.array([p]), n, temperature=temperature, seed=40 + i)
+            for i, (p, n) in enumerate(BACKLOG)
+        ]
+        go.set()
+        for i, ((p, n), future) in enumerate(zip(BACKLOG, futures)):
+            kw = (
+                dict(temperature=temperature, key=jax.random.PRNGKey(40 + i))
+                if temperature else {}
+            )
+            np.testing.assert_array_equal(
+                future.result(120), _ref(params, [p], n, **kw)
+            )
+        assert len(behind_a_step) == len(BACKLOG) and any(behind_a_step)
+        assert _ahead("yes") > ahead
+        led = eng.ledger()
+        assert led["drained"] and led["balanced"], led
+    finally:
+        eng.close()
+
+
+def test_a_table_handed_to_a_program_is_not_the_mirror_the_host_edits(params):
+    """``jnp.asarray`` may hand the device the numpy array's own memory
+    (on the CPU it does, for some alignments), and a program in flight
+    reads its block table while the host zeroes a retired row's entries
+    for the next: the device gets a copy that nobody writes again."""
+    eng = GenerationEngine(
+        CFG,
+        params,
+        EngineConfig(max_slots=2, slot_buckets=(1, 2), block_size=4),
+        model_id="table",
+    )
+    try:
+        assert eng._table_np.shape == (2, 8)
+        mirrors = []  # kept: each new mirror at another address
+        for _ in range(32):  # whatever alignment the allocator gives
+            mirrors.append(eng._table_np)
+            eng._table_np = np.zeros_like(eng._table_np)
+            eng._table_np[0, :3] = (5, 6, 7)
+            eng._table_dirty = True
+            handed = eng._table()
+            eng._table_np[0, :] = 0  # the row retires
+            assert np.asarray(handed)[0].tolist() == [5, 6, 7, 0, 0, 0, 0, 0]
+    finally:
+        eng.close()
+
+
+def test_a_bursts_last_answer_needs_no_further_request(params):
+    """Nothing stays in flight where nobody would collect it: with the
+    last row's last token scheduled the loop has nothing left to launch,
+    and it fetches what is in flight before it waits for work."""
+    eng = GenerationEngine(
+        CFG,
+        params,
+        EngineConfig(
+            max_slots=2, slot_buckets=(1, 2), min_prompt_bucket=8,
+            block_size=8, fused=False,
+        ),
+        model_id="burst",
+    )
+    try:
+        go = _gated(eng)
+        futures = [eng.enqueue(np.array([p]), n) for p, n in BACKLOG]
+        go.set()
+        for (p, n), future in zip(BACKLOG, futures):
+            np.testing.assert_array_equal(
+                future.result(120), _ref(params, [p], n)
+            )
+        assert not eng._arrivals
+        stats = eng.stats()
+        assert stats["tokens_total"] == sum(n for _, n in BACKLOG)
+        assert stats["live_slots"] == 0 and eng.ledger()["drained"]
+    finally:
+        eng.close()
+
+
+def test_close_answers_a_row_whose_last_token_is_in_flight(params):
+    """``close()`` lands between two passes of the loop: row A's last
+    token was scheduled by the pass's last step and is still in flight,
+    row B is mid-generation. The thread collects before it leaves, so A
+    is answered (its tokens were made); B, which is not finished, fails
+    typed."""
+    eng = GenerationEngine(
+        CFG,
+        params,
+        EngineConfig(
+            max_slots=2, slot_buckets=(1, 2), min_prompt_bucket=8,
+            block_size=8, fused=False, quantum=2,
+        ),
+        model_id="closing",
+    )
+    reached, release = threading.Event(), threading.Event()
+    step, steps = eng._step, []
+
+    def held_after_the_pass():
+        freed = step()
+        steps.append(freed)
+        if len(steps) == 2:  # the quantum's last step: A just retired
+            reached.set()
+            release.wait(30)
+        return freed
+
+    eng._step = held_after_the_pass
+    go = _gated(eng)
+    a = eng.enqueue(np.array([[3, 5, 2]]), 3)  # prefill + two steps
+    b = eng.enqueue(np.array([[7, 8]]), 20)
+    go.set()
+    assert reached.wait(60)
+    assert not a.done() and [r.pending.future for x in eng._arrivals for r in x.rows]
+    closer = threading.Thread(target=eng.close)
+    closer.start()
+    while eng._running:
+        closer.join(0.01)
+    release.set()
+    closer.join(30)
+    assert not closer.is_alive()
+    np.testing.assert_array_equal(a.result(0), _ref(params, [[3, 5, 2]], 3))
+    with pytest.raises(E.PyGridError, match="closed"):
+        b.result(0)
+    assert not eng._arrivals and eng.ledger()["balanced"]
 
 
 def test_compiled_surface_is_buckets_plus_two_programs_a_width(params):
