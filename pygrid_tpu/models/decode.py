@@ -73,6 +73,7 @@ FAMILIES = {
     "transformer": ("TransformerConfig", "pygrid_tpu.models.decode"),
     "jamba": ("JambaConfig", "pygrid_tpu.models.jamba"),
     "sdar_moe": ("SdarConfig", "pygrid_tpu.models.sdar_moe"),
+    "solar_open2": ("SolarConfig", "pygrid_tpu.models.solar_open2"),
 }
 
 
@@ -116,8 +117,11 @@ def from_bundle(spec: dict) -> tuple[Any, Any]:
 # family's module (:func:`family_of`): ``init_paged_cache``,
 # ``paged_prefill_chunk`` and ``paged_decode_step`` over a cache whose
 # first three fields are ``k, v, pos``, and the facts below. This module
-# is the transformer's; :mod:`pygrid_tpu.models.jamba` the hybrid's and
-# :mod:`pygrid_tpu.models.sdar_moe` the block-diffusion decoder's.
+# is the transformer's; :mod:`pygrid_tpu.models.jamba` the state-space
+# hybrid's, :mod:`pygrid_tpu.models.sdar_moe` the block-diffusion
+# decoder's and :mod:`pygrid_tpu.models.solar_open2` the delta-rule
+# hybrid's with a chip's share of its experts. A family with experts
+# answers, after its cache, what its forward counted of them.
 
 #: no recurrent state, so prefix pages can be shared: that sharing is the
 #: one thing a family switches off by saying True here

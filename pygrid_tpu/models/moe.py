@@ -19,9 +19,14 @@ reassociation — that is what the tests pin.
 The second half of the module is the SERVED expert layer
 (:func:`routed_experts`): a top-k softmax router without drops or
 capacity and a grouped gated-SiLU FFN that reads each touched expert's
-weights once — what :mod:`pygrid_tpu.models.sdar_moe` runs in every
-layer of every forward. The training-only top-1 layer above is untouched
-by it.
+weights once — what :mod:`pygrid_tpu.models.sdar_moe` and
+:mod:`pygrid_tpu.models.solar_open2` run in every layer of every forward.
+A chip may HOLD a share of a layer's experts (``held``): the router still
+scores all of them, the held ones compute their part of the result, and
+what the absent ones would add is left out (no exchange, nothing standing
+in for the other chips). A shared expert beside the routed ones is the
+caller's dense FFN (:func:`shared_expert`). The training-only top-1 layer
+above is untouched by it.
 """
 
 from __future__ import annotations
@@ -161,9 +166,27 @@ def apply_expert_parallel(
 #: again)
 ROW_TILE = 32
 
-#: VMEM the grouped kernel asks for: one expert's three matrices, double
-#: buffered (2 x 9.4 MB at 2048 x 768 in bfloat16), and the row tiles
+#: rows of one grid step where an expert receives a hundred rows and
+#: more (a long prompt's prefill): a whole MXU pass of rows for each load
+#: of the expert's weights
+ROW_TILE_WIDE = 128
+
+#: VMEM the grouped kernel asks for at the least: one expert's three
+#: matrices, double buffered (2 x 9.4 MB at 2048 x 768 in bfloat16), and
+#: the row tiles. A wider expert asks for what its own matrices need
+#: (:func:`_vmem_limit`)
 VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def _vmem_limit(w_gate: jax.Array, w_down: jax.Array, tile: int) -> int:
+    """VMEM for one grid step: the expert's three matrices twice (the
+    next expert's arrive while this one multiplies), the row tile in and
+    out twice, and the tile's float32 hidden activations."""
+    _, d, f = w_gate.shape
+    expert = (2 * w_gate[0].size + w_down[0].size) * w_gate.dtype.itemsize
+    rows = tile * d * (w_gate.dtype.itemsize + 4)
+    need = 2 * expert + 2 * rows + 4 * tile * f * 4 + (4 << 20)
+    return max(VMEM_LIMIT, need)
 
 
 def route_topk(x: jax.Array, w_router: jax.Array, k: int):
@@ -186,17 +209,29 @@ def grouped_layout(expert_ids: jax.Array, n_experts: int, tile: int):
     Returns ``dest`` [N] (its row), ``tile_expert`` [tiles] (the expert
     whose weights a tile multiplies; a dead tile repeats the last live
     one's, so it fetches nothing), ``n_live`` (tiles that hold rows) and
-    ``sizes`` [E] (rows an expert received)."""
+    ``sizes`` [E] (rows an expert received). An assignment whose id is
+    ``n_experts`` fell on an expert that is not held here: it takes no
+    row (its ``dest`` is past the layout's end) and counts in no size.
+    The layout has room for all ``N`` it is given landing on one expert
+    or spread over all; tiles past ``n_live`` cost a grid step and move
+    nothing."""
     n = expert_ids.shape[0]
     n_tiles = -(-n // tile) + n_experts
-    sizes = jnp.zeros((n_experts,), jnp.int32).at[expert_ids].add(1)
+    sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[expert_ids].add(1)[
+        :n_experts
+    ]
     tiles = -(-sizes // tile)
     tile_end = jnp.cumsum(tiles)
     n_live = tile_end[-1]
     order = jnp.argsort(expert_ids, stable=True)
     sorted_ids = expert_ids[order]
-    rank = jnp.arange(n, dtype=jnp.int32) - (jnp.cumsum(sizes) - sizes)[sorted_ids]
-    dest_sorted = (tile_end - tiles)[sorted_ids] * tile + rank
+    here = jnp.minimum(sorted_ids, n_experts - 1)
+    rank = jnp.arange(n, dtype=jnp.int32) - (jnp.cumsum(sizes) - sizes)[here]
+    dest_sorted = jnp.where(
+        sorted_ids < n_experts,
+        (tile_end - tiles)[here] * tile + rank,
+        n_tiles * tile,
+    )
     dest = jnp.zeros((n,), jnp.int32).at[order].set(dest_sorted)
     t = jnp.arange(n_tiles, dtype=jnp.int32)
     tile_expert = jnp.searchsorted(
@@ -225,6 +260,15 @@ def _grouped_kernel(te_ref, live_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
         )
 
 
+def _row_block(t, n_live):
+    """The block of rows grid step ``t`` works on: its own while it is
+    live; a dead tile stays on the last live one (nothing is fetched or
+    written back); and where no assignment fell on a held expert there is
+    no live tile and every step stays on the first (an index of -1 is a
+    DMA outside the array: the chip halts)."""
+    return jnp.maximum(jnp.minimum(t, n_live - 1), 0)
+
+
 def grouped_expert_ffn(
     x_rows: jax.Array,
     w_gate: jax.Array,
@@ -233,19 +277,20 @@ def grouped_expert_ffn(
     tile_expert: jax.Array,
     n_live: jax.Array,
     interpret: bool = False,
+    tile: int = ROW_TILE,
 ) -> jax.Array:
     """``(silu(x W_gate,e) * x W_up,e) W_down,e`` for every row of the
     tile-aligned layout (:func:`grouped_layout`), float32 out. ``x_rows``
-    [tiles·ROW_TILE, d] in the weights' dtype; the weights ``[E, d, f]``,
+    [tiles·tile, d] in the weights' dtype; the weights ``[E, d, f]``,
     ``[E, d, f]``, ``[E, f, d]`` stay in HBM and one expert's three
     matrices are in VMEM at a time. Rows of dead tiles are left
     unwritten."""
     n_rows, d = x_rows.shape
     _, _, f = w_gate.shape
-    n_tiles = n_rows // ROW_TILE
+    n_tiles = n_rows // tile
 
     def rows(t, te, live):
-        return (jnp.minimum(t, live[0] - 1), 0)
+        return (_row_block(t, live[0]), 0)
 
     def expert(t, te, live):
         return (te[t], 0, 0)
@@ -256,17 +301,17 @@ def grouped_expert_ffn(
             num_scalar_prefetch=2,
             grid=(n_tiles,),
             in_specs=[
-                pl.BlockSpec((ROW_TILE, d), rows),
+                pl.BlockSpec((tile, d), rows),
                 pl.BlockSpec((1, d, f), expert),
                 pl.BlockSpec((1, d, f), expert),
                 pl.BlockSpec((1, f, d), expert),
             ],
-            out_specs=pl.BlockSpec((ROW_TILE, d), rows),
+            out_specs=pl.BlockSpec((tile, d), rows),
         ),
         out_shape=jax.ShapeDtypeStruct((n_rows, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=VMEM_LIMIT,
+            vmem_limit_bytes=_vmem_limit(w_gate, w_down, tile),
         ),
         interpret=interpret,
         name="grouped_expert_ffn",
@@ -281,6 +326,77 @@ def grouped_eligible(w_gate: jax.Array) -> bool:
     return jax.default_backend() == "tpu" and d % 128 == 0 and f % 128 == 0
 
 
+def shared_expert(
+    x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array
+) -> jax.Array:
+    """The expert every token passes through, beside the routed ones: a
+    dense gated SiLU FFN over ``x`` [T, d] in the weights' dtype,
+    accumulated in float32."""
+    with jax.named_scope("moe.shared"):
+        mm = lambda a, w: jnp.dot(  # noqa: E731
+            a.astype(w.dtype), w, preferred_element_type=jnp.float32,
+            precision=(
+                lax.Precision.HIGHEST if w.dtype == jnp.float32 else None
+            ),
+        )
+        return mm(jax.nn.silu(mm(x, w_gate)) * mm(x, w_up), w_down)
+
+
+def _grouped_rows(xw, token, ids, w_gate, w_up, w_down, tile, interpret):
+    """Each assignment's expert output ``[N, d]`` float32 through the tile
+    layout and the kernel (an assignment to no held expert reads whatever
+    the buffer held: the caller masks it)."""
+    dest, tile_expert, n_live, _ = grouped_layout(ids, w_gate.shape[0], tile)
+    x_rows = jnp.zeros(
+        (tile_expert.shape[0] * tile, xw.shape[1]), xw.dtype
+    ).at[dest].set(xw[token], mode="drop")
+    return grouped_expert_ffn(
+        x_rows, w_gate, w_up, w_down, tile_expert, n_live,
+        interpret=interpret, tile=tile,
+    )[dest]
+
+
+def _weighed(rows, p):
+    """A token's ``k`` assignments' rows ``[T·k, d]``, each times its
+    probability, summed: ``[T, d]``."""
+    return (rows.reshape(*p.shape, rows.shape[-1]) * p[..., None]).sum(1)
+
+
+def _held_rounds(xw, ids, here, p, k, w_gate, w_up, w_down, share, tile, interpret):
+    """``sum_j p_j expert_j(x)`` over the assignments that fell on a held
+    expert, ``[T, d]`` float32, where the chip holds ``share`` of the
+    router's experts. The tile layout is sized for TWICE the even share
+    of the ``N`` assignments, not for all of them landing here (which
+    the router may do, and a layout sized for it moves eight times the
+    rows it has to): the held assignments are taken in rounds of that
+    many, in their own order, until none is left. One round under even
+    routing; as many as it takes under any other, so nothing is ever
+    dropped."""
+    N, T = ids.shape[0], xw.shape[0]
+    cap = min(N, tile * -(-int(2 * N * share) // tile))
+    rounds = -(-N // cap)
+    order = jnp.argsort(~here, stable=True)  # the held ones first
+    order = jnp.concatenate(
+        [order, jnp.zeros((rounds * cap - N,), order.dtype)]
+    ).astype(jnp.int32)
+    n_held = jnp.sum(here).astype(jnp.int32)
+    p = p.reshape(-1)
+
+    def a_round(r, y):
+        mine = lax.dynamic_slice(order, (r * cap,), (cap,))
+        real = r * cap + jnp.arange(cap, dtype=jnp.int32) < n_held
+        rows = _grouped_rows(
+            xw, mine // k, jnp.where(real, ids[mine], w_gate.shape[0]),
+            w_gate, w_up, w_down, tile, interpret,
+        )
+        rows = jnp.where(real[:, None], rows, 0.0) * p[mine][:, None]
+        return y.at[mine // k].add(rows, indices_are_sorted=True)
+
+    return lax.fori_loop(
+        0, -(-n_held // cap), a_round, jnp.zeros((T, xw.shape[1]), jnp.float32)
+    )
+
+
 def routed_experts(
     x: jax.Array,
     w_router: jax.Array,
@@ -290,32 +406,54 @@ def routed_experts(
     k: int,
     kernel: bool | None = None,
     interpret: bool = False,
+    held: tuple[int, int] | None = None,
 ):
     """The served expert layer over ``x`` [T, d] (float32, normed):
     ``sum_{e in top-k} p_e · W_down,e (silu(W_gate,e x) * W_up,e x)`` with
     ``p`` the router's softmax renormalised over the ``k`` chosen; no
-    drops, no capacity, no shared expert. Returns (``y`` [T, d] float32,
-    the number of experts that received a row, int32: what the forward
-    had to read of this layer's experts)."""
+    drops, no capacity.
+
+    ``held = (first, count)`` says which of the router's experts the
+    weights given are: ``w_gate[i]`` is expert ``first + i``. The router
+    scores all of its outputs and the ``k`` chosen are renormalised as
+    ever; an assignment to an expert that is not held takes no row of
+    the tile layout, reads no weight and adds nothing (what the chips
+    that hold it would add is left out). ``None``: every expert is here.
+
+    Returns (``y`` [T, d] float32; the number of held experts that
+    received a row, int32: what the forward had to read of this layer's
+    experts; the number of assignments that fell on a held expert,
+    int32: all ``T·k`` of them where every expert is here)."""
     n_experts = w_gate.shape[0]
     idx, p = route_topk(x, w_router, k)
     with jax.named_scope("moe.experts"):
         ids = idx.reshape(-1)
+        if held is not None:
+            local = ids - held[0]
+            here = (local >= 0) & (local < n_experts)
+            ids = jnp.where(here, local, n_experts)
+            p = jnp.where(here.reshape(p.shape), p, 0.0)
+        sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[ids].add(1)[
+            :n_experts
+        ]
         token = jnp.arange(ids.shape[0], dtype=jnp.int32) // k
         xw = x.astype(w_gate.dtype)
         if grouped_eligible(w_gate) if kernel is None else kernel:
-            dest, tile_expert, n_live, sizes = grouped_layout(
-                ids, n_experts, ROW_TILE
-            )
-            x_rows = jnp.zeros(
-                (tile_expert.shape[0] * ROW_TILE, x.shape[1]), xw.dtype
-            ).at[dest].set(xw[token])
-            y = grouped_expert_ffn(
-                x_rows, w_gate, w_up, w_down, tile_expert, n_live,
-                interpret=interpret,
-            )[dest]
+            # a tile of rows a step: wide where the router's even share
+            # gives an expert a wide tile's worth (a long prompt), two
+            # sublane tiles where it gives it a handful (a decode step)
+            wide = ids.shape[0] // w_router.shape[1] >= ROW_TILE_WIDE
+            tile = ROW_TILE_WIDE if wide else ROW_TILE
+            if held is not None:
+                y = _held_rounds(
+                    xw, ids, here, p, k, w_gate, w_up, w_down,
+                    n_experts / w_router.shape[1], tile, interpret,
+                )
+            else:
+                y = _weighed(_grouped_rows(
+                    xw, token, ids, w_gate, w_up, w_down, tile, interpret
+                ), p)
         else:
-            sizes = jnp.zeros((n_experts,), jnp.int32).at[ids].add(1)
             order = jnp.argsort(ids, stable=True)
             xs = xw[token[order]]
             dot = lambda a, w: lax.ragged_dot(  # noqa: E731
@@ -327,5 +465,11 @@ def routed_experts(
             hidden = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
             ys = dot(hidden.astype(w_down.dtype), w_down)
             y = jnp.zeros_like(ys).at[order].set(ys)
-        y = (y.reshape(-1, k, y.shape[-1]) * p[..., None]).sum(1)
-        return y, jnp.sum(sizes > 0).astype(jnp.int32)
+            if held is not None:
+                # a row past the held experts' groups is nobody's
+                y = jnp.where(here[:, None], y, 0.0)
+            y = _weighed(y, p)
+        return (
+            y, jnp.sum(sizes > 0).astype(jnp.int32),
+            jnp.sum(sizes).astype(jnp.int32),
+        )

@@ -250,7 +250,7 @@ def _experts(h, lp, c, cfg):
     """The expert layer's residual branch over ``h`` [..., d] and the
     number of this layer's experts that received a row."""
     lead = h.shape[:-1]
-    y, touched = moe.routed_experts(
+    y, touched, _ = moe.routed_experts(
         _rms(h, lp["norm_ff"]).reshape(-1, cfg.d_model), lp["router"],
         c(lp["w_gate"]), c(lp["w_up"]), c(lp["w_down"]), cfg.top_k,
         interpret=jax.default_backend() != "tpu",

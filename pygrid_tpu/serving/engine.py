@@ -999,8 +999,8 @@ class GenerationEngine:
         """A prefill's answer, fetched: its seconds, and a causal
         family's first token into the row."""
         telemetry.observe("serving_prefill_seconds", seconds)
+        self._note_counted("prefill", fetched[1:])
         if self._block_len > 1:
-            self._note_expert_bytes("prefill", fetched[1])
             return
         telemetry.observe(
             "serving_ttft_seconds", time.perf_counter() - row.enqueued_at
@@ -1197,10 +1197,15 @@ class GenerationEngine:
             temps[i] = row.temperature
             if row.keys is not None:
                 keys[i] = row.keys[row.scheduled]
+
+        def take(live, toks, *counted):
+            self._note_counted("step", counted)
+            return [int(toks[i]) for i, _ in live]
+
         return (
             self.programs.paged_decode(width),
             (jnp.asarray(temps), jnp.asarray(keys)),
-            lambda live, toks: [int(toks[i]) for i, _ in live],
+            take,
         )
 
     def _block_inputs(self, width: int, live: list[tuple[int, "_Row"]]):
@@ -1229,8 +1234,8 @@ class GenerationEngine:
             {"denoise": len(live) - commits, "commit": commits},
         )
 
-        def take(live, toks, chosen, expert_bytes):
-            self._note_expert_bytes("step", expert_bytes)
+        def take(live, toks, chosen, *counted):
+            self._note_counted("step", counted)
             yielded = []
             for i, row in live:
                 tokens = self._close_forward(
@@ -1247,13 +1252,25 @@ class GenerationEngine:
         )
 
     @staticmethod
-    def _note_expert_bytes(path: str, read) -> None:
-        """Bytes of expert weights one forward had to read, as the block
-        family's program counted them (touched (layer, expert) pairs x
-        one expert's matrices), under the path that ran it."""
-        telemetry.incr(
-            "serving_expert_bytes_total", float(read), kind="read", path=path
-        )
+    def _note_counted(path: str, counted) -> None:
+        """What a family's program counted of one forward and answered
+        beside its tokens (nothing, for a family without experts), under
+        the path that ran it: first the bytes of expert weights it had
+        to read (touched (layer, expert) pairs x one expert's matrices);
+        then, where the family holds a share of its experts, the
+        assignments its routers made and those that fell on an expert
+        held here."""
+        for counts in counted:
+            read, *rows = np.ravel(counts)
+            telemetry.incr(
+                "serving_expert_bytes_total", float(read), kind="read",
+                path=path,
+            )
+            if rows:
+                telemetry.incr_many(
+                    "serving_expert_rows_total", "kind",
+                    {"routed": float(rows[0]), "held": float(rows[1])},
+                )
 
     def _open_block(self, row: _Row, pos: int) -> None:
         """Start the row's block at position ``pos``: what of it is
@@ -1363,7 +1380,8 @@ class GenerationEngine:
         """A scan's answer, fetched: its seconds on the bus and each
         row's first ``count`` tokens of the [steps, w] matrix (a frozen
         row's tail is its last token repeated)."""
-        (toks,) = fetched
+        toks, *counted = fetched
+        self._note_counted("fused", counted)
         telemetry.observe(
             "serving_dispatch_seconds", seconds, path="fused",
             width=str(width),

@@ -169,9 +169,10 @@ class ProgramSet:
         on-device and left in ``last[slot]``. A family whose cache has
         more arrays than ``k, v, pos`` (a recurrent state) takes and
         returns them after ``pos``, donated like the rest: so for every
-        paged program below. What a family's prefill answers after its
-        cache (a block family's expert bytes) comes back between the
-        token and the cache."""
+        paged program below. What a family's prefill or step answers
+        after its cache (what it counted of its experts) comes back
+        between the tokens and the cache: so for every paged program
+        below too (a scan answers the sum over its steps)."""
         fn = self._paged_prefill.get(bucket)
         if fn is None:
             import jax
@@ -232,7 +233,7 @@ class ProgramSet:
                 def body(carry, step_keys):
                     *arrays, tok, remaining = carry
                     alive = remaining > 0
-                    logits, cache = model.paged_decode_step(
+                    logits, cache, *counted = model.paged_decode_step(
                         params, cache_of(arrays), table, tok, cfg, cd,
                         active=alive,
                     )
@@ -241,12 +242,15 @@ class ProgramSet:
                     carry = (
                         *cache, nxt, remaining - alive.astype(jnp.int32),
                     )
-                    return carry, nxt
+                    return carry, (nxt, *counted)
 
-                (*arrays, tok, _), emitted = lax.scan(
+                (*arrays, tok, _), (emitted, *counted) = lax.scan(
                     body, (*args[:n], last[:width], budget), keys
                 )
-                return (emitted, *arrays, last.at[:width].set(tok))
+                return (
+                    emitted, *(c.sum(0) for c in counted), *arrays,
+                    last.at[:width].set(tok),
+                )
 
             fn = telemetry.profiler.wrap(
                 jax.jit(_fused, donate_argnums=self._donated_last),
@@ -272,11 +276,11 @@ class ProgramSet:
             def _paged_decode_step(params, *args):
                 last, table, temps, keys = args[n:]
                 width = temps.shape[0]
-                logits, cache = model.paged_decode_step(
+                logits, cache, *counted = model.paged_decode_step(
                     params, cache_of(args[:n]), table, last[:width], cfg, cd
                 )
                 toks = jax.vmap(self._pick)(logits, temps, keys)
-                return (toks, *cache, last.at[:width].set(toks))
+                return (toks, *counted, *cache, last.at[:width].set(toks))
 
             fn = telemetry.profiler.wrap(
                 jax.jit(

@@ -239,3 +239,61 @@ def test_block_step_gathers_its_pages_from_the_pool_where_it_lies(
     # rows x 16 pages), or of the pool's, is made on the way
     assert len(re.findall(r"= bf16\[1024,64,4,128\]", entry)) == len(gathers)
     assert not re.search(r"= bf16\[\d+,64,4,128\]\S* copy\(", entry)
+
+
+def test_longdoc_prefill_holds_its_kernels_and_no_square_of_scores(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """The delta-rule family's prefill at the published widths (64 heads
+    of 128 on 8 K/V heads, 40 of 320 experts of 4096 x 1280, bfloat16; a
+    bucket of 2,048 and 512 blocks, to keep the compile short): three
+    ``kda_chunk`` layers, one flash forward, ``grouped_expert_ffn`` in
+    every layer, under the names the roofline readers sum
+    (``perfbench/metrics/kda_chunk_roofline_pct.longdoc.py``,
+    ``expert_ffn_roofline_pct.longdoc.py``); no ``[heads, P, P]`` float32
+    scores, and the K/V pool is written in place, never copied whole."""
+    import re
+
+    from pygrid_tpu.models import solar_open2
+    from pygrid_tpu.serving.programs import ProgramSet
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = solar_open2.SolarConfig(
+        vocab=24576, d_model=4096, n_heads=64, n_kv_heads=8, head_dim=128,
+        n_layers=4, n_experts=320, top_k=8, d_expert=1280, held_first=0,
+        held_count=40, max_len=8448, kda_rank=128,
+    )
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda p: arg(p.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: solar_open2.init(jax.random.PRNGKey(0), cfg)),
+    )
+    P, slots, blocks, pages = 2048, 64, 512, 132
+    pool = arg((1, blocks, 64, 8, 128), jnp.bfloat16)
+    prefill = ProgramSet(cfg, cache_dtype=jnp.bfloat16).paged_prefill(P)
+    while not hasattr(prefill, "lower"):  # the profiler's wrapper
+        prefill = prefill.__wrapped__
+    compiled = prefill.lower(
+        params, pool, pool, arg((slots,), jnp.int32),
+        arg((3, slots, 64, 128, 128), jnp.float32),
+        arg((3, 3, slots, 3 * 8192), jnp.bfloat16), arg((slots,), jnp.int32),
+        arg((slots, pages), jnp.int32), arg((), jnp.int32), arg((P,), jnp.int32),
+        arg((), jnp.int32), arg((), jnp.int32), arg((), jnp.float32),
+        arg((2,), jnp.uint32),
+    ).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    named = lambda name: sum(f"{name}" in c for c in calls)  # noqa: E731
+    assert len(calls) == 8, len(calls)
+    assert named("kda_chunk") == 3 and named("grouped_expert_ffn") == 4
+    assert named("flash_fwd") == 1
+    # the scores of 64 heads over the bucket, whole: 1 GB here, 17 GB at 8,192
+    assert not re.search(rf"f32\[64,{P},{P}\]|f32\[8,8,{P},{P}\]", text)
+    # the state is one float32 tensor a layer and slot, updated where it lies
+    assert "f32[3,64,64,128,128]" in text
+    assert not re.search(r"= bf16\[1,512,64,8,128\][^ ]* copy\(", text)
+    # what a prefill of 2,048 keeps beside its arguments: under 2 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024**3

@@ -146,7 +146,7 @@ def test_grouped_experts_equal_dense_compute_every_expert(params, kernel, tokens
     lp = params["layers"][1]
     x = jax.random.normal(jax.random.PRNGKey(tokens), (tokens, 64))
     want, idx = _dense_experts(x, lp, 2)
-    got, touched = jax.jit(
+    got, touched, landed = jax.jit(
         lambda x: moe.routed_experts(
             x, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"], 2,
             kernel=kernel, interpret=True,
@@ -155,6 +155,8 @@ def test_grouped_experts_equal_dense_compute_every_expert(params, kernel, tokens
     assert float(jnp.abs(got - want).max()) <= 2e-6
     # the count the roofline reader divides by: experts that received a row
     assert int(touched) == len(np.unique(np.asarray(idx)))
+    # every expert is here (``held`` not passed): every assignment lands
+    assert int(landed) == tokens * 2
 
 
 def test_grouped_layout_starts_every_expert_on_a_tile_and_drops_nothing():
