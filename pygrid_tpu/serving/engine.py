@@ -41,30 +41,34 @@ the node generates:
   per step — the dominant cost of small/medium-model decode;
 - a family whose forward carries a BLOCK of positions (``BLOCK_LEN`` >
   1: generation by diffusion over blocks) takes the same step loop with
-  one program a width, ``paged_block_step``: a row holds its current
-  block (tokens, which positions are still masked, the forward's index)
-  on the host between forwards, a forward reveals nought to
-  ``BLOCK_LEN`` of its tokens, and the row's position moves only when
-  the block is committed. Rows in different phases share a dispatch.
+  one program a width, ``paged_block_step``: a row's current block
+  (its tokens, which positions are still masked) lives on the device
+  beside the cache, a forward reveals nought to ``BLOCK_LEN`` of its
+  tokens there, and the row's position moves only when the block is
+  committed. Rows in different phases share a dispatch.
 
 **The loop dispatches ahead of what it has read.** Nothing the host
-decides between two steps of a causal family depends on the tokens:
-there is no stop token, so who is live, who ends and who is admitted at
-step n+1 follows from counts. A row therefore carries how many tokens
-are SCHEDULED for it (launched programs will make them) apart from how
-many have ARRIVED in ``row.out``; the loop plans by the first: a row
-leaves its slot, and its pages the pool, when its last token is
-scheduled. The device runs its programs in order, so a prefill into the
-same slot or pages queues behind the step that still writes them. The
-last tokens stay on the device (``programs``: ``last``), and what a
-launched program will answer the host waits in a FIFO of arrivals:
-``_step`` builds and launches step n+1 and only then fetches the
-arrivals up to step n, so the device has its next program while the host
-hands tokens out. Nothing stays in flight where nobody would collect it:
-the FIFO is drained when nothing is live any more (before the loop waits
-for work), when the thread leaves, and behind every fused scan. A block
-family's next forward is built from this forward's answer, so its
-``_step`` drains the FIFO before it returns (fetch, then build).
+decides between two forwards depends on the tokens: there is no stop
+token, so who is live, who ends and who is admitted at step n+1 follows
+from counts. A row therefore carries how many tokens are SCHEDULED for
+it (launched programs will make them) apart from how many have ARRIVED
+in ``row.out``; the loop plans by the first: a row leaves its slot, and
+its pages the pool, when its last token is scheduled. A block family's
+counts are fixed by ``denoising_steps``: a block with ``m`` masked
+positions takes ``ceil(m / (BLOCK_LEN / denoising_steps))`` denoising
+forwards, each revealing that many or what is left, then one commit (a
+row's last block none), so how many a forward reveals, whether it
+commits and what it makes final are known as it is built. The device
+runs its programs in order, so a prefill into the same slot or pages
+queues behind the step that still writes them. What a forward reads of
+the one before stays on the device (``programs``: ``last``, a causal
+family's last tokens; a block family's blocks), and what a launched
+program will answer the host waits in a FIFO of arrivals: ``_step``
+builds and launches step n+1 and only then fetches the arrivals up to
+step n, so the device has its next program while the host hands tokens
+out. Nothing stays in flight where nobody would collect it: the FIFO is
+drained when nothing is live any more (before the loop waits for work),
+when the thread leaves, and behind every fused scan.
 
 Every instant of the worker thread belongs to one of six phases of a
 :class:`~pygrid_tpu.telemetry.loopclock.LoopClock` — ``idle`` (nothing
@@ -168,8 +172,8 @@ class _Row:
         "pending", "row", "batch", "prompt", "n_new", "temperature",
         "seed", "keys", "out", "scheduled", "enqueued_at", "admitted_at",
         "pages", "shared_pages", "start", "demand", "denoising_steps",
-        "blk_pos", "blk_tokens", "blk_masked", "blk_step", "blk_forward",
-        "reveal", "dropped",
+        "blk_pos", "blk_left", "blk_forward", "blk_tail", "blk_tokens",
+        "blk_step", "reveal", "dropped",
     )
 
     def __init__(
@@ -203,17 +207,22 @@ class _Row:
         self.start = 0
         self.demand = 0
         #: a block family's row: how many denoising forwards reveal a
-        #: whole block; the current block (its first position, its
-        #: tokens, which are still masked, the forward that revealed
-        #: each, the next forward's index); and, beside ``out``, the
-        #: forward that revealed each token, with the (token, forward)
-        #: pairs the last block made past ``n_new``
+        #: whole block. What is PLANNED, as ``scheduled`` is: the block
+        #: the next forward runs (its first position, the masked
+        #: positions the launched forwards leave in it, the next
+        #: forward's index in it) and the prompt's tail, which the row's
+        #: first forward brings. What has ARRIVED, as ``out`` has: the
+        #: block being read (its tokens, the forward that revealed
+        #: each) and, beside ``out``, the forward that revealed each
+        #: token, with the (token, forward) pairs the last block made
+        #: past ``n_new``
         self.denoising_steps = denoising_steps
         self.blk_pos = 0
-        self.blk_tokens = None
-        self.blk_masked = None
-        self.blk_step = None
+        self.blk_left = 0
         self.blk_forward = 0
+        self.blk_tail = None
+        self.blk_tokens = None
+        self.blk_step = None
         self.reveal: list[int] = []
         self.dropped: list[tuple[int, int]] = []
 
@@ -300,10 +309,9 @@ class GenerationEngine:
         self._recurrent = bool(self._family.RECURRENT)
         #: positions a row's forward carries (1: a token a row a step)
         self._block_len = int(self._family.BLOCK_LEN)
-        # the fused scan carries one token a row from step to step ON
-        # the device; a block's state between forwards (which positions
-        # are revealed) lives on the host, so a family that says
-        # BLOCK_LEN > 1 has nothing the scan could carry
+        # the fused scan carries one token a row from step to step; a
+        # family that says BLOCK_LEN > 1 has its own step program and no
+        # scan over it
         self._fused = (
             pagedkv.fused_enabled(self.config.fused) and self._block_len == 1
         )
@@ -380,9 +388,11 @@ class GenerationEngine:
         # (a recurrent state; nothing for the transformer): it rides
         # every program call, donated like the rest
         self._k, self._v, self._pos, *self._state = cache
-        #: a causal family's last token a slot, on the device beside the
-        #: cache: the programs read and write it (``programs``), the
-        #: host never builds a step's input from tokens it has fetched
+        #: what a forward reads of the one before, on the device beside
+        #: the cache: a causal family's last token a slot, a block
+        #: family's block a slot (tokens, masked flags). The programs
+        #: read and write it (``programs``), the host never builds a
+        #: step's input from tokens it has fetched
         self._last = self._new_last()
         #: what launched programs will answer, oldest first, and when
         #: the newest fetch returned (engine thread only)
@@ -803,9 +813,7 @@ class GenerationEngine:
                 self._call(
                     self.programs.paged_block_step(w),
                     jnp.zeros((w, self._block_len), jnp.int32),
-                    jnp.zeros((w, self._block_len), bool),
                     jnp.zeros((w,), jnp.int32), jnp.zeros((w,), bool),
-                    last=False,
                 )
                 continue
             self._call(
@@ -1096,26 +1104,26 @@ class GenerationEngine:
         if not live:
             return False
         clock.annotate(path="step", width=width, live=len(live), steps=1)
-        causal = self._block_len == 1
-        build = self._token_inputs if causal else self._block_inputs
-        fn, inputs, take = build(width, live)
+        self._note_dispatch("step", width, live, 1)
+        build = (
+            self._token_inputs if self._block_len == 1
+            else self._block_inputs
+        )
+        fn, inputs, take, made = build(width, live)
         retired = self._retired
         launched = time.perf_counter()
         # the ONE decode dispatch site of the per-step path, whatever
         # the family
-        answer = self._call(fn, *inputs, last=causal)
-        self._note_dispatch("step", width, live, 1)
+        answer = self._call(fn, *inputs)
         self._arrivals.append(_Arrival(
             answer, launched, [row for _, row in live], True,
             functools.partial(self._hand_step, width, live, take),
         ))
-        if causal:
-            for i, row in live:
-                self._schedule(i, row, 1)
-        # a causal family leaves this step in flight and hands out what
-        # came before it while the device runs it; a block family's next
-        # forward is built from this one's answer
-        self._collect(keep=1 if causal else 0)
+        for (i, row), count in zip(live, made):
+            self._schedule(i, row, count)
+        # this step stays in flight: what came before it is handed out
+        # while the device runs it
+        self._collect(keep=1)
         return self._retired > retired
 
     def _hand_step(self, width: int, live, take, fetched, seconds) -> None:
@@ -1152,29 +1160,24 @@ class GenerationEngine:
             self._arrivals.popleft()
             arrival.hand(fetched, seconds)
 
-    def _call(self, fn, *inputs, last: bool = True) -> tuple:
+    def _call(self, fn, *inputs) -> tuple:
         """Launch one program on the engine's cache: the donated buffers
-        in (``last`` after them, for every program but a block step),
-        the returned ones in their place. Returns what the program
-        answers before them, not fetched. Engine thread only — the cache
-        buffers are single-writer."""
-        carried = (self._last,) if last else ()
-        result = fn(
+        in (``last`` after them), the returned ones in their place.
+        Returns what the program answers before them, not fetched.
+        Engine thread only — the cache buffers are single-writer."""
+        *result, self._last = fn(
             self.params, self._k, self._v, self._pos, *self._state,
-            *carried, self._table(), *inputs,
+            self._last, self._table(), *inputs,
         )
-        if last:
-            *result, self._last = result
         n = 3 + len(self._state)
         self._k, self._v, self._pos, *self._state = result[-n:]
         return tuple(result[:-n])
 
     def _schedule(self, slot: int, row: _Row, count: int) -> None:
         """``count`` more of the row's tokens are on their way (launched
-        programs make them; a block row's count is known once its
-        forward has answered). With its last the row is retired: its
-        slot freed, its pages released and its table row zeroed, for
-        whatever is launched next."""
+        programs make them). With its last the row is retired: its slot
+        freed, its pages released and its table row zeroed, for whatever
+        is launched next."""
         row.scheduled += count
         if row.scheduled < row.n_new:
             return
@@ -1185,10 +1188,10 @@ class GenerationEngine:
         self._retired += 1
 
     def _token_inputs(self, width: int, live: list[tuple[int, "_Row"]]):
-        """A decode step's program, its inputs after the table, and how
-        its answer reads: one token a live row. The step's tokens are
-        the device's (``last``); a row's key is the one of the token
-        being scheduled."""
+        """A decode step's program, its inputs after the table, how its
+        answer reads, and the tokens it makes each live row: one. The
+        step's tokens are the device's (``last``); a row's key is the
+        one of the token being scheduled."""
         import jax.numpy as jnp
 
         temps = np.zeros(width, np.float32)
@@ -1206,28 +1209,49 @@ class GenerationEngine:
             self.programs.paged_decode(width),
             (jnp.asarray(temps), jnp.asarray(keys)),
             take,
+            [1] * len(live),
         )
 
     def _block_inputs(self, width: int, live: list[tuple[int, "_Row"]]):
-        """A block step's program, its inputs after the table, and how
-        its answer reads. A row whose block still has masked positions
-        takes a DENOISING forward that reveals ``BLOCK_LEN /
-        denoising_steps`` of them (or those that are left); a row whose
-        block is whole takes the COMMIT forward, which reveals none and
-        moves its position on."""
+        """A block step's program, its inputs after the table, how its
+        answer reads, and the tokens it makes final for each live row.
+        All of it from counts, nothing from an answer: a row whose block
+        still has masked positions takes a DENOISING forward that
+        reveals ``BLOCK_LEN / denoising_steps`` of them (or those that
+        are left), and the one that reveals the last makes the block's
+        tokens final; a row whose block is whole takes the COMMIT
+        forward, which reveals none, moves its position on and opens the
+        next block. The blocks are the device's (``last``); a row's
+        first forward brings the prompt's tail, and a free slot inside
+        the width runs a known block of zeros."""
         import jax.numpy as jnp
 
         L = self._block_len
-        tokens = np.zeros((width, L), np.int32)
-        masked = np.zeros((width, L), bool)
+        tail = np.zeros((width, L), np.int32)
         n_reveal = np.zeros(width, np.int32)
         advance = np.zeros(width, bool)
+        plans, made = [], []
         for i, row in live:
-            tokens[i] = row.blk_tokens
-            masked[i] = row.blk_masked
-            left = int(row.blk_masked.sum())
-            n_reveal[i] = min(L // row.denoising_steps, left)
-            advance[i] = left == 0
+            tail[i] = -1 if row.blk_tail is None else row.blk_tail
+            row.blk_tail = None
+            if row.blk_left == 0:
+                advance[i] = True
+                plans.append(None)
+                made.append(0)
+                row.blk_pos += L
+                row.blk_left = L
+                row.blk_forward = 0
+                continue
+            n_reveal[i] = n = min(L // row.denoising_steps, row.blk_left)
+            row.blk_left -= n
+            whole = row.blk_left == 0
+            plans.append((row.blk_forward, row.blk_pos, whole))
+            row.blk_forward += 1
+            first, end = len(row.prompt), len(row.prompt) + row.n_new
+            made.append(
+                min(row.blk_pos + L, end) - max(row.blk_pos, first)
+                if whole else 0
+            )
         commits = int(advance.sum())
         telemetry.incr_many(
             "serving_block_forwards_total", "kind",
@@ -1236,19 +1260,16 @@ class GenerationEngine:
 
         def take(live, toks, chosen, *counted):
             self._note_counted("step", counted)
-            yielded = []
-            for i, row in live:
-                tokens = self._close_forward(
-                    row, toks[i], chosen[i], advance[i]
-                )
-                self._schedule(i, row, len(tokens))
-                yielded.append(tokens)
-            return yielded
+            return [
+                self._close_forward(row, toks[i], chosen[i], plan)
+                for (i, row), plan in zip(live, plans)
+            ]
 
         return (
             self.programs.paged_block_step(width),
-            tuple(map(jnp.asarray, (tokens, masked, n_reveal, advance))),
+            tuple(map(jnp.asarray, (tail, n_reveal, advance))),
             take,
+            made,
         )
 
     @staticmethod
@@ -1273,34 +1294,38 @@ class GenerationEngine:
                 )
 
     def _open_block(self, row: _Row, pos: int) -> None:
-        """Start the row's block at position ``pos``: what of it is
-        prompt is known, the rest masked."""
+        """Start the row's first block at position ``pos``: what of it
+        is prompt is known (the tail its first forward brings), the rest
+        masked. A later block starts wholly masked: each of its
+        positions is revealed, token and forward, before it is read, so
+        ``blk_tokens`` and ``blk_step`` need no opening again."""
         at = pos + np.arange(self._block_len)
         known = at < len(row.prompt)
         row.blk_pos = pos
-        row.blk_tokens = np.where(
-            known, row.prompt[np.minimum(at, len(row.prompt) - 1)], 0
-        ).astype(np.int32)
-        row.blk_masked = ~known
-        row.blk_step = np.full(self._block_len, -1, np.int32)
+        row.blk_left = int((~known).sum())
         row.blk_forward = 0
+        row.blk_tail = np.where(
+            known, row.prompt[np.minimum(at, len(row.prompt) - 1)], -1
+        ).astype(np.int32)
+        row.blk_tokens = row.blk_tail.copy()
+        row.blk_step = np.full(self._block_len, -1, np.int32)
 
-    def _close_forward(self, row: _Row, toks, chosen, committed) -> tuple:
-        """Take one forward's answer into the row's block; returns the
-        tokens the forward yields the row, in position order: the
-        block's own once its last masked position is revealed (those
-        past ``n_new`` go to ``row.dropped``), none before, none from a
-        commit (which opens the next block)."""
-        if committed:
-            self._open_block(row, row.blk_pos + self._block_len)
+    def _close_forward(self, row: _Row, toks, chosen, plan) -> tuple:
+        """Read one forward's answer into the row's block as the plan it
+        was built by says (``None``: a commit; else the forward's index
+        in its block, the block's position and whether it reveals the
+        block's last masked position); returns the tokens the forward
+        yields the row, in position order: the block's own once it is
+        whole (those past ``n_new`` go to ``row.dropped``), none before,
+        none from a commit."""
+        if plan is None:
             return ()
+        forward, pos, whole = plan
         row.blk_tokens[chosen] = toks[chosen]
-        row.blk_step[chosen] = row.blk_forward
-        row.blk_masked &= ~chosen
-        row.blk_forward += 1
-        if row.blk_masked.any():
+        row.blk_step[chosen] = forward
+        if not whole:
             return ()
-        at = row.blk_pos + np.arange(self._block_len)
+        at = pos + np.arange(self._block_len)
         end = len(row.prompt) + row.n_new
         past = at >= end
         kept = (at >= len(row.prompt)) & ~past
@@ -1398,9 +1423,9 @@ class GenerationEngine:
         live: list[tuple[int, "_Row"]],
         steps: int,
     ) -> None:
-        """One decode dispatch on the bus as it is launched, BEFORE its
+        """One decode dispatch on the bus as it is built, BEFORE its
         tokens are scheduled (the rows still hold the lengths the
-        program runs at): whether it went out ahead of an earlier decode
+        program runs at): whether it goes out ahead of an earlier decode
         dispatch whose tokens the host had not fetched; the rows it
         carries; the row-steps it computes against those that belong to
         an occupied slot; and the KV pages its attention reads against
@@ -1607,9 +1632,16 @@ class GenerationEngine:
     # ── helpers ─────────────────────────────────────────────────────────
 
     def _new_last(self):
+        """What the programs carry beside the cache (``programs``): a
+        causal family's last token a slot; a block family's block a
+        slot, its tokens and which positions are masked."""
         import jax.numpy as jnp
 
-        return jnp.zeros((self.config.max_slots,), jnp.int32)
+        slots = self.config.max_slots
+        if self._block_len == 1:
+            return jnp.zeros((slots,), jnp.int32)
+        block = (slots, self._block_len)
+        return jnp.zeros(block, jnp.int32), jnp.zeros(block, bool)
 
     def _span(self, p_len: int, n_new: int) -> int:
         """Positions a row's pages must cover: prompt and new tokens, up

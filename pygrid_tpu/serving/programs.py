@@ -31,6 +31,17 @@ back, a fused scan starts from it and leaves its carry there. So a
 step's input never waits for the host to have fetched the step before
 it; what a program answers the host (``tok``, ``toks``, ``emitted``) is
 an array of its own, which the next program does not consume.
+
+A block family's CURRENT BLOCK lives there in ``last``'s place, a pair
+over the slots: the block's tokens (int32 ``[slots, BLOCK_LEN]``) and
+which of its positions are still masked (bool, the same shape). A
+prefill opens the admitted slot's block (every position masked); a block
+step reads the first ``w`` rows, takes in what the host knows of a row's
+first block (the prompt's tail), runs the forward, applies the reveal
+there and, on a committing row, opens the next block. So a forward's
+input never waits for the host to have fetched the forward before it
+either; ``tokens`` and ``chosen`` are what the host reads, a dispatch
+late.
 """
 
 from __future__ import annotations
@@ -84,10 +95,13 @@ class ProgramSet:
         #: its paged cache: ``k, v, pos`` and whatever state the family
         #: keeps beside them, all donated (argument 0 is ``params``)
         self._cache_of = self._family.PagedCache._make
+        #: positions a row's forward carries (1: ``last`` is a token a
+        #: slot; more: the pair that holds a slot's block)
+        self._block_len = int(self._family.BLOCK_LEN)
         self._cache_arrays = len(self._family.PagedCache._fields)
-        self._donated = tuple(range(1, 1 + self._cache_arrays))
-        #: the programs that carry ``last`` donate it too
-        self._donated_last = (*self._donated, 1 + self._cache_arrays)
+        #: every program carries ``last`` (or the block) after them and
+        #: donates it too
+        self._donated = tuple(range(1, 2 + self._cache_arrays))
         self._paged_prefill: dict[int, Callable] = {}
         self._paged_decode: dict[int, Callable] = {}
         self._paged_fused: dict[tuple[int, int], Callable] = {}
@@ -166,7 +180,9 @@ class ProgramSet:
         start, length, temp, key) -> (first_token, k, v, pos, last)`` —
         admission of one request through its block table, continuing
         after a shared prefix of ``start`` tokens; first token picked
-        on-device and left in ``last[slot]``. A family whose cache has
+        on-device and left in ``last[slot]`` (a block family's prefill
+        yields no token: it opens the slot's block, every position
+        masked). A family whose cache has
         more arrays than ``k, v, pos`` (a recurrent state) takes and
         returns them after ``pos``, donated like the rest: so for every
         paged program below. What a family's prefill or step answers
@@ -187,10 +203,15 @@ class ProgramSet:
                     length, cfg, cd,
                 )
                 tok = self._pick(logits, temp, key)
-                return (tok, *counted, *cache, last.at[slot].set(tok))
+                if self._block_len > 1:
+                    tokens, masked = last
+                    last = tokens.at[slot].set(0), masked.at[slot].set(True)
+                else:
+                    last = last.at[slot].set(tok)
+                return (tok, *counted, *cache, last)
 
             fn = telemetry.profiler.wrap(
-                jax.jit(_paged_prefill, donate_argnums=self._donated_last),
+                jax.jit(_paged_prefill, donate_argnums=self._donated),
                 kind="paged_prefill", bucket=bucket,
                 model_id=self.model_id,
             )
@@ -253,7 +274,7 @@ class ProgramSet:
                 )
 
             fn = telemetry.profiler.wrap(
-                jax.jit(_fused, donate_argnums=self._donated_last),
+                jax.jit(_fused, donate_argnums=self._donated),
                 kind="paged_decode_fused", bucket=width,
                 model_id=self.model_id,
             )
@@ -283,9 +304,7 @@ class ProgramSet:
                 return (toks, *counted, *cache, last.at[:width].set(toks))
 
             fn = telemetry.profiler.wrap(
-                jax.jit(
-                    _paged_decode_step, donate_argnums=self._donated_last
-                ),
+                jax.jit(_paged_decode_step, donate_argnums=self._donated),
                 kind="paged_decode", bucket=width,
                 model_id=self.model_id,
             )
@@ -294,31 +313,52 @@ class ProgramSet:
         return fn
 
     def paged_block_step(self, width: int) -> Callable:
-        """``fn(params, k, v, pos, table, tokens[w, L], masked[w, L],
-        n_reveal[w], advance[w]) -> (tokens[w, L], chosen[w, L],
-        expert_bytes, k, v, pos)`` — one forward of the current block of
-        the first ``w`` slots of a family whose forward carries ``L =
-        BLOCK_LEN`` positions. Rows in different phases share the
+        """``fn(params, k, v, pos, block, table, tail[w, L], n_reveal[w],
+        advance[w]) -> (tokens[w, L], chosen[w, L], expert_bytes, k, v,
+        pos, block)`` — one forward of the current block of the first
+        ``w`` slots of a family whose forward carries ``L = BLOCK_LEN``
+        positions. ``block`` is the pair ``(tokens[slots, L],
+        masked[slots, L])`` the device keeps beside the cache, donated.
+        ``tail`` is what the host knows of a block that no forward has
+        run yet: a token (>= 0) stands at its position, known, whatever
+        the device held there; -1 leaves the position as it is. A row's
+        first forward brings the prompt's tail so; a free slot inside the
+        width brings four known zeros. Rows in different phases share the
         dispatch: a denoising row reveals ``n_reveal`` of its masked
-        positions (``chosen``, each with the token beside it), a
-        committing row reveals none and its position moves on
-        (``advance``). ``expert_bytes`` is what the forward read of the
-        experts' weights, as the family counts it."""
+        positions (``chosen``, each with the token beside it in
+        ``tokens``; they take those tokens and lose their mask in
+        ``block``), a committing row reveals none, its position moves on
+        (``advance``) and its next block opens, every position masked.
+        ``expert_bytes`` is what the forward read of the experts'
+        weights, as the family counts it."""
         fn = self._paged_block.get(width)
         if fn is None:
             import jax
+            import jax.numpy as jnp
 
             model, cfg, cd = self._family, self.cfg, self.compute_dtype
             cache_of, n = self._cache_of, self._cache_arrays
 
             def _paged_block_step(params, *args):
-                table, tokens, masked, n_reveal, advance = args[n:]
+                (tokens, masked), table, tail, n_reveal, advance = args[n:]
+                width = n_reveal.shape[0]
+                known = tail >= 0
+                blk = jnp.where(known, tail, tokens[:width])
+                hidden = masked[:width] & ~known
                 logits, cache, expert_bytes = model.paged_decode_step(
-                    params, cache_of(args[:n]), table, tokens, cfg, cd,
-                    active=advance, masked=masked,
+                    params, cache_of(args[:n]), table, blk, cfg, cd,
+                    active=advance, masked=hidden,
                 )
-                toks, chosen = self._reveal(logits, masked, n_reveal)
-                return (toks, chosen, expert_bytes, *cache)
+                toks, chosen = self._reveal(logits, hidden, n_reveal)
+                blk = jnp.where(chosen, toks, blk)
+                hidden = (hidden & ~chosen) | advance[:, None]
+                return (
+                    toks, chosen, expert_bytes, *cache,
+                    (
+                        tokens.at[:width].set(blk),
+                        masked.at[:width].set(hidden),
+                    ),
+                )
 
             fn = telemetry.profiler.wrap(
                 jax.jit(_paged_block_step, donate_argnums=self._donated),

@@ -222,8 +222,9 @@ def test_block_step_gathers_its_pages_from_the_pool_where_it_lies(
         step = step.__wrapped__
     text = step.lower(
         params, pool, pool, arg((w,), jnp.int32),
+        (arg((w, 4), jnp.int32), arg((w, 4), jnp.bool_)),
         arg((w, pages), jnp.int32), arg((w, 4), jnp.int32),
-        arg((w, 4), jnp.bool_), arg((w,), jnp.int32), arg((w,), jnp.bool_),
+        arg((w,), jnp.int32), arg((w,), jnp.bool_),
     ).compile().as_text()
     entry = text[text.index("\nENTRY"):]
     gathers = re.findall(
@@ -239,6 +240,11 @@ def test_block_step_gathers_its_pages_from_the_pool_where_it_lies(
     # rows x 16 pages), or of the pool's, is made on the way
     assert len(re.findall(r"= bf16\[1024,64,4,128\]", entry)) == len(gathers)
     assert not re.search(r"= bf16\[\d+,64,4,128\]\S* copy\(", entry)
+    # the row's block kept on the device (PR 39) is two [64, 4] arrays
+    # written where they lie: both pools and both of them are aliased to
+    # the program's results, none copied
+    aliased = re.search(r"input_output_alias=\{([^\n]*)\}, entry", text).group(1)
+    assert aliased.count("may-alias") + aliased.count("must-alias") == 5
 
 
 def test_longdoc_prefill_holds_its_kernels_and_no_square_of_scores(

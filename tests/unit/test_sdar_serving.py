@@ -23,6 +23,8 @@ from __future__ import annotations
 
 import json
 import sys
+import threading
+import time
 from pathlib import Path
 
 import numpy as np
@@ -372,15 +374,263 @@ def test_counters_and_the_ledger_after_the_cases(served):
         assert bus[path] > 0 and bus[path] % per_expert == 0
 
 
-def test_a_block_family_dispatches_nothing_ahead(served):
-    """The next forward's tokens and mask come from this forward's answer
-    through the host, so a block family fetches, then builds: with seven
-    requests on four slots every forward went out with no earlier decode
-    dispatch unread, and nothing was left in flight."""
-    _, _, stats, _ = served
+def test_a_block_family_dispatches_ahead_of_what_it_has_read(served):
+    """A row's block lives on the device and the host plans its forwards by
+    count, so forward n+1 goes out before forward n's answer is fetched:
+    with seven requests on four slots nearly every forward went out with an
+    earlier one unread (the first of a burst has none before it), nothing
+    was left in flight after the drain, and the ledger closes."""
+    _, _, stats, ledger = served
     bus = stats["bus"]
-    assert bus["ahead_yes"] == 0 and bus["ahead_no"] > 0
+    assert bus["ahead_yes"] > 4 * bus["ahead_no"] > 0
     assert bus["arrivals_left"] == 0
+    assert ledger["balanced"] and ledger["drained"]
+
+
+# ── the plan by count, forward by forward ────────────────────────────────
+
+PLANNED = [  # prompt length (a tail of 1-3 known positions), n_new, denoising_steps
+    (5, 9, 4), (10, 6, 2), (7, 8, 1), (14, 5, 4), (11, 7, 2), (9, 4, 1),
+]
+
+
+def _gated(eng):
+    """Hold the worker thread at its door: everything enqueued before
+    ``go`` is queued when the loop starts."""
+    go = threading.Event()
+    loop = eng._loop
+    eng._loop = lambda: (go.wait(30), loop())
+    return go
+
+
+@pytest.fixture(scope="module")
+def planned(model, cfg, scfg, params, weights):
+    """Six requests queued before a three-slot engine starts, every block
+    step's inputs recorded as it is built (``tail``, ``n_reveal``,
+    ``advance`` of each live row), and the plain generate's answers."""
+    eng = _engine(scfg, params, max_slots=3, slot_buckets=(3,))
+    sent, build = [], eng._block_inputs
+
+    def recording(width, live):
+        rows = [(row.pending.request_id, i) for i, row in live]
+        out = build(width, live)
+        tail, n_reveal, advance = (np.asarray(x) for x in out[1])
+        sent.append([
+            (rid, tail[i].tolist(), int(n_reveal[i]), bool(advance[i]))
+            for rid, i in rows
+        ])
+        return out
+
+    eng._block_inputs = recording
+    try:
+        go = _gated(eng)
+        prompts = [_tokens(200 + i, p) for i, (p, _, _) in enumerate(PLANNED)]
+        futures = [
+            eng.enqueue(prompt[None], n, denoising_steps=steps)
+            for prompt, (_, n, steps) in zip(prompts, PLANNED)
+        ]
+        ids = [r.pending.request_id for r in eng._queue]
+        go.set()
+        got = [
+            {k: np.asarray(v).tolist() for k, v in f.result(300).items()} for f in futures
+        ]
+        ledger = eng.ledger()
+    finally:
+        eng.close()
+    want = [
+        model.generate(weights, cfg, prompt, n, steps, pad_to=PAD)
+        for prompt, (_, n, steps) in zip(prompts, PLANNED)
+    ]
+    return got, want, sent, ids, prompts, ledger
+
+
+@pytest.mark.parametrize(
+    "case", range(len(PLANNED)), ids=[f"P{p}-n{n}-s{s}" for p, n, s in PLANNED]
+)
+def test_the_host_plans_by_count_what_fetch_then_build_would_have_sent(planned, case):
+    """What the host sends a row, forward by forward, it decides before any
+    answer is read; it must be what the answers would have told it. From
+    the plain generate's ``reveal_step``: a block's forward ``f`` reveals as
+    many positions as are named ``f``, a commit follows every block but the
+    row's last, and only the row's first forward brings tokens (the
+    prompt's tail)."""
+    got, want, sent, ids, prompts, _ = planned
+    p_len, n_new, steps = PLANNED[case]
+    assert got[case] == want[case]
+    named = (
+        [-1] * (p_len % 4) + want[case]["reveal_step"][0]
+        + want[case]["dropped_reveal_step"][0]
+    )
+    expect = []
+    for a in range(0, len(named), 4):
+        block = named[a : a + 4]
+        expect += [(block.count(f), False) for f in range(max(block) + 1)]
+        expect.append((0, True))
+    expect.pop()  # nothing reads the last block's K/V
+    mine = [row for step in sent for row in step if row[0] == ids[case]]
+    assert [(n, adv) for _, _, n, adv in mine] == expect
+    assert all(n <= 4 // steps for n, _ in expect)
+    tail = prompts[case][p_len // 4 * 4 :].tolist()
+    assert mine[0][1] == tail + [-1] * (4 - len(tail))
+    assert all(t == [-1] * 4 for _, t, _, _ in mine[1:])
+
+
+def test_rows_of_every_denoising_step_shared_the_planned_dispatches(planned):
+    _, _, sent, ids, _, ledger = planned
+    steps_of = {rid: steps for rid, (_, _, steps) in zip(ids, PLANNED)}
+    assert any({steps_of[r[0]] for r in step} == {4, 2, 1} for step in sent)
+    # a dispatch with a committing row beside a denoising one
+    assert any({adv for _, _, _, adv in step} == {True, False} for step in sent)
+    assert ledger["balanced"] and ledger["drained"]
+
+
+def test_a_slot_is_refilled_while_its_last_row_s_forward_is_unread(
+    model, cfg, scfg, params, weights
+):
+    """Two slots, three requests queued. The short row leaves slot 0 when
+    its last forward is LAUNCHED; the third request is admitted into that
+    slot (its prefill opens the slot's block on the device) while that
+    forward's answer is still in flight, beside a long row that goes on.
+    All three are answered as the plain generate answers them."""
+    eng = _engine(scfg, params, max_slots=2, slot_buckets=(2,))
+    seen, assign = [], eng._assign_pages
+
+    def watching(slot, row):
+        unread = [r for a in eng._arrivals if a.decode for r in a.rows]
+        seen.append((slot, [(len(r.out), r.scheduled, r.n_new) for r in unread]))
+        return assign(slot, row)
+
+    eng._assign_pages = watching
+    cases = [(9, 6, 2), (6, 24, 4), (7, 5, 4)]
+    try:
+        go = _gated(eng)
+        prompts = [_tokens(300 + i, p) for i, (p, _, _) in enumerate(cases)]
+        futures = [
+            eng.enqueue(prompt[None], n, denoising_steps=steps)
+            for prompt, (_, n, steps) in zip(prompts, cases)
+        ]
+        go.set()
+        got = [
+            {k: np.asarray(v).tolist() for k, v in f.result(300).items()} for f in futures
+        ]
+        assert not eng._arrivals
+        ledger = eng.ledger()
+    finally:
+        eng.close()
+    assert seen[:2] == [(0, []), (1, [])] and len(seen) == 3
+    # the short row's slot again, while the step that made its last block
+    # whole is unread: every token scheduled, the last not yet on the host
+    slot, unread = seen[2]
+    assert slot == 0 and len(unread) == 2
+    arrived, scheduled, n_new = unread[0]
+    assert scheduled == n_new == 6 and arrived < n_new
+    for answer, prompt, (_, n, steps) in zip(got, prompts, cases):
+        assert answer == model.generate(weights, cfg, prompt, n, steps, pad_to=PAD)
+    assert ledger["balanced"] and ledger["drained"]
+
+
+class _Poisoned:
+    """A program's answer whose error surfaces when the host fetches it."""
+
+    def __array__(self, *args, **kwargs):
+        raise RuntimeError("injected device failure")
+
+
+@pytest.mark.parametrize("when", ["call", "fetch"])
+def test_a_failed_block_forward_leaves_a_fresh_block_and_a_balanced_ledger(
+    model, cfg, scfg, params, weights, when
+):
+    """The third block step RUNS (the donated cache and the block beside it
+    are consumed) and then raises at its call, or hands back an answer that
+    raises when it is fetched, a dispatch later; either way a forward is in
+    flight when the failure lands. Every pending fails typed, the engine
+    holds fresh zeroed buffers (the block's two arrays among them), and the
+    next request equals the plain generate."""
+    eng = _engine(scfg, params, max_slots=1, slot_buckets=(1,))
+    try:
+        builder = eng.programs.paged_block_step
+        calls = []
+
+        def failing(width):
+            fn = builder(width)
+
+            def run_then_fail(*args):
+                result = fn(*args)
+                calls.append(width)
+                if len(calls) != 3:  # it fails once
+                    return result
+                if when == "call":
+                    raise RuntimeError("injected device failure")
+                return (_Poisoned(), *result[1:])
+
+            return run_then_fail
+
+        eng.programs.paged_block_step = failing
+        go = _gated(eng)
+        futures = [eng.enqueue(_tokens(400 + i, 9)[None], 8) for i in range(2)]
+        go.set()
+        for future in futures:
+            with pytest.raises(E.PyGridError, match="engine error"):
+                future.result(timeout=60)
+        eng.programs.paged_block_step = builder
+        assert len(calls) == (3 if when == "call" else 4) and not eng._arrivals
+        tokens, masked = eng._last
+        assert tokens.shape == masked.shape == (1, 4) and masked.dtype == bool
+        for arr in (eng._k, eng._v, eng._pos, tokens, masked):
+            assert not arr.is_deleted()
+            assert not np.asarray(jnp.abs(arr).sum())
+        stats = eng.stats()
+        assert stats["live_slots"] == 0 and stats["queue_depth"] == 0
+        assert stats["kv_blocks_free"] == stats["kv_blocks_total"]
+        prompt = _tokens(410, 10)
+        out = eng.submit(prompt[None], 7, denoising_steps=2, timeout=300)
+        got = {k: np.asarray(v).tolist() for k, v in out.items()}
+        assert got == model.generate(weights, cfg, prompt, 7, 2, pad_to=PAD)
+        led = eng.ledger()
+        assert led["drained"] and led["balanced"], led
+    finally:
+        eng.close()
+
+
+def test_close_reads_the_block_forward_in_flight_and_fails_the_rest(
+    model, cfg, scfg, params, weights
+):
+    """Two rows in two slots, a quantum of one step. The first step makes
+    the short row's only block whole (all of its tokens scheduled: it
+    leaves its slot) and is the long row's first of many; the engine is
+    closed with that step's answer unread. The thread reads it before it
+    leaves, so the short row is answered, right; the long row fails typed;
+    nothing is left in flight and every page is back."""
+    eng = _engine(scfg, params, max_slots=2, slot_buckets=(2,), quantum=1)
+    stepped, step = threading.Event(), eng._step
+
+    def step_then_wait():
+        freed = step()
+        stepped.set()
+        deadline = time.monotonic() + 30
+        while eng._running and time.monotonic() < deadline:
+            time.sleep(0.001)
+        return freed
+
+    eng._step = step_then_wait
+    try:
+        go = _gated(eng)
+        short, long_ = _tokens(500, 8), _tokens(501, 9)
+        first = eng.enqueue(short[None], 4, denoising_steps=1)
+        second = eng.enqueue(long_[None], 12, denoising_steps=4)
+        go.set()
+        assert stepped.wait(60)
+        in_flight = [a for a in eng._arrivals if a.decode]
+        assert len(in_flight) == 1 and not first.done()
+    finally:
+        eng.close()
+    got = {k: np.asarray(v).tolist() for k, v in first.result(timeout=10).items()}
+    assert got == model.generate(weights, cfg, short, 4, 1, pad_to=PAD)
+    with pytest.raises(E.PyGridError, match="closed"):
+        second.result(timeout=10)
+    assert not eng._arrivals
+    led = eng.ledger()
+    assert led["drained"] and led["balanced"], led
 
 
 def test_typed_errors_at_the_engine(scfg, params):
