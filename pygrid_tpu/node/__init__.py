@@ -168,6 +168,8 @@ def create_app(
         # windows have data even when no one scrapes. Clamped: 0 or a
         # negative knob would make the tick task a hot loop.
         telemetry.profiler.MEMORY.start()
+        # the collector's pauses stall every thread, the engine's too
+        telemetry.gcwatch.start()
         # periodic engine snapshots (flight recorder §7): crash dumps
         # carry a before-the-crash trajectory — cycle accumulators and
         # serving stats every ~10 s under load, nothing when idle
@@ -215,6 +217,7 @@ def create_app(
         await asyncio.get_running_loop().run_in_executor(
             None, telemetry.recorder.stop_snapshots
         )
+        telemetry.gcwatch.stop()
 
     app.on_startup.append(_start_observability)
     app.on_cleanup.append(_stop_observability)

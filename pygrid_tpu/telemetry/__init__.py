@@ -20,6 +20,9 @@ through:
 - :mod:`pygrid_tpu.telemetry.loopclock` — a loop thread's time
   partitioned into named phases: seconds per phase on the bus, and one
   ``jax.profiler.TraceAnnotation`` per phase on the profiler's clock.
+- :mod:`pygrid_tpu.telemetry.gcwatch` — the cyclic collector's pauses
+  by generation (``process_gc_seconds``), a full collection also as a
+  ``gc.full`` annotation on the profiler's clock.
 - :mod:`pygrid_tpu.telemetry.profiler` — per-jit-callsite
   compile/execute timing (``GET /telemetry/programs``) and background
   device-memory gauges; off-switch ``PYGRID_PROFILER=off``.
@@ -37,6 +40,7 @@ loop's budget is < 2% over the bare wire path
 from __future__ import annotations
 
 from pygrid_tpu.telemetry import (  # noqa: F401
+    gcwatch,
     loopclock,
     profiler,
     recorder,
@@ -81,6 +85,11 @@ def export(exp) -> None:
         tensor_copy_count(),
         "tensor-buffer byte copies made by wire deserialization",
     )
+    for generation, snap in gcwatch.snapshots().items():
+        exp.histogram(
+            gcwatch.FAMILY, snap, family_help(gcwatch.FAMILY),
+            {"generation": str(generation)},
+        )
 
 
 def http_middleware():

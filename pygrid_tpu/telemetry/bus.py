@@ -78,17 +78,28 @@ class Histogram:
         self.sum += v
         self.count += 1
 
+    def merge(self, gained: Mapping[int, int], total: float) -> None:
+        """Take in observations a producer bucketed itself: ``gained``
+        maps an index into ``bounds`` (``bisect_left``, as ``observe``
+        finds it) to a count, ``total`` is the observations' sum."""
+        for index, n in gained.items():
+            self.counts[index] += n
+            self.count += n
+        self.sum += total
+
     def snapshot(self) -> dict:
         """``{"buckets": [(le, cumulative_count), ...], "sum", "count"}``
         with cumulative counts and a trailing ``+Inf`` bucket — exactly
-        what ``Exposition.histogram`` renders."""
+        what ``Exposition.histogram`` renders. The count is the buckets'
+        own total, taken from one copy of them: a reader that holds no
+        lock (``telemetry/gcwatch.py``) still gets a snapshot that agrees
+        with itself, at most one observation behind."""
         buckets = []
         running = 0
-        for le, c in zip(self.bounds, self.counts):
+        for le, c in zip((*self.bounds, float("inf")), list(self.counts)):
             running += c
             buckets.append((le, running))
-        buckets.append((float("inf"), running + self.counts[-1]))
-        return {"buckets": buckets, "sum": self.sum, "count": self.count}
+        return {"buckets": buckets, "sum": self.sum, "count": running}
 
 
 def _label_key(labels: dict) -> tuple:
@@ -128,6 +139,21 @@ _FAMILY_HELP: dict[str, str] = {
     "serving_loop_seconds_total": (
         "engine-thread seconds by phase (idle, admit, prefill, build, "
         "fetch, emit) — the phases partition the thread's time"
+    ),
+    "serving_loop_cpu_seconds_total": (
+        "engine-thread CPU seconds by phase (time.thread_time at the "
+        "phases' boundaries): wall less cpu in admit, build and emit is "
+        "time the thread waited for the GIL, a lock or a CPU; absent "
+        "where the host's thread clock ticks coarsely or reads dear"
+    ),
+    "serving_loop_phase_seconds": (
+        "length of each instance of an engine-thread phase; its sum is "
+        "serving_loop_seconds_total, its highest bucket the longest stretch"
+    ),
+    # telemetry/gcwatch.py, written at scrape
+    "process_gc_seconds": (
+        "one pause of the cyclic collector, by generation, process-wide: "
+        "a collection holds the GIL, so every thread stalls for it"
     ),
     "serving_dispatch_seconds": (
         "one decode dispatch (step or fused scan), from the later of its "
@@ -283,15 +309,37 @@ class TelemetryBus:
             self._counters[key] = self._counters.get(key, 0) + value
 
     def incr_many(
-        self, name: str, label: str, amounts: Mapping[str, float]
+        self,
+        name: str,
+        label: str,
+        amounts: Mapping[str, float],
+        *,
+        more: Iterable[tuple[str, Mapping[str, float]]] = (),
+        instances: tuple[str, Mapping[str, Mapping[int, int]]] | None = None,
     ) -> None:
         """Add ``amounts[v]`` to ``name{label=v}`` for every ``v`` under
         ONE hold of the lock — a producer that keeps its own sums (the
-        engine's loop clock) pays one lock a flush, not one a series."""
+        engine's loop clock) pays one lock a flush, not one a series.
+        ``more`` are further ``(name, amounts)`` counter families over
+        the same label. ``instances`` is ``(name, {v: gained})``: a
+        histogram family over the default bounds whose observations the
+        producer bucketed itself and whose sums are ``amounts``
+        (:meth:`Histogram.merge`), so the histogram's ``_sum`` and the
+        counter cannot part. All under the same hold."""
+        counters, histograms = self._counters, self._histograms
         with self._lock:
-            for value, amount in amounts.items():
-                key = self._admit(name, ((label, value),), self._counters)
-                self._counters[key] = self._counters.get(key, 0) + amount
+            for fam, sums in ((name, amounts), *more):
+                for value, amount in sums.items():
+                    key = self._admit(fam, ((label, value),), counters)
+                    counters[key] = counters.get(key, 0) + amount
+            if instances:
+                fam, series = instances
+                for value, gained in series.items():
+                    key = self._admit(fam, ((label, value),), histograms)
+                    hist = histograms.get(key)
+                    if hist is None:
+                        hist = histograms[key] = Histogram()
+                    hist.merge(gained, amounts[value])
 
     def observe(
         self,

@@ -91,11 +91,19 @@ def _dispatches(**labels):
 
 def test_clock_sums_phases_and_flushes_under_one_lock(monkeypatch):
     flushed = []
+
+    def incr_many(name, label, amounts, *, more, instances):
+        # copies: the clock empties its own sums once they are handed over
+        family, gained = instances
+        flushed.append((name, label, dict(amounts), {
+            "more": [(f, dict(sums)) for f, sums in more],
+            "instances": (family, {p: dict(g) for p, g in gained.items()}),
+        }))
+
+    monkeypatch.setattr(telemetry.loopclock.bus, "incr_many", incr_many)
+    # whatever the probe makes of this machine's thread clock
     monkeypatch.setattr(
-        telemetry.loopclock.bus, "incr_many",
-        lambda name, label, amounts: flushed.append(
-            (name, label, dict(amounts))
-        ),
+        telemetry.loopclock, "cpu_clock", lambda: time.thread_time
     )
     clock = LoopClock("unit_loop_seconds_total", "unit.")
     t0 = time.perf_counter()
@@ -107,8 +115,15 @@ def test_clock_sums_phases_and_flushes_under_one_lock(monkeypatch):
     clock.enter("a")
     clock.flush()
     assert len(flushed) == 1
-    name, label, sums = flushed[0]
+    name, label, sums, beside = flushed[0]
     assert (name, label) == ("unit_loop_seconds_total", "phase")
+    # what the clock gathers beside the seconds rides in the same call
+    assert sorted(beside) == ["instances", "more"]
+    ((family, cpu),) = beside["more"]
+    assert family == "unit_loop_cpu_seconds_total" and sorted(cpu) == ["a", "b"]
+    family, gained = beside["instances"]
+    assert family == "unit_loop_phase_seconds"
+    assert {p: sum(g.values()) for p, g in gained.items()} == {"a": 1, "b": 1}
     # the open phase is not handed over before it ends
     assert sorted(sums) == ["a", "b"]
     assert sums["a"] >= 0.02 and sums["b"] >= 0.01
@@ -370,3 +385,399 @@ def test_profile_carries_the_phases_with_their_arguments(params, tmp_path):
     # every launched program's answer is fetched once, a dispatch late
     fetches = [ev for ev in events if ev.name == "engine.fetch"]
     assert len(fetches) == len(builds) + len(prefills)
+
+
+# ── the host account: instances, CPU seconds, the collector ──────────────
+
+
+class _CountingLock:
+    """A lock that counts how often it was taken."""
+
+    def __init__(self):
+        self._lock, self.taken = threading.Lock(), 0
+
+    def __enter__(self):
+        self._lock.acquire()
+        self.taken += 1
+
+    def __exit__(self, *exc):
+        self._lock.release()
+
+
+def _instances(family):
+    """{phase: snapshot} of a clock's histogram of instances."""
+    return {
+        dict(lab)["phase"]: snap
+        for (n, lab), snap in telemetry.histograms().items()
+        if n == family
+    }
+
+
+def _script(monkeypatch, wall, cpu=None):
+    """Give the clock's module scripted clocks: the readings it will
+    get, in order. ``cpu`` None is a host whose thread clock gives no
+    data: reading it at all fails the test."""
+    import types
+
+    wall_at, cpu_at = iter(wall), iter(cpu or ())
+
+    def never():
+        raise AssertionError("no CPU clock here: nothing may read one")
+
+    monkeypatch.setattr(telemetry.loopclock, "time", types.SimpleNamespace(
+        perf_counter=lambda: next(wall_at), thread_time=never,
+    ))
+    monkeypatch.setattr(
+        telemetry.loopclock, "cpu_clock",
+        lambda: None if cpu is None else (lambda: next(cpu_at)),
+    )
+
+
+def test_each_boundarys_readings_go_to_the_phase_that_was_open(monkeypatch):
+    lock = _CountingLock()
+    monkeypatch.setattr(telemetry.BUS, "_lock", lock)
+    # a from 0 to 1 (0.25 of it on the CPU), b to 1.5 (all of it), a to 3.5
+    # (none), b to 3.502, then the thread leaves
+    _script(
+        monkeypatch, wall=[0.0, 1.0, 1.5, 3.5, 3.502],
+        cpu=[10.0, 10.25, 10.75, 10.75, 10.752],
+    )
+    clock = LoopClock("unit_loop_seconds_total", "unit.")
+    clock.enter("a")
+    clock.enter("b")
+    clock.enter("a")
+    clock.enter("b")
+    clock.flush()
+    # three families went over under one hold of the bus lock
+    assert lock.taken == 1
+    clock.stop()
+    assert lock.taken == 2
+    wall = {p: _counter("unit_loop_seconds_total", phase=p) for p in "ab"}
+    cpu = {p: _counter("unit_loop_cpu_seconds_total", phase=p) for p in "ab"}
+    assert wall == {"a": 3.0, "b": pytest.approx(0.502)}
+    assert cpu == {"a": 0.25, "b": pytest.approx(0.502)}
+    snaps = _instances("unit_loop_phase_seconds")
+    # one observation an instance; the open phase is closed by stop()
+    assert {p: s["count"] for p, s in snaps.items()} == {"a": 2, "b": 2}
+    for phase, snap in snaps.items():
+        assert snap["sum"] == pytest.approx(wall[phase], rel=1e-12)
+
+    def gained(phase):
+        """The buckets (by upper bound) that hold an instance."""
+        buckets = snaps[phase]["buckets"]
+        return [
+            le for (le, n), (_, below) in zip(buckets, [(0, 0)] + buckets)
+            if n > below
+        ]
+
+    # 1 s lies in (0.5, 1], 2 s in (1, 2.5]; 0.5 s in (0.25, 0.5], 2 ms
+    # in (1, 2.5] ms: the highest is the longest single stretch
+    assert gained("a") == [1.0, 2.5] and gained("b") == [0.0025, 0.5]
+
+
+def test_a_host_without_a_cpu_clock_reads_none_and_has_no_family(monkeypatch):
+    _script(monkeypatch, wall=[0.0, 1.0, 1.5], cpu=None)
+    clock = LoopClock("unit_loop_seconds_total", "unit.")
+    clock.enter("a")
+    clock.enter("b")
+    clock.stop()
+    names = {n for (n, _) in telemetry.counters()}
+    assert "unit_loop_seconds_total" in names
+    # absent, not zero
+    assert "unit_loop_cpu_seconds_total" not in names
+    assert {p: s["count"] for p, s in _instances("unit_loop_phase_seconds").items()} == {
+        "a": 1, "b": 1,
+    }
+
+
+@pytest.mark.parametrize(
+    "tick, cost, kept",
+    [
+        (1e-9, 0.4e-6, True),  # a plain kernel: a system call, nanoseconds
+        (1e-2, 0.4e-6, False),  # charged in scheduler ticks
+        (1e-9, 6e-6, False),  # fine, but an emulated call a boundary
+        (1e-2, 6e-6, False),  # a TPU v5e's host
+    ],
+    ids=["fine-cheap", "ticks", "dear", "ticks-dear"],
+)
+def test_the_probe_keeps_a_cpu_clock_only_where_it_gives_data(tick, cost, kept):
+    now = [0.0]
+
+    def read():  # a thread that spins: its CPU time is the time, in ticks
+        now[0] += cost
+        return now[0] // tick * tick
+
+    probe = telemetry.loopclock.probe_cpu_clock
+    assert probe(read, wall=lambda: now[0]) is (read if kept else None)
+
+
+def test_one_interrupted_batch_does_not_cost_the_process_its_cpu_clock():
+    now, reads = [0.0], [0]
+
+    def read():
+        reads[0] += 1
+        # the machine takes the thread away for 5 ms in the first batch
+        now[0] += 5e-3 if reads[0] == 7 else 0.4e-6
+        return now[0]
+
+    assert telemetry.loopclock.probe_cpu_clock(read, wall=lambda: now[0]) is read
+
+
+def test_the_real_clocks_tell_a_sleep_from_a_spin_where_there_is_one():
+    """The one test on the machine's own clocks, and loose: the rest of
+    the account is arithmetic, held above on scripted ones."""
+    clock = LoopClock("unit_loop_seconds_total", "unit.")
+    clock.enter("sleep")
+    time.sleep(0.05)
+    clock.enter("spin")
+    until = time.perf_counter() + 0.05
+    while time.perf_counter() < until:
+        pass
+    clock.stop()
+    wall = {p: _counter("unit_loop_seconds_total", phase=p) for p in ("sleep", "spin")}
+    assert wall["sleep"] >= 0.05 and wall["spin"] >= 0.05
+    snaps = _instances("unit_loop_phase_seconds")
+    assert {p: s["count"] for p, s in snaps.items()} == {"sleep": 1, "spin": 1}
+    names = {n for (n, _) in telemetry.counters()}
+    if telemetry.loopclock.cpu_clock() is None:
+        assert "unit_loop_cpu_seconds_total" not in names
+    else:
+        cpu = {p: _counter("unit_loop_cpu_seconds_total", phase=p) for p in ("sleep", "spin")}
+        assert cpu["sleep"] < cpu["spin"]
+
+
+def test_merged_instances_keep_the_cardinality_guard():
+    bus = TelemetryBus(max_labelsets=1)
+    bus.incr_many(
+        "fam_total", "phase", {"a": 1.0, "b": 2.0},
+        more=[("cpu_total", {"a": 0.5})],
+        instances=("fam_seconds", {"a": {3: 2}, "b": {4: 1}}),
+    )
+    bus.incr_many(
+        "fam_total", "phase", {"a": 700.0},
+        instances=("fam_seconds", {"a": {3: 1, 27: 1}}),
+    )
+    assert bus.counters()[("cpu_total", (("phase", "a"),))] == 0.5
+    hists = bus.histograms()
+    a = hists[("fam_seconds", (("phase", "a"),))]
+    # the histogram's sum is the counter's: they cannot part
+    assert (a["count"], a["sum"]) == (4, 701.0)
+    assert bus.counters()[("fam_total", (("phase", "a"),))] == 701.0
+    # bucket 3 is (5 us, 10 us]; index 27 is past the last bound: +Inf
+    assert dict(a["buckets"])[1e-5] == 3 and a["buckets"][-1][1] == 4
+    assert a["buckets"][-2][1] == 3
+    # the second label set folded, as it would through observe()
+    other = hists[("fam_seconds", (("other", "true"),))]
+    assert (other["count"], other["sum"]) == (1, 2.0)
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["step", "fused"])
+def test_cpu_seconds_and_instances_over_an_engine_run(params, fused, monkeypatch):
+    import itertools
+
+    # a CPU clock that gains one second a reading: a phase's CPU seconds
+    # are then the boundaries that closed it, whatever the machine does
+    reading = itertools.count()
+    monkeypatch.setattr(
+        telemetry.loopclock, "cpu_clock", lambda: lambda: float(next(reading))
+    )
+    eng = _engine(params, max_slots=4, slot_buckets=(1, 2, 4), fused=fused)
+    go, wall = _gate(eng)
+    entered = {}
+    enter = eng._clock.enter
+
+    def counted(phase, **args):
+        entered[phase] = entered.get(phase, 0) + 1
+        enter(phase, **args)
+
+    eng._clock.enter = counted
+    go.set()
+    try:
+        eng.submit(PROMPT[None, :], 6, timeout=120)
+        time.sleep(0.05)
+        for f in [eng.enqueue(PROMPT[None, :], n) for n in (3, 12)]:
+            f.result(120)
+    finally:
+        eng.close()
+    seconds = {p: _counter("serving_loop_seconds_total", phase=p) for p in PHASES}
+    assert sum(seconds.values()) == pytest.approx(wall[0], rel=0.02)
+    assert set(entered) == set(PHASES)
+    cpu = {p: _counter("serving_loop_cpu_seconds_total", phase=p) for p in PHASES}
+    assert cpu == entered
+    snaps = _instances("serving_loop_phase_seconds")
+    assert {p: s["count"] for p, s in snaps.items()} == entered
+    for phase, snap in snaps.items():
+        assert snap["sum"] == pytest.approx(seconds[phase], rel=1e-9)
+
+
+def _gc_counts(generation):
+    snap = telemetry.gcwatch.snapshots().get(generation)
+    return (0, 0.0) if snap is None else (snap["count"], snap["sum"])
+
+
+def test_a_collection_is_one_observation_and_takes_no_bus_lock(monkeypatch):
+    import gc
+
+    lock = _CountingLock()
+    monkeypatch.setattr(telemetry.BUS, "_lock", lock)
+    telemetry.gcwatch.start()
+    telemetry.gcwatch.start()  # a second node in the process
+    try:
+        assert gc.callbacks.count(telemetry.gcwatch._on_gc) == 1
+        full, young = _gc_counts(2), _gc_counts(0)
+        gc.collect()
+        gc.collect(0)
+        assert _gc_counts(2)[0] == full[0] + 1 and _gc_counts(2)[1] > full[1]
+        assert _gc_counts(0)[0] == young[0] + 1
+        telemetry.gcwatch.stop()
+        assert telemetry.gcwatch._on_gc in gc.callbacks  # one user left
+    finally:
+        telemetry.gcwatch.stop()
+    assert telemetry.gcwatch._on_gc not in gc.callbacks
+    assert lock.taken == 0
+    seen = _gc_counts(2)
+    gc.collect()  # nobody watches any more; what was counted stays
+    assert _gc_counts(2) == seen
+
+
+def test_a_hook_taken_down_inside_a_full_collection_leaves_nothing_open():
+    watch = telemetry.gcwatch
+    full = {"generation": watch.OLDEST}
+    watch.start()
+    try:
+        watch._on_gc("start", full)  # a full collection begins ...
+        assert watch._span is not None
+        watch.stop()  # ... and its "stop" finds no hook to call
+        assert watch._span is None
+        watch.start()
+        seen = _gc_counts(watch.OLDEST)
+        # a "stop" whose "start" the new hook never saw: no length, and
+        # no stale annotation to close
+        watch._on_gc("stop", full)
+        assert _gc_counts(watch.OLDEST) == seen and watch._span is None
+    finally:
+        watch.stop()
+        watch.stop()  # one too many is harmless
+
+
+def test_the_node_hangs_the_collectors_hook_and_takes_it_down():
+    import asyncio
+    import gc
+
+    from aiohttp import web
+
+    from pygrid_tpu.node import create_app
+    from pygrid_tpu.telemetry import promtext
+    from pygrid_tpu.utils.metrics import Exposition
+
+    async def life():
+        runner = web.AppRunner(create_app("gc-node"))
+        await runner.setup()
+        try:
+            assert telemetry.gcwatch._on_gc in gc.callbacks
+            before = _gc_counts(2)
+            gc.collect()
+            exp = Exposition()
+            telemetry.export(exp)
+            return before, promtext.parse(exp.render())
+        finally:
+            await runner.cleanup()
+
+    before, families = asyncio.run(life())
+    assert telemetry.gcwatch._on_gc not in gc.callbacks
+    pauses = families["pygrid_process_gc_seconds"]
+    assert pauses.type == "histogram" and "collector" in pauses.help
+    full = {
+        s[0]: s[2] for s in pauses.samples
+        if s[1].get("generation") == "2" and "le" not in s[1]
+    }
+    # the forced one, and any the collector chose to make beside it
+    assert full["pygrid_process_gc_seconds_count"] >= before[0] + 1
+    assert full["pygrid_process_gc_seconds_sum"] > before[1]
+    assert {s[1]["generation"] for s in pauses.samples} == {"0", "1", "2"}
+
+
+def test_the_host_account_reaches_metrics_and_parses_strictly(
+    params, monkeypatch
+):
+    from pygrid_tpu.telemetry import promtext
+    from pygrid_tpu.utils.metrics import Exposition
+
+    # half of every second on the CPU, whatever this machine's clock is
+    monkeypatch.setattr(
+        telemetry.loopclock, "cpu_clock",
+        lambda: lambda: 0.5 * time.perf_counter(),
+    )
+    eng = _engine(params, max_slots=4, slot_buckets=(1, 2, 4))
+    try:
+        eng.submit(PROMPT[None, :], 10, timeout=120)
+    finally:
+        eng.close()
+    exp = Exposition()
+    telemetry.export(exp)
+    families = promtext.parse(exp.render())
+    wall = families["pygrid_serving_loop_seconds_total"]
+    cpu = families["pygrid_serving_loop_cpu_seconds_total"]
+    assert cpu.type == "counter" and "CPU" in cpu.help
+    assert {s[1]["phase"] for s in cpu.samples} == {
+        s[1]["phase"] for s in wall.samples
+    }
+    # the boundaries' readings telescope: the sum over the phases is the
+    # clock's own gain from the first boundary to the last
+    assert sum(s[2] for s in cpu.samples) == pytest.approx(
+        0.5 * sum(s[2] for s in wall.samples), abs=0.01
+    )
+    instances = families["pygrid_serving_loop_phase_seconds"]
+    assert instances.type == "histogram" and "instance" in instances.help
+    sums = {
+        s[1]["phase"]: s[2] for s in instances.samples
+        if s[0].endswith("_sum")
+    }
+    for s in wall.samples:
+        # the histogram's sum is written with six digits, the counter whole
+        assert sums[s[1]["phase"]] == pytest.approx(s[2], rel=1e-5)
+    for name in (
+        "serving_loop_cpu_seconds_total", "serving_loop_phase_seconds",
+        "process_gc_seconds",
+    ):
+        assert not telemetry.bus.family_help(name).startswith(
+            "pygrid telemetry metric"
+        )
+
+
+def test_profile_carries_a_full_collection_beside_the_phases(params, tmp_path):
+    import gc
+
+    from jax.profiler import ProfileData
+
+    eng = _engine(params, max_slots=4, slot_buckets=(1, 2, 4), fused=False)
+    telemetry.gcwatch.start()
+    try:
+        eng.submit(PROMPT[None, :], 2, timeout=120)  # compile outside
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            future = eng.enqueue(PROMPT[None, :], 4)
+            gc.collect()
+            gc.collect(0)  # a young collection is no event
+            future.result(120)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        telemetry.gcwatch.stop()
+        eng.close()
+    (xplane,) = tmp_path.rglob("*.xplane.pb")
+    events = [
+        ev
+        for plane in ProfileData.from_file(str(xplane)).planes
+        for line in plane.lines
+        for ev in line.events
+        if ev.name == "gc.full" or ev.name.startswith("engine.")
+    ]
+    assert {"engine.build", "engine.fetch", "engine.emit"} <= {
+        ev.name for ev in events
+    }
+    (full,) = [ev for ev in events if ev.name == "gc.full"]
+    assert full.duration_ns > 0
