@@ -45,32 +45,58 @@ products take one (:func:`_dot_split`).
 **The decays stay in log space, float32, and no exponential of a
 positive number is ever formed.** ``exp(G_i - G_j)`` does not factor into
 ``exp(G_i) exp(-G_j)`` safely (a channel that decays fast makes the second
-overflow within a few positions), so the chunk is cut into sub-chunks of
-``SUB`` positions: between two sub-chunks the exponent is split at the
-boundary ``R`` between them, ``(G_i - R) + (R - G_j)``, both parts <= 0,
-and the sum over channels is one matrix product; inside a sub-chunk every
-pair's ``exp(G_i - G_j)`` is formed itself (``SUB`` elementwise passes)
-and the matrix unit only does the summing.
+overflow within a few positions). The pairs ``j < i`` of a chunk are taken
+level by level instead, the doubling's own levels: at level ``s`` the
+pairs of every aligned block of ``2 s`` rows with ``i`` in its second half
+and ``j`` in its first (each pair falls in exactly one level), the
+exponent split between the halves, ``exp(G_i - G_j) = exp(g_m + .. + g_i)
+exp(g_{j+1} + .. + g_{m-1})`` with ``m`` the second half's first row: both
+factors are sums of the block's own non-positive terms, so a level is one
+exponential a row and ONE matrix product, ``[k|q * E] (kb * E)^T``, of which
+the level's pairs are kept; what it keeps of ``N`` is exactly the ``C`` the
+doubling joins two inverted blocks with. Those sums come from a ladder of
+block totals (:func:`_ladder`: ``log2(CHUNK)`` shifted additions, in the
+kernels, no ``cumsum`` outside them) and never from a difference of two
+running sums, so a small exponent keeps its digits however far a chunk
+has decayed.
 
-The chunk's work is one function of plain 2-D array operations
-(:func:`_chunk`), used twice: as the body of the Pallas kernel
-``kda_chunk`` (a grid step a head a chunk, the state resident in VMEM
-across a head's chunks, chunks past the prompt's true length skipped) on
-a TPU at lane-aligned head sizes, and under ``vmap``/``scan`` through
-XLA everywhere else (the CPU, tier-1's tiny widths): the same
-mathematics, as :func:`pygrid_tpu.models.moe.grouped_eligible` does it
-for the expert kernel. A padded position must arrive with ``g = 0`` and
-``b = 0``: it then neither decays the state nor feeds it.
+The chunk's work is two functions of plain 2-D array operations, each
+used twice. :func:`_solver` is what no state enters (the levels' products,
+``B``, the inverse), for the 128 rows of two chunks at once, side by side
+on the diagonal of full 128 x 128 tiles; :func:`_advance` is a chunk's
+three steps against the state (``U``, ``O``, ``S_C``). On a TPU at
+lane-aligned head sizes they are the bodies of two Pallas kernels,
+``kda_chunk_solver`` (every grid step on its own) and ``kda_chunk_state``
+(the pass over a head's chunks, the state resident in VMEM, chunks past
+the prompt's true length skipped by both); under ``vmap``/``scan`` through
+XLA everywhere else (the CPU, tier-1's tiny widths): the same mathematics,
+as :func:`pygrid_tpu.models.moe.grouped_eligible` does it for the expert
+kernel. Both are generators that ``yield`` between their dependent
+products, and a grid step advances four of them in turn
+(:func:`_lockstep`): the compiler keeps to program order, and a chain of
+ten dependent products a group leaves the matrix units idle unless another
+group's products are emitted between them. A padded position must arrive
+with ``g = 0`` and ``b = 0``: it then neither decays the state nor feeds
+it.
 
 Matrix products take their operands in ``mm_dtype`` (the served weights'
 type: bfloat16 on the chip) and accumulate in float32; in float32 they
 run at full precision. The state, the decays and every elementwise step
 are float32.
+
+Measured on a v5e (PERF.md §5 item 0, PR 42; 8,192 positions, 64 heads of
+128, a layer): the one kernel this replaces took 19.7 ms, 11.8 of them the
+inverse's thirty dependent 64 x 64 passes a chunk and 3.0 the sixteen
+passes a sub-chunk that formed every pair's decay itself, and XLA's
+``cumsum`` beside it 4.7; the two kernels take 4.5 + 2.6. Emitted a group
+after another they take 9.5 + 4.0: the interleaving is worth as much as
+the arithmetic.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -80,8 +106,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 #: positions a chunk: one triangular system, one state update
 CHUNK = 64
-#: positions a sub-chunk: every pair's decay formed itself inside one
-SUB = 16
+#: positions the state-free part handles at once: two chunks side by
+#: side on the diagonal of one 128 x 128 tile
+GROUP = 2 * CHUNK
 
 _NN = (((1,), (0,)), ((), ()))
 _NT = (((1,), (1,)), ((), ()))
@@ -117,69 +144,6 @@ def _dot_split(a, b, dims=_NN):
     return one(a_hi, b_hi) + (one(a_hi, b_lo) + one(a_lo, b_hi))
 
 
-def _chunk(q, k, kb, v, G, St, dot, exact):
-    """One chunk of one head (module docstring). ``q, k, kb, G`` [C, d_k]
-    (``kb = b * k``; ``G`` the inclusive sum of the chunk's log-decays),
-    ``v`` [C, d_v], ``St`` [d_v, d_k] the state before the chunk, all
-    float32. Returns (``O`` [C, d_v], the state after)."""
-    C = q.shape[0]
-    row = lax.broadcasted_iota(jnp.int32, (SUB, C), 0)
-    col = lax.broadcasted_iota(jnp.int32, (SUB, C), 1)
-    decay = jnp.exp(G)
-    rhs = v - dot(k * decay, St, _NT)
-    n_rows, b_rows = [], []
-    for lo in range(0, C, SUB):
-        Gr, kr, qr = G[lo : lo + SUB], k[lo : lo + SUB], q[lo : lo + SUB]
-        # inside the sub-chunk: pair (i, j)'s own exp(G_i - G_j), a pass
-        # a column j; the product with kb sums over the channels and the
-        # column of Z that is wanted of pass j is column lo + j
-        passes = []
-        for j in range(SUB):
-            e = jnp.exp(jnp.minimum(Gr - Gr[j : j + 1], 0.0))
-            passes += [kr * e, qr * e]
-        Z = dot(jnp.concatenate(passes, 0), kb, _NT)  # [SUB * 2 SUB, C]
-        n_a = jnp.zeros((SUB, C), jnp.float32)
-        b_a = jnp.zeros((SUB, C), jnp.float32)
-        for j in range(SUB):
-            at = col == lo + j
-            z = Z[2 * SUB * j : 2 * SUB * (j + 1)]
-            n_a = n_a + jnp.where(at, z[:SUB], 0.0)
-            b_a = b_a + jnp.where(at, z[SUB:], 0.0)
-        inside = col >= lo
-        n_a = jnp.where(inside & (col < lo + row), n_a, 0.0)
-        b_a = jnp.where(inside & (col <= lo + row), b_a, 0.0)
-        if lo:
-            # earlier sub-chunks: the exponent split at the boundary
-            R = G[lo - 1 : lo]
-            e = jnp.exp(Gr - R)
-            before = kb * jnp.exp(jnp.minimum(R - G, 0.0))
-            cross = dot(jnp.concatenate([kr * e, qr * e], 0), before, _NT)
-            n_a = n_a + jnp.where(inside, 0.0, cross[:SUB])
-            b_a = b_a + jnp.where(inside, 0.0, cross[SUB:])
-        n_rows.append(n_a)
-        b_rows.append(b_a)
-    N = jnp.concatenate(n_rows, 0)
-    B = jnp.concatenate(b_rows, 0)
-    # (I + N)^-1 by doubling the inverted diagonal blocks
-    row = lax.broadcasted_iota(jnp.int32, (C, C), 0)
-    col = lax.broadcasted_iota(jnp.int32, (C, C), 1)
-    # the blocks of N that join two inverted blocks of s rows
-    joins = lambda s: jnp.where(  # noqa: E731
-        ((row // s) % 2 == 1) & (col // s == row // s - 1), N, 0.0
-    )
-    # blocks of one row are their own inverses: pairs need no product
-    inverse = (row == col).astype(jnp.float32) - joins(1)
-    s = 2
-    while s < C:
-        inverse = inverse - exact(inverse, exact(joins(s), inverse))
-        s *= 2
-    U = dot(inverse, rhs)
-    O = dot(q * decay, St, _NT) + dot(B, U)
-    last = G[C - 1 : C]
-    St = St * jnp.exp(last) + dot(U.T, kb * jnp.exp(last - G))
-    return O, St
-
-
 def _exact(mm_dtype):
     """The product the triangular inverse is built with."""
     if jnp.dtype(mm_dtype) == jnp.float32:
@@ -187,71 +151,231 @@ def _exact(mm_dtype):
     return _dot_split
 
 
-def _kernel(len_ref, q_ref, k_ref, kb_ref, v_ref, g_ref, o_ref, s_ref, *,
-            mm_dtype):
+def _ladder(g):
+    """The sums of log-decays that the chunks of ``g`` [n CHUNK, d_k] need,
+    block size by block size: for ``s = 1, 2, 4, .. CHUNK`` yields ``(s,
+    pre, suf, tot)``, a row's sum over its own aligned block of ``s`` rows
+    up to and with itself, after itself, and whole. Each is a sum of the
+    block's own (non-positive) terms and nothing else: no difference of
+    two long sums is ever taken, so a small exponent keeps its digits
+    however far the chunk has decayed."""
+    at = lax.broadcasted_iota(jnp.int32, g.shape, 0)
+    pre, suf, tot, s = g, jnp.zeros_like(g), g, 1
+    while True:
+        yield s, pre, suf, tot
+        if s == CHUNK:
+            return
+        odd = (at & s) != 0
+        # a block of 2 s rows: its first half's total joins the second
+        # half's sums from the start, the second's the first's to the end
+        up, down = jnp.roll(tot, s, 0), jnp.roll(tot, -s, 0)
+        pre = pre + jnp.where(odd, up, 0.0)
+        suf = suf + jnp.where(odd, 0.0, down)
+        tot = tot + jnp.where(odd, up, down)
+        s *= 2
+
+
+def _lockstep(tasks):
+    """The results of generators that do independent work, each advanced
+    one stage (to its next ``yield``) in turn: the stages of several heads
+    or groups are then emitted side by side, and a scheduler that keeps to
+    program order has one's products to run while another's are on
+    their way."""
+    out, live = [None] * len(tasks), dict(enumerate(tasks))
+    while live:
+        for i, task in list(live.items()):
+            try:
+                next(task)
+            except StopIteration as stop:
+                out[i] = stop.value
+                del live[i]
+    return out
+
+
+def _solver(q, k, kb, g, dot, exact):
+    """What a chunk needs that no state enters, for the ``GROUP`` rows of
+    two chunks at once (module docstring). ``q, k, kb, g`` [GROUP, d_k]
+    float32 (``kb = b * k``, ``g`` the log-decays). A generator
+    (:func:`_lockstep`) that returns ``[GROUP, 2 CHUNK]``: a row's
+    ``CHUNK`` entries of its chunk's ``(I + N)^-1``, then its entries of
+    ``B``."""
+    R = GROUP
+    at = lax.broadcasted_iota(jnp.int32, q.shape, 0)
+    row = lax.broadcasted_iota(jnp.int32, (R, R), 0)
+    col = lax.broadcasted_iota(jnp.int32, (R, R), 1)
+    B, inverse, bit = jnp.zeros((R, R), jnp.float32), None, 0
+    for s, pre, suf, _ in _ladder(g):
+        if s == CHUNK:
+            break
+        # pairs (i, j) of one block of 2 s rows, i in its second half and j
+        # in its first, split between the halves: exp(G_i - G_j) is i's
+        # decay from its half's start times j's to its half's end. One
+        # exponent a row, the left factor's where i lies, the right one's
+        # where j does
+        E = jnp.exp(jnp.where((at & s) != 0, pre, suf))
+        Z = dot(jnp.concatenate([k * E, q * E], 0), kb * E, _NT)  # [2 R, R]
+        yield
+        pair = ((row & s) != 0) & ((row >> bit) - (col >> bit) == 1)
+        join = jnp.where(pair, Z[:R], 0.0)
+        B = jnp.where(pair, Z[R:], B)
+        # the inverted diagonal blocks of s rows give those of 2 s
+        if inverse is None:
+            inverse = (row == col).astype(jnp.float32) - join
+        else:
+            joined = exact(join, inverse)
+            yield
+            inverse = inverse - exact(inverse, joined)
+            yield
+        bit += 1
+    B = jnp.where(row == col, dot(q, kb, _NT), B)
+    # both are zero outside the two chunks' own squares: fold them
+    fold = lambda m: m + jnp.roll(m, CHUNK, 1)  # noqa: E731
+    return jnp.where(col < CHUNK, fold(inverse), fold(B))
+
+
+def _advance(q, k, kb, v, g, AB, St, dot):
+    """One chunk of one head against the state. ``q, k, kb, g`` [CHUNK,
+    d_k], ``v`` [CHUNK, d_v], ``AB`` the chunk's rows of :func:`_solver`,
+    ``St`` [d_v, d_k] the state before the chunk, all float32. A generator
+    (:func:`_lockstep`) that returns (``O`` [CHUNK, d_v], the state
+    after)."""
+    C = CHUNK
+    *_, (_, pre, suf, tot) = _ladder(g)
+    decay = jnp.exp(pre)
+    read = dot(jnp.concatenate([k * decay, q * decay], 0), St, _NT)
+    yield
+    rhs = v - read[:C]
+    none = jnp.zeros_like(rhs)
+    # AB's first half multiplies the upper rows, its second the lower
+    U = dot(AB, jnp.concatenate([rhs, none], 0))
+    yield
+    O = read[C:] + dot(AB, jnp.concatenate([none, U], 0))
+    St = St * jnp.exp(tot[:1]) + dot(U.T, kb * jnp.exp(suf))
+    return O, St
+
+
+#: (rows, heads) a grid step of each kernel holds
+_SOLVER_BLOCK = (GROUP, 4)
+_STATE_BLOCK = (2 * CHUNK, 4)
+
+
+def _solver_kernel(len_ref, q_ref, k_ref, kb_ref, g_ref, ab_ref, *, heads,
+                   mm_dtype):
+    rows, dk = q_ref.shape[0], q_ref.shape[1] // heads
+
+    # rows past the prompt are never read: kda_chunk_state skips them
+    @pl.when(pl.program_id(1) * rows < len_ref[0])
+    def _():
+        # every (head, group) is on its own
+        places = [
+            (slice(lo, lo + GROUP), h)
+            for h in range(heads) for lo in range(0, rows, GROUP)
+        ]
+        groups = _lockstep([
+            _solver(
+                *(ref[at, h * dk : (h + 1) * dk]
+                  for ref in (q_ref, k_ref, kb_ref, g_ref)),
+                _dot(mm_dtype), _exact(mm_dtype),
+            )
+            for at, h in places
+        ])
+        for (at, h), AB in zip(places, groups):
+            ab_ref[at, h * GROUP : (h + 1) * GROUP] = AB
+
+
+def _state_kernel(len_ref, q_ref, k_ref, kb_ref, v_ref, g_ref, ab_ref, o_ref,
+                  s_ref, *, heads, mm_dtype):
     c = pl.program_id(1)
+    rows = q_ref.shape[0]
+    dk, dv = q_ref.shape[1] // heads, v_ref.shape[1] // heads
+
+    ks = [slice(h * dk, (h + 1) * dk) for h in range(heads)]
+    vs = [slice(h * dv, (h + 1) * dv) for h in range(heads)]
 
     @pl.when(c == 0)
     def _():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    live = c * CHUNK < len_ref[0]
+    for lo in range(0, rows, CHUNK):
+        live = c * rows + lo < len_ref[0]
+        at = slice(lo, lo + CHUNK)
 
-    @pl.when(live)
-    def _():
-        O, St = _chunk(
-            q_ref[...], k_ref[...], kb_ref[...], v_ref[...], g_ref[...],
-            s_ref[0], _dot(mm_dtype), _exact(mm_dtype),
-        )
-        o_ref[...] = O
-        s_ref[0] = St
+        @pl.when(live)
+        def _():
+            done = _lockstep([
+                _advance(
+                    q_ref[at, ks[h]], k_ref[at, ks[h]], kb_ref[at, ks[h]],
+                    v_ref[at, vs[h]], g_ref[at, ks[h]],
+                    ab_ref[at, h * GROUP : (h + 1) * GROUP], s_ref[h],
+                    _dot(mm_dtype),
+                )
+                for h in range(heads)
+            ])
+            for h, (O, St) in enumerate(done):
+                o_ref[at, vs[h]] = O
+                s_ref[h] = St
 
-    @pl.when(jnp.logical_not(live))
-    def _():
-        # all padding: nothing moves the state; the rows must still be
-        # written (what lies in the buffer may not be a number)
-        o_ref[...] = jnp.zeros_like(o_ref)
+        @pl.when(jnp.logical_not(live))
+        def _():
+            # all padding: nothing moves the state; the rows must still
+            # be written (what lies in the buffer may not be a number)
+            o_ref[at, :] = jnp.zeros((CHUNK, o_ref.shape[1]), o_ref.dtype)
 
 
-def _chunk_call(q, k, kb, v, G, length, heads, mm_dtype, interpret):
+def _chunk_call(q, k, kb, v, g, length, heads, mm_dtype, interpret):
     """The Pallas form over ``[P, heads * d]`` arrays, ``P`` a multiple of
-    ``CHUNK``: (``O`` [P, heads * d_v], states [heads, d_v, d_k]). (Two
-    and four heads a grid step were tried on the chip: 3% and 4% off a
-    layer's time, so the products' throughput bounds a step, not their
-    waits: PERF.md §6, PR 36.)"""
+    ``GROUP``: (``O`` [P, heads * d_v], states [heads, d_v, d_k]). What
+    ``kda_chunk_solver`` hands ``kda_chunk_state`` is one more array of
+    ``q``'s size. (Measured, PERF.md §6, PR 42: four groups or heads a grid
+    step in lockstep; two take 30% and 45% longer, eight gain nothing.)"""
     P = q.shape[0]
     dk, dv = q.shape[1] // heads, v.shape[1] // heads
+    length = jnp.reshape(length, (1,)).astype(jnp.int32)
 
-    def rows(width):
-        return pl.BlockSpec((CHUNK, width), lambda h, c, length: (c, h))
+    def call(kernel, name, block, widths, out_specs, out_shape, order):
+        rows, hb = math.gcd(block[0], P), math.gcd(block[1], heads)
+        spec = lambda w: pl.BlockSpec(  # noqa: E731
+            (rows, hb * w), lambda h, c, length: (c, h)
+        )
+        return pl.pallas_call(
+            functools.partial(kernel, heads=hb, mm_dtype=mm_dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(heads // hb, P // rows),
+                in_specs=[spec(w) for w in widths],
+                out_specs=out_specs(spec, hb),
+            ),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(dimension_semantics=order),
+            interpret=interpret,
+            name=name,
+        )
 
-    return pl.pallas_call(
-        functools.partial(_kernel, mm_dtype=mm_dtype),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(heads, P // CHUNK),
-            in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), rows(dk)],
-            out_specs=[
-                rows(dv),
-                # the same block for every chunk of a head: the state
-                # stays in VMEM until the head's last chunk
-                pl.BlockSpec((1, dv, dk), lambda h, c, length: (h, 0, 0)),
-            ],
-        ),
-        out_shape=[
+    AB = call(
+        _solver_kernel, "kda_chunk_solver", _SOLVER_BLOCK, [dk] * 4,
+        lambda spec, hb: spec(GROUP),
+        jax.ShapeDtypeStruct((P, heads * GROUP), jnp.float32),
+        ("parallel", "parallel"),
+    )(length, q, k, kb, g)
+    return call(
+        _state_kernel, "kda_chunk_state", _STATE_BLOCK,
+        [dk, dk, dk, dv, dk, GROUP],
+        lambda spec, hb: [
+            spec(dv),
+            # the same block for every chunk of a head: the state stays
+            # in VMEM until the head's last chunk
+            pl.BlockSpec((hb, dv, dk), lambda h, c, length: (h, 0, 0)),
+        ],
+        [
             jax.ShapeDtypeStruct((P, heads * dv), jnp.float32),
             jax.ShapeDtypeStruct((heads, dv, dk), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-        name="kda_chunk",
-    )(jnp.reshape(length, (1,)).astype(jnp.int32), q, k, kb, v, G)
+        ("parallel", "arbitrary"),
+    )(length, q, k, kb, v, g, AB)
 
 
 def chunk_eligible(dk: int, dv: int) -> bool:
-    """True where a prompt's chunks take the kernel: on a TPU, with head
+    """True where a prompt's chunks take the kernels: on a TPU, with head
     sizes that fill whole 128-lane rows."""
     return jax.default_backend() == "tpu" and dk % 128 == 0 and dv % 128 == 0
 
@@ -274,40 +398,35 @@ def chunked(
     1`` [H, d_v, d_k])."""
     P, H, dk = q.shape
     dv = v.shape[-1]
-    pad = -P % CHUNK
+    pad = -P % GROUP
     Pp = P + pad
     with jax.named_scope("kda.chunk"):
         kb = k * beta[..., None]
         flat = lambda x: jnp.pad(  # noqa: E731
             x.reshape(P, -1), ((0, pad), (0, 0))
         )
-        # a chunk's own running sum of log-decays
-        G = jnp.cumsum(
-            jnp.pad(g, ((0, pad), (0, 0), (0, 0))).reshape(
-                Pp // CHUNK, CHUNK, H * dk
-            ), axis=1,
-        ).reshape(Pp, H * dk)
-        q, k, kb, v = flat(q), flat(k), flat(kb), flat(v)
+        q, k, kb, v, g = flat(q), flat(k), flat(kb), flat(v), flat(g)
         if chunk_eligible(dk, dv) if kernel is None else kernel:
-            o, St = _chunk_call(q, k, kb, v, G, length, H, mm_dtype, interpret)
+            o, St = _chunk_call(q, k, kb, v, g, length, H, mm_dtype, interpret)
         else:
             dot, exact = _dot(mm_dtype), _exact(mm_dtype)
-            heads = lambda x, d: x.reshape(  # noqa: E731
-                Pp // CHUNK, CHUNK, H, d
+            heads = lambda x, rows: x.reshape(  # noqa: E731
+                Pp // rows, rows, H, -1
             ).transpose(0, 2, 1, 3)
+            AB = jax.vmap(jax.vmap(
+                lambda *group: _lockstep([_solver(*group, dot, exact)])[0]
+            ))(*(heads(x, GROUP) for x in (q, k, kb, g)))
+            AB = AB.transpose(0, 2, 1, 3).reshape(Pp, H * GROUP)
 
             def one(St, xs):
                 O, St = jax.vmap(
-                    lambda q, k, kb, v, G, St: _chunk(
-                        q, k, kb, v, G, St, dot, exact
-                    )
+                    lambda *head: _lockstep([_advance(*head, dot)])[0]
                 )(*xs, St)
                 return St, O
 
             St, o = lax.scan(
                 one, jnp.zeros((H, dv, dk), jnp.float32),
-                (heads(q, dk), heads(k, dk), heads(kb, dk), heads(v, dv),
-                 heads(G, dk)),
+                tuple(heads(x, CHUNK) for x in (q, k, kb, v, g, AB)),
             )
             o = o.transpose(0, 2, 1, 3).reshape(Pp, H * dv)
         return o[:P].reshape(P, H, dv), St

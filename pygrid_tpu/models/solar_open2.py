@@ -60,7 +60,8 @@ garbage in every row that no live request owns. A prompt is always
 prefilled whole (``start == 0``): no prefix pages are shared.
 
 A prompt's delta-rule layers run the recurrence in CHUNKS (the Pallas
-kernel ``kda_chunk`` on a TPU), never a token at a time; its softmax
+kernels ``kda_chunk_solver`` and ``kda_chunk_state`` on a TPU), never a
+token at a time; its softmax
 layers never build ``[heads, P, P]`` scores (the flash kernel of
 :mod:`pygrid_tpu.parallel.pallas_attention` on a TPU, K/V heads repeated
 to their groups). A decode step gathers whole block tables.

@@ -247,14 +247,50 @@ def test_block_step_gathers_its_pages_from_the_pool_where_it_lies(
     assert aliased.count("may-alias") + aliased.count("must-alias") == 5
 
 
+def test_every_kernel_of_the_chunked_delta_rule_is_named_kda_chunk(
+    one_chip, no_compile_cache
+):
+    """``kda.chunked`` alone at the cell's widths (64 heads of 128, a
+    bucket of 2,048, bfloat16 products): whatever Pallas kernels it
+    reaches, each carries the ``kda_chunk`` prefix that
+    ``perfbench/metrics/kda_chunk_roofline_pct.longdoc.py`` sums by, and
+    the log-decays' running sums are taken inside them (no ``cumsum`` or
+    ``reduce-window`` of XLA's beside the kernels)."""
+    import re
+
+    from pygrid_tpu.models import kda
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    P, H, d = 2048, 64, 128
+    text = jax.jit(
+        lambda q, k, v, g, beta, length: kda.chunked(
+            q, k, v, g, beta, length, mm_dtype=jnp.bfloat16, kernel=True
+        )
+    ).lower(
+        arg((P, H, d)), arg((P, H, d)), arg((P, H, d)), arg((P, H, d)),
+        arg((P, H)), arg((), jnp.int32),
+    ).compile().as_text()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    assert len(calls) == 2, len(calls)
+    assert all("kda_chunk" in c for c in calls)
+    assert sum("kda_chunk_solver" in c for c in calls) == 1
+    assert sum("kda_chunk_state" in c for c in calls) == 1
+    assert "reduce-window" not in text and "cumsum" not in text
+
+
 def test_longdoc_prefill_holds_its_kernels_and_no_square_of_scores(
     one_chip, no_compile_cache, monkeypatch
 ):
     """The delta-rule family's prefill at the published widths (64 heads
     of 128 on 8 K/V heads, 40 of 320 experts of 4096 x 1280, bfloat16; a
     bucket of 2,048 and 512 blocks, to keep the compile short): three
-    ``kda_chunk`` layers, one flash forward, ``grouped_expert_ffn`` in
-    every layer, under the names the roofline readers sum
+    delta-rule layers of two kernels each (``kda_chunk_solver``,
+    ``kda_chunk_state``: every Pallas kernel that ``kda.chunked`` reaches
+    carries the ``kda_chunk`` prefix), one flash forward,
+    ``grouped_expert_ffn`` in every layer, under the names the roofline
+    readers sum
     (``perfbench/metrics/kda_chunk_roofline_pct.longdoc.py``,
     ``expert_ffn_roofline_pct.longdoc.py``); no ``[heads, P, P]`` float32
     scores, and the K/V pool is written in place, never copied whole."""
@@ -293,8 +329,9 @@ def test_longdoc_prefill_holds_its_kernels_and_no_square_of_scores(
     text = compiled.as_text()
     calls = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*", text)
     named = lambda name: sum(f"{name}" in c for c in calls)  # noqa: E731
-    assert len(calls) == 8, len(calls)
-    assert named("kda_chunk") == 3 and named("grouped_expert_ffn") == 4
+    assert len(calls) == 11, len(calls)
+    assert named("kda_chunk_solver") == 3 and named("kda_chunk_state") == 3
+    assert named("kda_chunk") == 6 and named("grouped_expert_ffn") == 4
     assert named("flash_fwd") == 1
     # the scores of 64 heads over the bucket, whole: 1 GB here, 17 GB at 8,192
     assert not re.search(rf"f32\[64,{P},{P}\]|f32\[8,8,{P},{P}\]", text)

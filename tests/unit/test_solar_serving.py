@@ -133,7 +133,7 @@ def _per_token(q, k, v, g, beta):
     return o, S
 
 
-def _draw(seed, P, length, decay=1.0, H=2, d=16):
+def _draw(seed, P, length, decay=1.0, H=2, d=16, tweak=None):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
     q = unit(jax.random.normal(ks[0], (P, H, d))) * d**-0.5
@@ -141,6 +141,11 @@ def _draw(seed, P, length, decay=1.0, H=2, d=16):
     v = jax.random.normal(ks[2], (P, H, d))
     g = -decay * jnp.exp(1.5 * jax.random.normal(ks[3], (P, H, d)) - 2.0)
     beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[4], (P, H)))
+    if tweak == "fast-channel":
+        # two channels of every head lose e^-20 a position, give or take
+        g = g.at[:, :, :2].set(-20.0 + g[:, :, :2])
+    elif tweak == "beta-2":
+        beta = jnp.full_like(beta, 2.0)
     real = jnp.arange(P) < length
     return (
         q, k, v, jnp.where(real[:, None, None], g, 0.0),
@@ -149,26 +154,35 @@ def _draw(seed, P, length, decay=1.0, H=2, d=16):
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["xla", "pallas-interpreted"])
-@pytest.mark.parametrize("P, length, decay", [
-    (64, 64, 1.0),      # one whole chunk
-    (16, 9, 1.0),       # a bucket under a chunk, mostly padding
-    (128, 100, 1.0),    # the second chunk part padding
-    (200, 137, 0.02),   # not a multiple of the chunk; slow decays: N is dense
-    (192, 70, 1.0),     # a whole chunk of nothing but padding
-    (128, 128, 40.0),   # decays of e^-40 a position: nothing overflows
+@pytest.mark.parametrize("P, length, decay, tweak", [
+    (64, 64, 1.0, None),      # one whole chunk
+    (16, 9, 1.0, None),       # a bucket under a chunk, mostly padding
+    (128, 100, 1.0, None),    # the second chunk part padding
+    (200, 137, 0.02, None),   # not a multiple of the chunk; slow decays: N is dense
+    (192, 70, 1.0, None),     # a whole chunk of nothing but padding
+    (128, 128, 40.0, None),   # decays of e^-40 a position: nothing overflows
+    # what the grouping by levels and by pairs of chunks could break (PR 42)
+    (128, 128, 1.0, "fast-channel"),  # every split exponent stays finite
+    (128, 128, 0.0, None),    # g = 0 throughout: N as dense as it gets
+    (128, 128, 1.0, "beta-2"),  # every beta at its ceiling
+    (128, 75, 1.0, None),     # the length ends inside a block of every level
+    (384, 70, 1.0, None),     # two whole groups of padding behind a part chunk
 ])
-def test_chunks_equal_the_recurrence_a_token_at_a_time(kernel, P, length, decay):
-    """``kda.chunked`` (through XLA and as the Pallas kernel, interpreted)
+def test_chunks_equal_the_recurrence_a_token_at_a_time(
+    kernel, P, length, decay, tweak
+):
+    """``kda.chunked`` (through XLA and as the Pallas kernels, interpreted)
     against the published recurrence, for lengths that are no multiple of
-    the chunk, padding behind the sequence, slow and violent decays: the
-    outputs of the real positions and the state after the last of them."""
-    q, k, v, g, beta = _draw(P + length, P, length, decay)
+    the chunk, padding behind the sequence, slow, absent and violent
+    decays: the outputs of the real positions and the state after the
+    last of them, and not one of them anything but a number."""
+    q, k, v, g, beta = _draw(P + length, P, length, decay, tweak=tweak)
     with jax.default_matmul_precision("highest"):
         want_o, want_S = _per_token(q, k, v, g, beta)
     o, St = kda.chunked(
         q, k, v, g, beta, jnp.int32(length), kernel=kernel, interpret=True
     )
-    assert bool(jnp.isfinite(o).all())
+    assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(St).all())
     np.testing.assert_allclose(o[:length], want_o[:length], atol=1e-5, rtol=0)
     np.testing.assert_allclose(St, want_S.transpose(0, 2, 1), atol=2e-5, rtol=0)
 
