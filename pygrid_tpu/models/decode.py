@@ -74,6 +74,7 @@ FAMILIES = {
     "jamba": ("JambaConfig", "pygrid_tpu.models.jamba"),
     "sdar_moe": ("SdarConfig", "pygrid_tpu.models.sdar_moe"),
     "solar_open2": ("SolarConfig", "pygrid_tpu.models.solar_open2"),
+    "brumby": ("BrumbyConfig", "pygrid_tpu.models.brumby"),
 }
 
 
@@ -116,12 +117,20 @@ def from_bundle(spec: dict) -> tuple[Any, Any]:
 # The engine and its programs reach a hosted model only through its
 # family's module (:func:`family_of`): ``init_paged_cache``,
 # ``paged_prefill_chunk`` and ``paged_decode_step`` over a cache whose
-# first three fields are ``k, v, pos``, and the facts below. This module
+# first three fields are ``k, v, pos``, and the facts below. A family
+# with no K/V layer (``kv_layers`` 0) still names the three: its ``k``
+# and ``v`` are empty arrays, ``pos`` counts the positions a slot has
+# taken in, the table its programs are handed has one column and is not
+# read, and everything the slot holds comes after, as state. This module
 # is the transformer's; :mod:`pygrid_tpu.models.jamba` the state-space
 # hybrid's, :mod:`pygrid_tpu.models.sdar_moe` the block-diffusion
-# decoder's and :mod:`pygrid_tpu.models.solar_open2` the delta-rule
-# hybrid's with a chip's share of its experts. A family with experts
-# answers, after its cache, what its forward counted of them.
+# decoder's, :mod:`pygrid_tpu.models.solar_open2` the delta-rule
+# hybrid's with a chip's share of its experts and
+# :mod:`pygrid_tpu.models.brumby` the power-retention decoder's, whose
+# cache is state alone. A family with experts answers, after its cache,
+# what its forward counted of them (one number, or three); a prefill whose
+# recurrence runs in chunks may answer a pair: the chunks that held the
+# prompt's own positions and the chunks it ran.
 
 #: no recurrent state, so prefix pages can be shared: that sharing is the
 #: one thing a family switches off by saying True here

@@ -343,8 +343,15 @@ class GenerationEngine:
         self._block = pagedkv.resolve_block_size(
             cfg.max_len, self.config.block_size
         )
-        self._max_pages = -(-cfg.max_len // self._block)
-        if self.config.num_blocks is not None:
+        #: whether any layer holds K/V pages: observed, as ``_recurrent``
+        #: is. A family whose whole cache is per-slot state has a pool of
+        #: the trash block alone and a table of one column that nothing
+        #: reads: a free slot admits, and no page is counted anywhere
+        self._paged = self.block_cost_bytes() > 0
+        self._max_pages = -(-cfg.max_len // self._block) if self._paged else 1
+        if not self._paged:
+            num_blocks = 1
+        elif self.config.num_blocks is not None:
             num_blocks = int(self.config.num_blocks)
         elif self.config.kv_budget_bytes is not None:
             # the trash block counts INSIDE the byte budget (same
@@ -358,7 +365,7 @@ class GenerationEngine:
             # room for every slot at full length; short requests free
             # what they don't use
             num_blocks = 1 + self.config.max_slots * self._max_pages
-        self._num_blocks = max(2, num_blocks)
+        self._num_blocks = max(2, num_blocks) if self._paged else 1
         self._pool = pagedkv.BlockPool(self._num_blocks)
         self._prefix = pagedkv.PrefixCache(
             self._pool, self._block, shareable=not self._recurrent
@@ -511,7 +518,9 @@ class GenerationEngine:
         # prefix cache ALREADY holds for this prompt (a probe —
         # admission re-matches for real; an eviction in between just
         # parks the row until blocks free)
-        pages_per_row = -(-self._span(p_len, n_new) // self._block)
+        pages_per_row = (
+            -(-self._span(p_len, n_new) // self._block) if self._paged else 0
+        )
         if pages_per_row > self._pool.usable:
             raise E.PyGridError(
                 f"request needs {pages_per_row} KV blocks of "
@@ -519,10 +528,11 @@ class GenerationEngine:
                 f"{self._pool.usable} — prompt + n_new can never "
                 "be cached"
             )
-        for row in rows:
-            row.demand = max(
-                1, pages_per_row - self._prefix.probe(row.prompt)
-            )
+        if self._paged:
+            for row in rows:
+                row.demand = max(
+                    1, pages_per_row - self._prefix.probe(row.prompt)
+                )
         demand = sum(r.demand for r in rows)
         with self._work:
             if not self._running:
@@ -787,7 +797,10 @@ class GenerationEngine:
         with self._lock:
             if self._live > 0 or self._queue:
                 return
-        if not self._kv_kernel and self._runs_on["platform"] == "tpu":
+        if (
+            self._paged and not self._kv_kernel
+            and self._runs_on["platform"] == "tpu"
+        ):
             logger.warning(
                 "engine %s: decode attention takes the gather path on "
                 "this TPU (pool %s %s does not tile for the paged kernel)",
@@ -931,7 +944,7 @@ class GenerationEngine:
                 row = self._queue.popleft()
                 self._slots[slot] = row
                 self._live += 1
-            if not self._assign_pages(slot, row):
+            if self._paged and not self._assign_pages(slot, row):
                 # block pool exhausted even after prefix-cache
                 # eviction: park the row at the queue HEAD (FIFO order
                 # kept) until a completing request frees blocks — the
@@ -990,10 +1003,11 @@ class GenerationEngine:
                 answer, launched, [row], False,
                 functools.partial(self._hand_prefill, slot, row),
             ))
-            # publish the full-prompt pages for future prefix hits
-            # (first prefill wins; a matched chain is only touched)
-            # gridlint: disable-next=GL202 — PrefixCache takes its own lock; only the engine thread mutates it
-            self._prefix.insert(row.prompt, row.pages)
+            if self._paged:
+                # publish the full-prompt pages for future prefix hits
+                # (first prefill wins; a matched chain is only touched)
+                # gridlint: disable-next=GL202 — PrefixCache takes its own lock; only the engine thread mutates it
+                self._prefix.insert(row.prompt, row.pages)
             if self._block_len > 1:
                 # no token comes of a block family's prefill: the row's
                 # first block opens where the prompt's whole blocks end
@@ -1275,14 +1289,23 @@ class GenerationEngine:
     @staticmethod
     def _note_counted(path: str, counted) -> None:
         """What a family's program counted of one forward and answered
-        beside its tokens (nothing, for a family without experts), under
-        the path that ran it: first the bytes of expert weights it had
-        to read (touched (layer, expert) pairs x one expert's matrices);
-        then, where the family holds a share of its experts, the
-        assignments its routers made and those that fell on an expert
-        held here."""
+        beside its tokens (nothing, for a family with neither experts
+        nor a chunked recurrence), under the path that ran it: first the
+        bytes of expert weights it had to read (touched (layer, expert)
+        pairs x one expert's matrices); then, where the family holds a
+        share of its experts, the assignments its routers made and those
+        that fell on an expert held here. A pair is no expert's: it is
+        what a prefill's chunked recurrence held and ran."""
         for counts in counted:
             read, *rows = np.ravel(counts)
+            if len(rows) == 1:
+                # a PAIR is a chunked recurrence's prefill: the chunks
+                # that held the prompt's own positions, the chunks run
+                telemetry.incr_many(
+                    "serving_retention_chunks_total", "kind",
+                    {"true": float(read), "computed": float(rows[0])},
+                )
+                continue
             telemetry.incr(
                 "serving_expert_bytes_total", float(read), kind="read",
                 path=path,
@@ -1443,18 +1466,19 @@ class GenerationEngine:
             "serving_dispatch_rowsteps_total", "kind",
             {"live": len(live) * steps, "computed": width * steps},
         )
-        table = width * self._max_pages * steps
-        telemetry.incr_many(
-            "serving_kv_pages_total", "kind",
-            {
-                "read": (
-                    self._kernel_pages(width, live, steps)
-                    if self._kv_kernel
-                    else table  # the gather reads whole tables
-                ),
-                "table": table,
-            },
-        )
+        if self._paged:
+            table = width * self._max_pages * steps
+            telemetry.incr_many(
+                "serving_kv_pages_total", "kind",
+                {
+                    "read": (
+                        self._kernel_pages(width, live, steps)
+                        if self._kv_kernel
+                        else table  # the gather reads whole tables
+                    ),
+                    "table": table,
+                },
+            )
         if self._recurrent:
             # a live row's state is read and written once a step (rows
             # the width computes beyond the live ones are not counted:
@@ -1548,9 +1572,10 @@ class GenerationEngine:
             # budget give-back stops being merely logical at the first
             # cache reallocation
             with self._lock:
-                self._num_blocks = max(
-                    2, self._num_blocks - self._shrunk_blocks
-                )
+                if self._paged:
+                    self._num_blocks = max(
+                        2, self._num_blocks - self._shrunk_blocks
+                    )
                 self._shrunk_blocks = 0
             cache = self._family.init_paged_cache(
                 self.cfg, self.config.max_slots, self._num_blocks,

@@ -120,7 +120,10 @@ def parse_weights(raw: str | None) -> dict[str, float]:
 def block_bytes(cfg, block: int, dtype: Any) -> int:
     """Device bytes one KV block costs for ``cfg``: k AND v, every layer
     that holds them (the family says which: a hybrid's state-space
-    layers hold none) — the unit the budget partitions."""
+    layers hold none) — the unit the budget partitions. 0 for a family
+    with no such layer at all (its cache is :func:`state_bytes` alone):
+    nothing may divide by it, and the engine of such a family keeps
+    neither pages nor tables (``GenerationEngine._paged``)."""
     import jax.numpy as jnp
 
     from pygrid_tpu.models import decode
@@ -146,13 +149,14 @@ class BlockPool:
     """Refcounted free-list allocator over ``num_blocks`` KV blocks.
 
     Block 0 is the trash block: reserved at construction, never handed
-    out. A block's refcount counts every holder — request tables and the
+    out (a pool of one block is that block alone, nothing usable: what
+    the engine of a family with no K/V layer holds). A block's refcount counts every holder — request tables and the
     prefix cache alike — and the block returns to the free list only at
     zero, so a shared prefix block outlives any single reader."""
 
     def __init__(self, num_blocks: int) -> None:
-        if num_blocks < 2:
-            raise ValueError("paged pool needs at least 2 blocks (one is trash)")
+        if num_blocks < 1:
+            raise ValueError("a pool holds at least its trash block")
         self.num_blocks = int(num_blocks)
         self._lock = threading.Lock()
         #: LIFO free list — reuse the hottest block first
@@ -482,10 +486,16 @@ class DeviceBudget:
         what the model holds per slot beside the pool (a recurrent
         state): it comes out of the model's share FIRST, and blocks are
         granted from the rest. Always grants at least one block beyond trash so a
-        registered model can serve SOMETHING."""
-        if self.total_bytes is None or bytes_per_block <= 0:
+        registered model can serve SOMETHING. A family with no K/V layer
+        (``bytes_per_block`` 0) is granted no block: its fixed bytes are
+        all it holds of the budget."""
+        if self.total_bytes is None:
             return None
         fixed_bytes = max(0, int(fixed_bytes))
+        if bytes_per_block <= 0:
+            with self._lock:
+                self._allocated[model_id] = fixed_bytes
+            return 0
         with self._lock:
             live = dict(self._allocated)
             live.pop(model_id, None)

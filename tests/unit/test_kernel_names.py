@@ -340,3 +340,99 @@ def test_longdoc_prefill_holds_its_kernels_and_no_square_of_scores(
     assert not re.search(r"= bf16\[1,512,64,8,128\][^ ]* copy\(", text)
     # what a prefill of 2,048 keeps beside its arguments: under 2 GB
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * 1024**3
+
+
+#: the power-retention cell's widths (Brumby-14B-Base: 40 query heads on 8
+#: K/V heads of 128, 20 slots; perfbench/configs/brumby-14b-base.json)
+RETENTION = dict(slots=20, heads=8, group=5, dk=128, dv=128)
+
+
+@pytest.mark.parametrize("form", ["step", "chunk"])
+def test_retention_kernels_lower_for_the_v5e_under_their_names(
+    form, one_chip, no_compile_cache
+):
+    """Both forms at the cell's widths, on two layers of a 20-slot state,
+    donated: one kernel each, under the names the roofline readers sum
+    (``perfbench/metrics/retention_*_roofline_pct.longreason.py``), the
+    state aliased (what the program holds beside its arguments is a
+    fraction of one layer's 0.69 GB)."""
+    from pygrid_tpu.models import retention
+
+    slots, G, R, dk, dv = RETENTION.values()
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    S, z = (arg(s) for s in retention.state_shapes(2, slots, G, dk, dv))
+    if form == "step":
+        fn = lambda S, z, q, k, v, g: retention.step(  # noqa: E731
+            S, z, 1, q, k, v, g, dk**-0.5, kernel=True
+        )
+        rest = (
+            arg((slots, G, R, dk)), arg((slots, G, dk)), arg((slots, G, dv)),
+            arg((slots, G)),
+        )
+    else:
+        P = 1024
+        fn = lambda S, z, slot, q, k, v, g, n: retention.chunked(  # noqa: E731
+            S, z, 1, slot, q, k, v, g, n, jnp.int32(0), dk**-0.5,
+            mm_dtype=jnp.bfloat16, kernel=True,
+        )
+        rest = (
+            arg((), jnp.int32), arg((P, G, R, dk)), arg((P, G, dk)),
+            arg((P, G, dv)), arg((P, G)), arg((), jnp.int32),
+        )
+    compiled = jax.jit(fn, donate_argnums=(0, 1)).lower(S, z, *rest).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert f"retention_{form}" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * slots * G * dv * 65 * dk * 4
+    assert memory.temp_size_in_bytes < 200e6
+
+
+def test_longreason_step_holds_eight_kernels_and_no_second_state(
+    one_chip, no_compile_cache, monkeypatch
+):
+    """The power-retention family's decode step at the published widths,
+    two layers of the cell's eight (to keep the compile short), width 20:
+    a ``retention_step`` a layer, the 1.4 GB state a parameter that is
+    aliased to its output and produced by nothing else, and no K/V array
+    with an element in it."""
+    import re
+
+    from pygrid_tpu.models import brumby, retention
+    from pygrid_tpu.serving.programs import ProgramSet
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layers, slots = 2, 20
+    cfg = brumby.BrumbyConfig(
+        vocab=151936, d_model=5120, n_heads=40, n_kv_heads=8, head_dim=128,
+        n_layers=layers, d_ff=17408, max_len=5120,
+    )
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda p: arg(p.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: brumby.init(jax.random.PRNGKey(0), cfg)),
+    )
+    S, z = (arg(s) for s in retention.state_shapes(layers, slots, 8, 128, 128))
+    empty = arg((0, 1, 64, 8, 128), jnp.bfloat16)
+    step = ProgramSet(cfg, cache_dtype=jnp.bfloat16).paged_decode(slots)
+    while not hasattr(step, "lower"):  # the profiler's wrapper
+        step = step.__wrapped__
+    compiled = step.lower(
+        params, empty, empty, arg((slots,), jnp.int32), S, z,
+        arg((slots,), jnp.int32), arg((slots, 1), jnp.int32),
+        arg((slots,), jnp.float32), arg((slots, 2), jnp.uint32),
+    ).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    assert len(calls) == layers and all("retention_step" in c for c in calls)
+    made = re.findall(rf"= f32\[{layers},{slots},8,128,8320\][^ ]* (\w+)\(", text)
+    assert set(made) <= {"parameter", "custom-call", "get-tuple-element"}, made
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= layers * slots * 8 * 129 * 8320 * 4
+    assert memory.temp_size_in_bytes < 200e6

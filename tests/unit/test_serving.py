@@ -614,7 +614,9 @@ def _family_attributes_used(path: Path) -> set[str]:
     return used
 
 
-@pytest.mark.parametrize("family", ["decode", "jamba", "sdar_moe", "solar_open2"])
+@pytest.mark.parametrize(
+    "family", ["decode", "jamba", "sdar_moe", "solar_open2", "brumby"]
+)
 def test_a_family_module_is_these_ten_names_and_nothing_else(family):
     """What a further family has to write, pinned: the module exposes the
     contract (the ten names and, since the block-diffusion family,
