@@ -407,6 +407,7 @@ def routed_experts(
     kernel: bool | None = None,
     interpret: bool = False,
     held: tuple[int, int] | None = None,
+    live: jax.Array | None = None,
 ):
     """The served expert layer over ``x`` [T, d] (float32, normed):
     ``sum_{e in top-k} p_e · W_down,e (silu(W_gate,e x) * W_up,e x)`` with
@@ -419,6 +420,11 @@ def routed_experts(
     ever; an assignment to an expert that is not held takes no row of
     the tile layout, reads no weight and adds nothing (what the chips
     that hold it would add is left out). ``None``: every expert is here.
+
+    ``live`` [T] bool says which rows of ``x`` are somebody's (a block
+    step carries positions that are nobody's beside the others): the
+    assignments of a row that is not take no row of the layout either,
+    read no weight, and its ``y`` is zero. ``None``: every row is.
 
     Returns (``y`` [T, d] float32; the number of held experts that
     received a row, int32: what the forward had to read of this layer's
@@ -433,6 +439,9 @@ def routed_experts(
             here = (local >= 0) & (local < n_experts)
             ids = jnp.where(here, local, n_experts)
             p = jnp.where(here.reshape(p.shape), p, 0.0)
+        if live is not None:
+            ids = jnp.where(jnp.repeat(live, k), ids, n_experts)
+            p = jnp.where(live[:, None], p, 0.0)
         sizes = jnp.zeros((n_experts + 1,), jnp.int32).at[ids].add(1)[
             :n_experts
         ]
@@ -450,9 +459,13 @@ def routed_experts(
                     n_experts / w_router.shape[1], tile, interpret,
                 )
             else:
-                y = _weighed(_grouped_rows(
+                rows = _grouped_rows(
                     xw, token, ids, w_gate, w_up, w_down, tile, interpret
-                ), p)
+                )
+                if live is not None:
+                    # past the layout's end: whatever the buffer held
+                    rows = jnp.where((ids < n_experts)[:, None], rows, 0.0)
+                y = _weighed(rows, p)
         else:
             order = jnp.argsort(ids, stable=True)
             xs = xw[token[order]]
@@ -465,9 +478,9 @@ def routed_experts(
             hidden = jax.nn.silu(dot(xs, w_gate)) * dot(xs, w_up)
             ys = dot(hidden.astype(w_down.dtype), w_down)
             y = jnp.zeros_like(ys).at[order].set(ys)
-            if held is not None:
-                # a row past the held experts' groups is nobody's
-                y = jnp.where(here[:, None], y, 0.0)
+            if held is not None or live is not None:
+                # a row past the experts' groups is nobody's
+                y = jnp.where((ids < n_experts)[:, None], y, 0.0)
             y = _weighed(y, p)
         return (
             y, jnp.sum(sizes > 0).astype(jnp.int32),

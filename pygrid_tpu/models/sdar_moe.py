@@ -22,12 +22,17 @@ starts masked (the mask token's embedding row stands at a masked
 position), a *denoising* forward runs the block's positions over the
 committed K/V and themselves and reveals some of the masked positions,
 each with the argmax of its OWN position's logits, the most confident
-first; when none is masked a *commit* forward runs the block once more,
-its K/V are what later blocks read, and the row's position moves on by
-``BLOCK_LEN``. So a row's forward carries ``BLOCK_LEN`` positions and
-yields nought to ``BLOCK_LEN`` tokens. Which positions are masked is the
-caller's knowledge, handed in as flags: ``cfg.mask_id`` can occur as a
-real token.
+first; when none is masked the block is *committed*: it runs once more
+with all of its tokens known, its K/V are what later blocks read, and
+the row's position moves on by ``BLOCK_LEN``. That run needs no forward
+of its own: the whole block rides in the NEXT block's first denoising
+forward, ``2 x BLOCK_LEN`` positions under the block-causal mask among
+them (the layout the family is trained in: a noised block attending the
+clean blocks before it). So a row's forward carries the current block's
+``BLOCK_LEN`` positions, with the block before them where it is to be
+committed, and yields nought to ``BLOCK_LEN`` tokens. Which positions
+are masked is the caller's knowledge, handed in as flags:
+``cfg.mask_id`` can occur as a real token.
 
 Parameters are a nested dict BY NAME (``embed``, ``head``, ``norm_f``,
 ``layers``: a list of per-layer dicts), matrices ``[in, out]``, a layer's
@@ -38,7 +43,9 @@ block, n_kv_heads, head_dim]``, keys after their norm and rotation.
 :func:`paged_decode_step` is the ONE device program's body: every forward
 writes its block's K/V at ``pos .. pos + BLOCK_LEN - 1`` (a later forward
 of the same block overwrites them) and attends over ``pos + BLOCK_LEN``
-rows; ``pos`` moves only where the caller says the block is committed. A
+rows; where the caller says the block before is committed, that block's
+known tokens run at ``pos`` first, their K/V stand, and ``pos`` moves on
+by ``BLOCK_LEN`` before the current block is placed. A
 page of ``block`` tokens ends on a block boundary (``block % BLOCK_LEN ==
 0``), so a prompt page's K/V depend on nothing after the page: prefix
 pages stay shareable.
@@ -246,14 +253,17 @@ def _pages(pool, layer: int, table):
     return pool[jnp.full_like(table, layer), table]
 
 
-def _experts(h, lp, c, cfg):
+def _experts(h, lp, c, cfg, live=None):
     """The expert layer's residual branch over ``h`` [..., d] and the
-    number of this layer's experts that received a row."""
+    number of this layer's experts that received a row. ``live`` [...]
+    bool: positions that are somebody's (``None``: all); the others take
+    no row of any expert."""
     lead = h.shape[:-1]
     y, touched, _ = moe.routed_experts(
         _rms(h, lp["norm_ff"]).reshape(-1, cfg.d_model), lp["router"],
         c(lp["w_gate"]), c(lp["w_up"]), c(lp["w_down"]), cfg.top_k,
         interpret=jax.default_backend() != "tpu",
+        live=None if live is None else live.reshape(-1),
     )
     return y.reshape(*lead, cfg.d_model), touched
 
@@ -349,38 +359,56 @@ def paged_decode_step(
     token: jax.Array,
     cfg: SdarConfig = SdarConfig(),
     compute_dtype: Any | None = None,
-    active: jax.Array | None = None,
-    masked: jax.Array | None = None,
+    *,
+    before: jax.Array,
+    commit: jax.Array,
+    masked: jax.Array,
 ) -> tuple[jax.Array, PagedKVCache, jax.Array]:
     """One forward of the current block of the first ``w`` slots, each at
-    its own ``pos``. ``token`` [w, BLOCK_LEN]: the block as it stands;
+    its own ``pos``, with the block before riding in it where it is to be
+    committed. ``token`` [w, BLOCK_LEN]: the block as it stands;
     ``masked`` [w, BLOCK_LEN] bool: positions that hold no token yet (the
     mask token's embedding stands there, whatever ``token`` says);
-    ``active`` [w] bool: rows whose block this forward commits (their
-    ``pos`` moves on by ``BLOCK_LEN``). Every row writes its block's K/V
-    at ``pos .. pos + BLOCK_LEN - 1`` and attends over ``pos + BLOCK_LEN``
-    rows: a free slot inside the width has a zeroed table row, so its
-    writes land in trash block 0. Returns the logits ``[w, BLOCK_LEN,
-    vocab]`` float32, the cache, and the bytes of expert weights the
-    forward had to read (touched (layer, expert) pairs x one expert's
-    three matrices, float32: exact up to 2**24 pairs)."""
+    ``before`` [w, BLOCK_LEN]: the tokens of the block before, whole;
+    ``commit`` [w] bool: rows whose block before this forward commits.
+    Such a row runs ``2 x BLOCK_LEN`` positions: the block before at
+    ``pos .. pos + BLOCK_LEN - 1`` (over the cache and itself: its K/V
+    there are what later blocks read), ``pos`` moves on by ``BLOCK_LEN``,
+    and the current block stands behind it and sees it. Every other row's
+    first ``BLOCK_LEN`` positions are nobody's: their K/V go to trash
+    block 0, nothing reads what they compute, and the current block
+    stands at ``pos``. Every row writes its current block's K/V at its
+    (moved) ``pos`` and attends over ``pos + BLOCK_LEN`` rows; a free slot
+    inside the width has a zeroed table row, so all its writes land in
+    trash block 0. The last layer runs its queries and its experts over
+    the current block alone (the block before is there for its K/V).
+    Returns the CURRENT block's logits ``[w, BLOCK_LEN, vocab]`` float32,
+    the cache, and the bytes of expert weights the forward had to read
+    (touched (layer, expert) pairs x one expert's three matrices,
+    float32: exact up to 2**24 pairs)."""
     c = _caster(compute_dtype)
     w, L = token.shape
     block = cache.k.shape[2]
     max_pages = table.shape[1]
     rows = max_pages * block
     G, R = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads
-    t = cache.pos[:w]
     tw = table[:w]
-    positions = t[:, None] + jnp.arange(L)  # [w, L]
+    t = cache.pos[:w]
+    moved = t + L * commit.astype(jnp.int32)
+    #: where each of a row's two blocks starts, and whose positions are
+    #: somebody's: the block before only where it is committed
+    starts = jnp.repeat(jnp.stack([t, moved], 1), L, axis=1)
+    live = jnp.repeat(jnp.stack([commit, jnp.ones_like(commit)], 1), L, axis=1)
+    positions = starts + jnp.tile(jnp.arange(L), 2)  # [w, 2L]
     page = jnp.minimum(positions // block, max_pages - 1)
-    blk = jnp.take_along_axis(tw, page, axis=1)
+    blk = jnp.where(live, jnp.take_along_axis(tw, page, axis=1), 0)
     off = positions % block
-    if masked is not None:
-        token = jnp.where(masked, cfg.mask_id, token)
-    #: all of the block and all before it: one mask for its L positions
-    mask = jnp.arange(rows)[None, :] < (t + L)[:, None]  # [w, rows]
-    h = c(params["embed"][token]).astype(jnp.float32)  # [w, L, d]
+    token = jnp.concatenate(
+        [before, jnp.where(masked, cfg.mask_id, token)], 1
+    )
+    #: all of a position's own block and all before it
+    mask = jnp.arange(rows)[None, None, :] < (starts + L)[:, :, None]
+    h = c(params["embed"][token]).astype(jnp.float32)  # [w, 2L, d]
 
     new_k, new_v = cache.k, cache.v
     touched = jnp.int32(0)
@@ -391,32 +419,33 @@ def paged_decode_step(
             )
             new_k = new_k.at[li, blk, off].set(k)
             new_v = new_v.at[li, blk, off].set(v)
+            if li + 1 == cfg.n_layers:
+                # nothing reads the block before past its K/V here, and
+                # every position that is left is somebody's
+                q, h, mask, live = q[:, L:], h[:, L:], mask[:, L:], None
+            P = q.shape[1]
             k_rows = _pages(new_k, li, tw).reshape(w, rows, G, cfg.head_dim)
             v_rows = _pages(new_v, li, tw).reshape(w, rows, G, cfg.head_dim)
             s = jnp.einsum(
                 "wpgrd,wlgd->wgrpl",
-                q.reshape(w, L, G, R, cfg.head_dim).astype(k_rows.dtype),
+                q.reshape(w, P, G, R, cfg.head_dim).astype(k_rows.dtype),
                 k_rows, preferred_element_type=jnp.float32,
             ) * cfg.head_dim**-0.5
             p = jax.nn.softmax(
-                jnp.where(mask[:, None, None, None, :], s, -1e30), -1
+                jnp.where(mask[:, None, None, :, :], s, -1e30), -1
             )
             a = jnp.einsum(
                 "wgrpl,wlgd->wpgrd", p.astype(v_rows.dtype), v_rows,
                 preferred_element_type=jnp.float32,
-            ).reshape(w, L, cfg.n_heads * cfg.head_dim)
+            ).reshape(w, P, cfg.n_heads * cfg.head_dim)
             h = h + _mm(a, c(lp["wo"]))
-        y, n = _experts(h, lp, c, cfg)
+        y, n = _experts(h, lp, c, cfg, live)
         h = h + y
         touched = touched + n
     with jax.named_scope("lm_head"):
         logits = _mm(_rms(h, params["norm_f"]), c(params["head"]))
-    advance = (
-        active.astype(jnp.int32) if active is not None
-        else jnp.ones((w,), jnp.int32)
-    )
     return (
         logits,
-        PagedKVCache(k=new_k, v=new_v, pos=cache.pos.at[:w].add(L * advance)),
+        PagedKVCache(k=new_k, v=new_v, pos=cache.pos.at[:w].set(moved)),
         touched.astype(jnp.float32) * float(expert_bytes(params)),
     )

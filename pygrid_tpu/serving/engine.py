@@ -44,8 +44,10 @@ the node generates:
   one program a width, ``paged_block_step``: a row's current block
   (its tokens, which positions are still masked) lives on the device
   beside the cache, a forward reveals nought to ``BLOCK_LEN`` of its
-  tokens there, and the row's position moves only when the block is
-  committed. Rows in different phases share a dispatch.
+  tokens there, and the forward after the one that made a block whole
+  carries that block beside the next one: its K/V stand, the row's
+  position moves on, and no forward runs for the commit alone. Rows in
+  different phases share a dispatch.
 
 **The loop dispatches ahead of what it has read.** Nothing the host
 decides between two forwards depends on the tokens: there is no stop
@@ -56,9 +58,10 @@ in ``row.out``; the loop plans by the first: a row leaves its slot, and
 its pages the pool, when its last token is scheduled. A block family's
 counts are fixed by ``denoising_steps``: a block with ``m`` masked
 positions takes ``ceil(m / (BLOCK_LEN / denoising_steps))`` denoising
-forwards, each revealing that many or what is left, then one commit (a
-row's last block none), so how many a forward reveals, whether it
-commits and what it makes final are known as it is built. The device
+forwards, each revealing that many or what is left, and the first
+forward of the block after it commits it (nothing commits a row's last
+block), so how many a forward reveals, whether it carries a commit and
+what it makes final are known as it is built. The device
 runs its programs in order, so a prefill into the same slot or pages
 queues behind the step that still writes them. What a forward reads of
 the one before stays on the device (``programs``: ``last``, a causal
@@ -209,9 +212,11 @@ class _Row:
         #: a block family's row: how many denoising forwards reveal a
         #: whole block. What is PLANNED, as ``scheduled`` is: the block
         #: the next forward runs (its first position, the masked
-        #: positions the launched forwards leave in it, the next
-        #: forward's index in it) and the prompt's tail, which the row's
-        #: first forward brings. What has ARRIVED, as ``out`` has: the
+        #: positions the launched forwards leave in it: none once it is
+        #: whole, and the next forward then opens the block after it and
+        #: carries this one's commit; the next forward's index in it)
+        #: and the prompt's tail, which the row's first forward brings.
+        #: What has ARRIVED, as ``out`` has: the
         #: block being read (its tokens, the forward that revealed
         #: each) and, beside ``out``, the forward that revealed each
         #: token, with the (token, forward) pairs the last block made
@@ -1229,33 +1234,31 @@ class GenerationEngine:
     def _block_inputs(self, width: int, live: list[tuple[int, "_Row"]]):
         """A block step's program, its inputs after the table, how its
         answer reads, and the tokens it makes final for each live row.
-        All of it from counts, nothing from an answer: a row whose block
-        still has masked positions takes a DENOISING forward that
-        reveals ``BLOCK_LEN / denoising_steps`` of them (or those that
-        are left), and the one that reveals the last makes the block's
-        tokens final; a row whose block is whole takes the COMMIT
-        forward, which reveals none, moves its position on and opens the
-        next block. The blocks are the device's (``last``); a row's
-        first forward brings the prompt's tail, and a free slot inside
-        the width runs a known block of zeros."""
+        All of it from counts, nothing from an answer: every forward is
+        a DENOISING forward that reveals ``BLOCK_LEN / denoising_steps``
+        of its block's masked positions (or those that are left), and the
+        one that reveals the last makes the block's tokens final. A row
+        whose block that left whole opens the next block at once: this
+        forward is that block's first and carries the COMMIT of the
+        block before (its known tokens ride along, their K/V stand, the
+        row's position moves on past them). The blocks are the device's
+        (``last``); a row's first forward brings the prompt's tail, and
+        a free slot inside the width runs a known block of zeros."""
         import jax.numpy as jnp
 
         L = self._block_len
         tail = np.zeros((width, L), np.int32)
         n_reveal = np.zeros(width, np.int32)
-        advance = np.zeros(width, bool)
+        commit = np.zeros(width, bool)
         plans, made = [], []
         for i, row in live:
             tail[i] = -1 if row.blk_tail is None else row.blk_tail
             row.blk_tail = None
             if row.blk_left == 0:
-                advance[i] = True
-                plans.append(None)
-                made.append(0)
+                commit[i] = True
                 row.blk_pos += L
                 row.blk_left = L
                 row.blk_forward = 0
-                continue
             n_reveal[i] = n = min(L // row.denoising_steps, row.blk_left)
             row.blk_left -= n
             whole = row.blk_left == 0
@@ -1266,10 +1269,12 @@ class GenerationEngine:
                 min(row.blk_pos + L, end) - max(row.blk_pos, first)
                 if whole else 0
             )
-        commits = int(advance.sum())
+        telemetry.incr(
+            "serving_block_forwards_total", len(live), kind="denoise"
+        )
         telemetry.incr_many(
-            "serving_block_forwards_total", "kind",
-            {"denoise": len(live) - commits, "commit": commits},
+            "serving_block_positions_total", "kind",
+            {"denoise": L * len(live), "commit": L * int(commit.sum())},
         )
 
         def take(live, toks, chosen, *counted):
@@ -1281,7 +1286,7 @@ class GenerationEngine:
 
         return (
             self.programs.paged_block_step(width),
-            tuple(map(jnp.asarray, (tail, n_reveal, advance))),
+            tuple(map(jnp.asarray, (tail, n_reveal, commit))),
             take,
             made,
         )
@@ -1335,14 +1340,11 @@ class GenerationEngine:
 
     def _close_forward(self, row: _Row, toks, chosen, plan) -> tuple:
         """Read one forward's answer into the row's block as the plan it
-        was built by says (``None``: a commit; else the forward's index
-        in its block, the block's position and whether it reveals the
-        block's last masked position); returns the tokens the forward
-        yields the row, in position order: the block's own once it is
-        whole (those past ``n_new`` go to ``row.dropped``), none before,
-        none from a commit."""
-        if plan is None:
-            return ()
+        was built by says (the forward's index in its block, the block's
+        position and whether it reveals the block's last masked
+        position); returns the tokens the forward yields the row, in
+        position order: the block's own once it is whole (those past
+        ``n_new`` go to ``row.dropped``), none before."""
         forward, pos, whole = plan
         row.blk_tokens[chosen] = toks[chosen]
         row.blk_step[chosen] = forward
@@ -1497,10 +1499,6 @@ class GenerationEngine:
         up to its length at each step (what is scheduled for it so far;
         parked once the row has its tokens, as its position is), one
         trash page for each free slot inside the width."""
-        if self._block_len > 1:
-            # a block step attends over the row's block and all before
-            ends = np.array([r.blk_pos + self._block_len for _, r in live])
-            return int((-(-ends // self._block)).sum()) + width - len(live)
         base = np.array([len(r.prompt) + r.scheduled for _, r in live])
         need = np.array([r.n_new - r.scheduled for _, r in live])
         lengths = base[:, None] + np.minimum(np.arange(steps), need[:, None])

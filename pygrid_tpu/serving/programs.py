@@ -37,11 +37,14 @@ over the slots: the block's tokens (int32 ``[slots, BLOCK_LEN]``) and
 which of its positions are still masked (bool, the same shape). A
 prefill opens the admitted slot's block (every position masked); a block
 step reads the first ``w`` rows, takes in what the host knows of a row's
-first block (the prompt's tail), runs the forward, applies the reveal
-there and, on a committing row, opens the next block. So a forward's
-input never waits for the host to have fetched the forward before it
-either; ``tokens`` and ``chosen`` are what the host reads, a dispatch
-late.
+first block (the prompt's tail), runs the forward and applies the reveal
+there. The forward after the one that made a block whole carries that
+block beside the next: its tokens are what ``last`` holds then, so the
+commit of a block needs no forward of its own and nothing from the host
+(the next block opens in the same program, every position masked). So a
+forward's input never waits for the host to have fetched the forward
+before it either; ``tokens`` and ``chosen`` are what the host reads, a
+dispatch late.
 """
 
 from __future__ import annotations
@@ -149,8 +152,8 @@ class ProgramSet:
     def _reveal(logits, masked, n_reveal):
         """What one forward of a block reveals. ``logits`` [w, L, vocab]:
         each position's own; ``masked`` [w, L] bool; ``n_reveal`` [w]:
-        how many of a row's masked positions to reveal (0: none, a
-        commit). A position's token is its argmax and its confidence the
+        how many of a row's masked positions to reveal (0: none, a free
+        slot). A position's token is its argmax and its confidence the
         largest probability of its softmax; the ``n_reveal`` most
         confident masked positions are chosen, the earlier at a tie.
         Returns (tokens [w, L] int32, chosen [w, L] bool)."""
@@ -314,7 +317,7 @@ class ProgramSet:
 
     def paged_block_step(self, width: int) -> Callable:
         """``fn(params, k, v, pos, block, table, tail[w, L], n_reveal[w],
-        advance[w]) -> (tokens[w, L], chosen[w, L], expert_bytes, k, v,
+        commit[w]) -> (tokens[w, L], chosen[w, L], expert_bytes, k, v,
         pos, block)`` — one forward of the current block of the first
         ``w`` slots of a family whose forward carries ``L = BLOCK_LEN``
         positions. ``block`` is the pair ``(tokens[slots, L],
@@ -324,11 +327,14 @@ class ProgramSet:
         the device held there; -1 leaves the position as it is. A row's
         first forward brings the prompt's tail so; a free slot inside the
         width brings four known zeros. Rows in different phases share the
-        dispatch: a denoising row reveals ``n_reveal`` of its masked
+        dispatch: every row reveals ``n_reveal`` of its block's masked
         positions (``chosen``, each with the token beside it in
         ``tokens``; they take those tokens and lose their mask in
-        ``block``), a committing row reveals none, its position moves on
-        (``advance``) and its next block opens, every position masked.
+        ``block``). A row whose last forward made its block whole says
+        ``commit``: what ``block`` holds for it is then the block BEFORE,
+        which rides in this forward with its tokens known (its K/V stand,
+        the row's position moves on past it), and the block the forward
+        denoises is the next one, opened here with every position masked.
         ``expert_bytes`` is what the forward read of the experts'
         weights, as the family counts it."""
         fn = self._paged_block.get(width)
@@ -340,23 +346,21 @@ class ProgramSet:
             cache_of, n = self._cache_of, self._cache_arrays
 
             def _paged_block_step(params, *args):
-                (tokens, masked), table, tail, n_reveal, advance = args[n:]
+                (tokens, masked), table, tail, n_reveal, commit = args[n:]
                 width = n_reveal.shape[0]
                 known = tail >= 0
                 blk = jnp.where(known, tail, tokens[:width])
-                hidden = masked[:width] & ~known
+                hidden = (masked[:width] & ~known) | commit[:, None]
                 logits, cache, expert_bytes = model.paged_decode_step(
                     params, cache_of(args[:n]), table, blk, cfg, cd,
-                    active=advance, masked=hidden,
+                    before=tokens[:width], commit=commit, masked=hidden,
                 )
                 toks, chosen = self._reveal(logits, hidden, n_reveal)
-                blk = jnp.where(chosen, toks, blk)
-                hidden = (hidden & ~chosen) | advance[:, None]
                 return (
                     toks, chosen, expert_bytes, *cache,
                     (
-                        tokens.at[:width].set(blk),
-                        masked.at[:width].set(hidden),
+                        tokens.at[:width].set(jnp.where(chosen, toks, blk)),
+                        masked.at[:width].set(hidden & ~chosen),
                     ),
                 )
 

@@ -99,6 +99,34 @@ def test_without_the_field_a_block_takes_its_length_in_forwards(node):
     assert out["dropped_tokens"].shape == (1, 0)
 
 
+def _scraped(server, family):
+    """``{kind: value}`` of one counter family on the node's ``/metrics``."""
+    from pygrid_tpu.telemetry import promtext
+
+    found = promtext.parse(requests.get(server.url + "/metrics", timeout=30).text)
+    if family not in found:
+        return {}
+    return {labels["kind"]: value for _, labels, value in found[family].samples}
+
+
+def test_the_node_counts_a_commit_s_positions_and_no_forward_for_it(node):
+    """Two blocks at two forwards each: four row-forwards, every one a
+    denoising forward; the first block's commit rode in the second block's
+    first forward, four positions beside that forward's own four."""
+    server, client, _, _ = node
+    forwards = "pygrid_serving_block_forwards_total"
+    positions = "pygrid_serving_block_positions_total"
+    before = _scraped(server, forwards), _scraped(server, positions)
+    client.run_remote_generation(
+        "blocks", np.full((1, 8), 9, np.int32), n_new=8, denoising_steps=2
+    )
+    after = _scraped(server, forwards), _scraped(server, positions)
+    gained = [
+        {k: v - was.get(k, 0.0) for k, v in now.items()} for was, now in zip(before, after)
+    ]
+    assert gained == [{"denoise": 4.0}, {"denoise": 16.0, "commit": 4.0}]
+
+
 @pytest.mark.parametrize("bad, says", [
     ({"denoising_steps": 3}, "must divide the block length (4)"),
     ({"denoising_steps": 0}, "must divide the block length (4)"),

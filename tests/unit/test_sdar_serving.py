@@ -76,6 +76,10 @@ def weights(model, cfg):
     return model.make_weights(3, cfg, "float32")
 
 
+def _blocks(named):
+    return [named[a : a + 4] for a in range(0, len(named), 4)]
+
+
 def _tokens(seed, n, vocab=128):
     return np.random.default_rng(seed).integers(0, vocab, n).astype(np.int32)
 
@@ -161,6 +165,29 @@ def test_grouped_experts_equal_dense_compute_every_expert(params, kernel, tokens
     assert int(landed) == tokens * 2
 
 
+@pytest.mark.parametrize("kernel", [True, False], ids=["pallas-interpreted", "ragged-dot"])
+def test_a_row_that_is_nobody_s_takes_no_expert_row_and_adds_nothing(params, kernel):
+    """``live``: a block step's first four positions where it commits
+    nothing. Their assignments take no row of any expert's group (an
+    expert only they chose is not read), their result is zero, and every
+    other row's is what it is without them."""
+    lp = params["layers"][1]
+    x = jax.random.normal(jax.random.PRNGKey(7), (24, 64))
+    live = np.arange(24) % 3 != 1
+    want, idx = _dense_experts(x, lp, 2)
+    got, touched, landed = jax.jit(
+        lambda x, live: moe.routed_experts(
+            x, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"], 2,
+            kernel=kernel, interpret=True, live=live,
+        )
+    )(x, jnp.asarray(live))
+    got = np.asarray(got)
+    assert float(np.abs(got[live] - np.asarray(want)[live]).max()) <= 2e-6
+    assert not got[~live].any()
+    assert int(touched) == len(np.unique(np.asarray(idx)[live]))
+    assert int(landed) == 2 * int(live.sum())
+
+
 def test_grouped_layout_starts_every_expert_on_a_tile_and_drops_nothing():
     ids = jnp.asarray([5, 0, 5, 5, 2, 0, 5, 5, 5], jnp.int32)
     dest, tile_expert, n_live, sizes = moe.grouped_layout(ids, 8, tile=4)
@@ -179,13 +206,13 @@ def test_grouped_layout_starts_every_expert_on_a_tile_and_drops_nothing():
 # ── the program against the reference, on logits ─────────────────────────
 
 
-def _cache_after_prefill(scfg, params, prompt, pages):
+def _cache_after_prefill(scfg, params, prompt, pages, bucket=32):
     cache = sdar_moe.init_paged_cache(scfg, 2, 12, PAGE)
     table = jnp.zeros((2, 128 // PAGE), jnp.int32).at[1, : len(pages)].set(
         jnp.asarray(pages, jnp.int32)
     )
     whole = len(prompt) // 4 * 4
-    chunk = np.zeros(32, np.int32)
+    chunk = np.zeros(bucket, np.int32)
     chunk[:whole] = prompt[:whole]
     _, cache, read = sdar_moe.paged_prefill_chunk(
         params, cache, table, jnp.int32(1), jnp.asarray(chunk), jnp.int32(0),
@@ -194,40 +221,74 @@ def _cache_after_prefill(scfg, params, prompt, pages):
     return cache, table, whole, float(read)
 
 
+def _rows_of(pool, pages):
+    """A row's K/V in position order: ``pool`` [layers, blocks, block, G,
+    dh] through its pages -> [layers, positions, G, dh]."""
+    got = np.asarray(pool)[:, list(pages)]
+    return got.reshape(got.shape[0], -1, *got.shape[3:])
+
+
 @pytest.mark.parametrize("p_len", [8, 21, 30])
 def test_prefill_and_block_steps_agree_with_the_full_forward(
     model, cfg, scfg, params, weights, p_len
 ):
     """The prompt's whole blocks through prefill, then every later block
-    twice through the one step function: a denoising forward with two of
-    its positions masked, then the commit forward with all four known.
-    Each forward's logits are the reference's for that state; the commit
-    forwards' K/V are what the next block reads."""
+    twice through the one step function: its first forward, two of its
+    positions masked, which carries the block before it, whole, and
+    commits it (the row's first block has none before it); then a forward
+    with one position masked that carries nothing. Each forward's logits
+    for the current block are the reference's for that state; ``pos``
+    moves on by four only where a commit rides; a previous block that is
+    nobody's writes nothing but trash block 0; and the K/V the commits
+    leave are those of a prefill over the committed sequence."""
     seq = _tokens(p_len, 40)
     seq[3], seq[p_len // 4 * 4 + 1] = 127, 127  # the mask token's id, as a real token
-    cache, table, whole, read = _cache_after_prefill(scfg, params, seq[:p_len], [3, 7, 5])
+    pages = [3, 7, 5]
+    cache, table, whole, read = _cache_after_prefill(scfg, params, seq[:p_len], pages)
     assert int(cache.pos[1]) == whole
     # the last layer's experts feed nothing a prefill returns: one layer's are read
     assert 0 < read <= 8 * sdar_moe.expert_bytes(params)
-    step = jax.jit(lambda cache, tok, masked, active: sdar_moe.paged_decode_step(
-        params, cache, table, tok, scfg, active=active, masked=masked,
+    step = jax.jit(lambda cache, tok, masked, before, commit: sdar_moe.paged_decode_step(
+        params, cache, table, tok, scfg, before=before, commit=commit, masked=masked,
     ))
+    free = np.zeros(4, np.int32)
     worst = 0.0
     for a in range(whole, 40, 4):
-        masked = np.array([False, True, False, True])
-        for flags, commit in ((masked, False), (np.zeros(4, bool), True)):
+        for flags, commit in (
+            (np.array([False, True, False, True]), a > whole),
+            (np.array([False, False, False, True]), False),
+        ):
             block = np.where(flags, 0, seq[a : a + 4])  # what a masked slot says is ignored
             state = np.concatenate([seq[:a], np.where(flags, 127, seq[a : a + 4])])
             want = np.asarray(model.logits(weights, jnp.asarray(state[None]), cfg)[0, a:])
+            # where no commit rides, what stands in the block's place is nobody's
+            before = seq[a - 4 : a] if commit else _tokens(a, 4)
+            held = np.asarray(cache.k), np.asarray(cache.v)
             got, cache, read = step(
-                cache, jnp.asarray(np.stack([np.zeros(4, np.int32), block])),
+                cache, jnp.asarray(np.stack([free, block])),
                 jnp.asarray(np.stack([np.zeros(4, bool), flags])),
+                jnp.asarray(np.stack([free, before])),
                 jnp.asarray([False, commit]),
             )
+            assert got.shape == (2, 4, 128)  # the current block's logits alone
             worst = max(worst, float(np.abs(np.asarray(got[1]) - want).max()))
-            assert int(cache.pos[1]) == (a + 4 if commit else a)
+            assert int(cache.pos[1]) == a and int(cache.pos[0]) == 0
             assert float(read) % sdar_moe.expert_bytes(params) == 0
+            # outside trash block 0 the forward wrote the current block's
+            # four rows and, where it commits, the four before them
+            wrote = {(pages[i // PAGE], i % PAGE) for i in range(a - 4 * commit, a + 4)}
+            for was, now in zip(held, (cache.k, cache.v)):
+                changed = (np.asarray(now) != was).any(axis=(0, 3, 4))
+                assert {(int(b), int(o)) for b, o in zip(*np.nonzero(changed[1:]))} <= {
+                    (b - 1, o) for b, o in wrote
+                }
     assert worst <= TOL, worst
+    # blocks up to the last but one are committed: their K/V are what a
+    # prefill of those 36 tokens writes
+    clean, _, _, _ = _cache_after_prefill(scfg, params, seq[:36], pages, bucket=48)
+    for got, want in ((cache.k, clean.k), (cache.v, clean.v)):
+        gap = np.abs(_rows_of(got, pages)[:, :36] - _rows_of(want, pages)[:, :36])
+        assert float(gap.max()) <= TOL
 
 
 def test_a_shared_prefix_page_serves_a_second_prompt(model, cfg, scfg, params, weights):
@@ -247,7 +308,8 @@ def test_a_shared_prefix_page_serves_a_second_prompt(model, cfg, scfg, params, w
     block = _tokens(3, 4)
     got, _, _ = sdar_moe.paged_decode_step(
         params, cache, table, jnp.asarray(block[None]), scfg,
-        active=jnp.asarray([False]), masked=jnp.zeros((1, 4), bool),
+        before=jnp.zeros((1, 4), jnp.int32), commit=jnp.asarray([False]),
+        masked=jnp.zeros((1, 4), bool),
     )
     state = np.concatenate([second, block])
     want = np.asarray(model.logits(weights, jnp.asarray(state[None]), cfg)[0, 28:])
@@ -312,6 +374,10 @@ def served(model, cfg, scfg, params, weights):
                 for kind in ("denoise", "commit")
             },
             **{
+                f"positions_{kind}": _count("serving_block_positions_total", kind=kind)
+                for kind in ("denoise", "commit")
+            },
+            **{
                 path: _count("serving_expert_bytes_total", kind="read", path=path)
                 for path in ("step", "prefill")
             },
@@ -362,12 +428,19 @@ def test_counters_and_the_ledger_after_the_cases(served):
     tokens = sum(n for _, n, _ in CASES)
     bus = stats["bus"]
     assert stats["tokens_total"] == tokens == bus["tokens"]
-    # a block of four costs its denoising forwards and a commit; the last
-    # block of a row is not committed: nothing reads its K/V
-    denoise = sum(max(g["reveal_step"][0] + g["dropped_reveal_step"][0]) + 1 for g in got)
-    assert bus["denoise"] >= denoise
+    # a block of four costs its denoising forwards and nothing more: every
+    # row-forward reveals something, none is a commit alone
+    denoise = sum(
+        max(block) + 1
+        for g, (p, _, _) in zip(got, CASES)
+        for block in _blocks([-1] * (p % 4) + g["reveal_step"][0] + g["dropped_reveal_step"][0])
+    )
+    assert bus["denoise"] == denoise and bus["commit"] == 0
+    # a commit's four positions ride in the next block's first forward; the
+    # last block of a row is not committed: nothing reads its K/V
     blocks = sum(-(-(p + n) // 4) - p // 4 for p, n, _ in CASES)
-    assert bus["commit"] == blocks - len(CASES)
+    assert bus["positions_denoise"] == 4 * denoise
+    assert bus["positions_commit"] == 4 * (blocks - len(CASES))
     assert not stats["fused"] and bus["fused"] == 0
     per_expert = 3 * 64 * 32 * 4
     for path in ("step", "prefill"):
@@ -407,16 +480,16 @@ def _gated(eng):
 def planned(model, cfg, scfg, params, weights):
     """Six requests queued before a three-slot engine starts, every block
     step's inputs recorded as it is built (``tail``, ``n_reveal``,
-    ``advance`` of each live row), and the plain generate's answers."""
+    ``commit`` of each live row), and the plain generate's answers."""
     eng = _engine(scfg, params, max_slots=3, slot_buckets=(3,))
     sent, build = [], eng._block_inputs
 
     def recording(width, live):
         rows = [(row.pending.request_id, i) for i, row in live]
         out = build(width, live)
-        tail, n_reveal, advance = (np.asarray(x) for x in out[1])
+        tail, n_reveal, commit = (np.asarray(x) for x in out[1])
         sent.append([
-            (rid, tail[i].tolist(), int(n_reveal[i]), bool(advance[i]))
+            (rid, tail[i].tolist(), int(n_reveal[i]), bool(commit[i]))
             for rid, i in rows
         ])
         return out
@@ -451,9 +524,10 @@ def test_the_host_plans_by_count_what_fetch_then_build_would_have_sent(planned, 
     """What the host sends a row, forward by forward, it decides before any
     answer is read; it must be what the answers would have told it. From
     the plain generate's ``reveal_step``: a block's forward ``f`` reveals as
-    many positions as are named ``f``, a commit follows every block but the
-    row's last, and only the row's first forward brings tokens (the
-    prompt's tail)."""
+    many positions as are named ``f``, the first forward of every block but
+    the row's first carries the commit of the block before it (so none
+    follows the row's last, and none is a forward of its own), and only the
+    row's first forward brings tokens (the prompt's tail)."""
     got, want, sent, ids, prompts, _ = planned
     p_len, n_new, steps = PLANNED[case]
     assert got[case] == want[case]
@@ -461,15 +535,15 @@ def test_the_host_plans_by_count_what_fetch_then_build_would_have_sent(planned, 
         [-1] * (p_len % 4) + want[case]["reveal_step"][0]
         + want[case]["dropped_reveal_step"][0]
     )
-    expect = []
-    for a in range(0, len(named), 4):
-        block = named[a : a + 4]
-        expect += [(block.count(f), False) for f in range(max(block) + 1)]
-        expect.append((0, True))
-    expect.pop()  # nothing reads the last block's K/V
+    expect = [
+        (block.count(f), f == 0 and b > 0)
+        for b, block in enumerate(_blocks(named))
+        for f in range(max(block) + 1)
+    ]
     mine = [row for step in sent for row in step if row[0] == ids[case]]
-    assert [(n, adv) for _, _, n, adv in mine] == expect
-    assert all(n <= 4 // steps for n, _ in expect)
+    assert [(n, commit) for _, _, n, commit in mine] == expect
+    assert all(0 < n <= 4 // steps for n, _ in expect)
+    assert sum(commit for _, commit in expect) == len(_blocks(named)) - 1
     tail = prompts[case][p_len // 4 * 4 :].tolist()
     assert mine[0][1] == tail + [-1] * (4 - len(tail))
     assert all(t == [-1] * 4 for _, t, _, _ in mine[1:])
@@ -479,9 +553,47 @@ def test_rows_of_every_denoising_step_shared_the_planned_dispatches(planned):
     _, _, sent, ids, _, ledger = planned
     steps_of = {rid: steps for rid, (_, _, steps) in zip(ids, PLANNED)}
     assert any({steps_of[r[0]] for r in step} == {4, 2, 1} for step in sent)
-    # a dispatch with a committing row beside a denoising one
-    assert any({adv for _, _, _, adv in step} == {True, False} for step in sent)
+    # a dispatch with a committing-and-denoising row beside a plain
+    # denoising one: every row of it reveals something
+    assert any({commit for _, _, _, commit in step} == {True, False} for step in sent)
+    assert all(n > 0 for step in sent for _, _, n, _ in step)
     assert ledger["balanced"] and ledger["drained"]
+
+
+@pytest.mark.parametrize("steps", [1, 2, 4])
+def test_a_row_s_pages_hold_the_k_v_of_a_prefill_over_what_it_committed(
+    scfg, params, steps
+):
+    """A prompt of 22 (its tail opens a block midway) and 13 new tokens (the
+    last falls inside a block): nine blocks are denoised, the first eight
+    committed, each in the next block's first forward. What the row's
+    pages hold of those 32 positions when it is answered is what a prefill
+    of the same 32 tokens writes: no commit was lost, none ran over a
+    block that was not whole, none landed a block off."""
+    eng = _engine(scfg, params, max_slots=2, slot_buckets=(2,))
+    pages, release = [], eng._release_row
+
+    def noting(slot, row):
+        pages.extend(row.pages)
+        release(slot, row)
+
+    eng._release_row = noting
+    prompt = _tokens(600 + steps, 22)
+    try:
+        out = eng.submit(prompt[None], 13, denoising_steps=steps, timeout=300)
+        served = np.concatenate([prompt, np.asarray(out["tokens"])[0]])[:32]
+        assert len(pages) == 3 and eng.ledger()["balanced"]
+        got = [_rows_of(pool, pages)[:, :32] for pool in (eng._k, eng._v)]
+    finally:
+        eng.close()
+    cache = sdar_moe.init_paged_cache(scfg, 1, 4, PAGE)
+    table = jnp.asarray([[1, 2, 3]], jnp.int32)
+    _, cache, _ = jax.jit(sdar_moe.paged_prefill_chunk, static_argnames=("cfg",))(
+        params, cache, table, jnp.int32(0), jnp.asarray(served), jnp.int32(0),
+        jnp.int32(32), cfg=scfg,
+    )
+    for mine, clean in zip(got, (cache.k, cache.v)):
+        assert float(np.abs(mine - _rows_of(clean, [1, 2, 3])[:, :32]).max()) <= TOL
 
 
 def test_a_slot_is_refilled_while_its_last_row_s_forward_is_unread(
@@ -540,10 +652,11 @@ class _Poisoned:
 def test_a_failed_block_forward_leaves_a_fresh_block_and_a_balanced_ledger(
     model, cfg, scfg, params, weights, when
 ):
-    """The third block step RUNS (the donated cache and the block beside it
-    are consumed) and then raises at its call, or hands back an answer that
-    raises when it is fetched, a dispatch later; either way a forward is in
-    flight when the failure lands. Every pending fails typed, the engine
+    """The third block step, the second block's first forward with the
+    first block's commit riding in it, RUNS (the donated cache and the
+    block beside it are consumed) and then raises at its call, or hands
+    back an answer that raises when it is fetched, a dispatch later; either
+    way a forward is in flight when the failure lands. Every pending fails typed, the engine
     holds fresh zeroed buffers (the block's two arrays among them), and the
     next request equals the plain generate."""
     eng = _engine(scfg, params, max_slots=1, slot_buckets=(1,))
@@ -556,7 +669,7 @@ def test_a_failed_block_forward_leaves_a_fresh_block_and_a_balanced_ledger(
 
             def run_then_fail(*args):
                 result = fn(*args)
-                calls.append(width)
+                calls.append(bool(np.asarray(args[-1])[0]))  # its commit flag
                 if len(calls) != 3:  # it fails once
                     return result
                 if when == "call":
@@ -567,13 +680,17 @@ def test_a_failed_block_forward_leaves_a_fresh_block_and_a_balanced_ledger(
 
         eng.programs.paged_block_step = failing
         go = _gated(eng)
-        futures = [eng.enqueue(_tokens(400 + i, 9)[None], 8) for i in range(2)]
+        futures = [
+            eng.enqueue(_tokens(400 + i, 9)[None], 8, denoising_steps=2) for i in range(2)
+        ]
         go.set()
         for future in futures:
             with pytest.raises(E.PyGridError, match="engine error"):
                 future.result(timeout=60)
         eng.programs.paged_block_step = builder
-        assert len(calls) == (3 if when == "call" else 4) and not eng._arrivals
+        # three masked positions two a forward, then the fused forward
+        assert calls == [False, False, True, False][: 3 if when == "call" else 4]
+        assert not eng._arrivals
         tokens, masked = eng._last
         assert tokens.shape == masked.shape == (1, 4) and masked.dtype == bool
         for arr in (eng._k, eng._v, eng._pos, tokens, masked):
