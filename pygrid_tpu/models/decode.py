@@ -75,6 +75,7 @@ FAMILIES = {
     "sdar_moe": ("SdarConfig", "pygrid_tpu.models.sdar_moe"),
     "solar_open2": ("SolarConfig", "pygrid_tpu.models.solar_open2"),
     "brumby": ("BrumbyConfig", "pygrid_tpu.models.brumby"),
+    "lfm2_moe": ("Lfm2Config", "pygrid_tpu.models.lfm2_moe"),
 }
 
 
@@ -125,9 +126,11 @@ def from_bundle(spec: dict) -> tuple[Any, Any]:
 # is the transformer's; :mod:`pygrid_tpu.models.jamba` the state-space
 # hybrid's, :mod:`pygrid_tpu.models.sdar_moe` the block-diffusion
 # decoder's, :mod:`pygrid_tpu.models.solar_open2` the delta-rule
-# hybrid's with a chip's share of its experts and
+# hybrid's with a chip's share of its experts,
 # :mod:`pygrid_tpu.models.brumby` the power-retention decoder's, whose
-# cache is state alone. A family with experts answers, after its cache,
+# cache is state alone, and :mod:`pygrid_tpu.models.lfm2_moe` the
+# short-convolution hybrid's with a dense layer ahead of its expert
+# layers. A family with experts answers, after its cache,
 # what its forward counted of them (one number, or three); a prefill whose
 # recurrence runs in chunks may answer a pair: the chunks that held the
 # prompt's own positions and the chunks it ran.

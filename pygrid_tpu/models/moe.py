@@ -17,10 +17,13 @@ dense compute-every-expert reference bit-for-bit up to float
 reassociation — that is what the tests pin.
 
 The second half of the module is the SERVED expert layer
-(:func:`routed_experts`): a top-k softmax router without drops or
-capacity and a grouped gated-SiLU FFN that reads each touched expert's
-weights once — what :mod:`pygrid_tpu.models.sdar_moe` and
-:mod:`pygrid_tpu.models.solar_open2` run in every layer of every forward.
+(:func:`routed_experts`): a top-k router without drops or capacity
+(softmax over the experts, or a sigmoid an expert with a selection bias:
+:func:`route_topk`) and a grouped gated-SiLU FFN that reads each touched
+expert's weights once — what :mod:`pygrid_tpu.models.sdar_moe` and
+:mod:`pygrid_tpu.models.solar_open2` run in every layer of every forward
+and :mod:`pygrid_tpu.models.lfm2_moe` in every layer behind its dense
+one.
 A chip may HOLD a share of a layer's experts (``held``): the router still
 scores all of them, the held ones compute their part of the result, and
 what the absent ones would add is left out (no exchange, nothing standing
@@ -189,19 +192,43 @@ def _vmem_limit(w_gate: jax.Array, w_down: jax.Array, tile: int) -> int:
     return max(VMEM_LIMIT, need)
 
 
-def route_topk(x: jax.Array, w_router: jax.Array, k: int):
-    """``softmax(x W_r)`` in float32 at full precision, the ``k`` largest
-    and their probabilities renormalised to sum to one: (``idx`` [T, k]
-    int32, ``p`` [T, k] float32). The product is 2048 x 128 a token:
-    float32 costs nothing and keeps the choice the reference's but at
-    true ties."""
+#: under the sum a sigmoid router's chosen scores are normalised by (the
+#: LFM2-MoE family's published code)
+SIGMOID_TOPK_EPS = 1e-6
+
+
+def route_topk(
+    x: jax.Array,
+    w_router: jax.Array,
+    k: int,
+    sigmoid: bool = False,
+    bias: jax.Array | None = None,
+):
+    """The router's scores over all its experts in float32 at full
+    precision, the ``k`` chosen and their weights: (``idx`` [T, k] int32,
+    ``p`` [T, k] float32). The product is 2048 x 128 a token: float32
+    costs nothing and keeps the choice the reference's but at true ties.
+
+    By default the scores are ``softmax(x W_r)``, the ``k`` largest are
+    chosen and their probabilities renormalised to sum to one. ``sigmoid``
+    scores each expert alone, ``sigmoid(x W_r)``; the chosen are then the
+    ``k`` largest of ``score + bias`` (``bias`` [E]: a per-expert
+    selection bias that balances the load and weighs nothing) and their
+    weights the scores WITHOUT the bias over ``(their sum +
+    SIGMOID_TOPK_EPS)``."""
     with jax.named_scope("moe.route"):
         logits = jnp.dot(
             x.astype(jnp.float32), w_router.astype(jnp.float32),
             precision=lax.Precision.HIGHEST,
         )
-        probs, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-        return idx.astype(jnp.int32), probs / probs.sum(-1, keepdims=True)
+        if not sigmoid:
+            probs, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+            return idx.astype(jnp.int32), probs / probs.sum(-1, keepdims=True)
+        scores = jax.nn.sigmoid(logits)
+        biased = scores if bias is None else scores + bias.astype(jnp.float32)
+        _, idx = lax.top_k(biased, k)
+        p = jnp.take_along_axis(scores, idx, axis=-1)
+        return idx.astype(jnp.int32), p / (p.sum(-1, keepdims=True) + SIGMOID_TOPK_EPS)
 
 
 def grouped_layout(expert_ids: jax.Array, n_experts: int, tile: int):
@@ -408,11 +435,15 @@ def routed_experts(
     interpret: bool = False,
     held: tuple[int, int] | None = None,
     live: jax.Array | None = None,
+    sigmoid: bool = False,
+    bias: jax.Array | None = None,
 ):
     """The served expert layer over ``x`` [T, d] (float32, normed):
     ``sum_{e in top-k} p_e · W_down,e (silu(W_gate,e x) * W_up,e x)`` with
-    ``p`` the router's softmax renormalised over the ``k`` chosen; no
-    drops, no capacity.
+    ``p`` the router's weights of the ``k`` chosen (:func:`route_topk`:
+    a softmax renormalised over them, or with ``sigmoid`` each expert's
+    own score, chosen with ``bias`` and weighed without it); no drops, no
+    capacity.
 
     ``held = (first, count)`` says which of the router's experts the
     weights given are: ``w_gate[i]`` is expert ``first + i``. The router
@@ -431,7 +462,7 @@ def routed_experts(
     experts; the number of assignments that fell on a held expert,
     int32: all ``T·k`` of them where every expert is here)."""
     n_experts = w_gate.shape[0]
-    idx, p = route_topk(x, w_router, k)
+    idx, p = route_topk(x, w_router, k, sigmoid, bias)
     with jax.named_scope("moe.experts"):
         ids = idx.reshape(-1)
         if held is not None:

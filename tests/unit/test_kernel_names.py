@@ -436,3 +436,69 @@ def test_longreason_step_holds_eight_kernels_and_no_second_state(
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= layers * slots * 8 * 129 * 8320 * 4
     assert memory.temp_size_in_bytes < 200e6
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_agent_programs_hold_their_kernels_and_no_copy_of_the_pool(
+    program, one_chip, no_compile_cache, monkeypatch
+):
+    """The short-convolution family's programs at the published widths (32
+    query heads on 8 K/V heads of 64, 64 experts of 2048 x 1536, a dense
+    MLP of 11,776, bfloat16), three layers of the cell's nine (the dense
+    conv layer, an attention layer, a conv layer; to keep the compile
+    short): ``grouped_expert_ffn`` in the two expert layers and not in the
+    dense one, one flash forward a prompt under the name it trains under,
+    no ``[heads, P, P]`` scores, and the pool, whose heads of 64 lie side
+    by side on 512 lanes, written and gathered where it lies: with the
+    heads on an axis of their own XLA copied all of it before every write
+    and gather."""
+    import re
+
+    from pygrid_tpu.models import lfm2_moe
+    from pygrid_tpu.serving.programs import ProgramSet
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = lfm2_moe.Lfm2Config(
+        vocab=65536, d_model=2048, n_heads=32, n_kv_heads=8, n_layers=3,
+        attn_layers=0b010, n_dense=1, d_ff=11776, n_experts=64, top_k=4,
+        d_expert=1536, d_conv=3, max_len=4608,
+    )
+    assert lfm2_moe.flash_eligible(cfg)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda p: arg(p.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: lfm2_moe.init(jax.random.PRNGKey(0), cfg)),
+    )
+    P, slots, blocks, pages = 2048, 64, 513, 72
+    pool = arg((1, blocks, 64, 512), jnp.bfloat16)
+    cache = (
+        pool, pool, arg((slots,), jnp.int32),
+        arg((2, 2, slots, 2048), jnp.bfloat16),
+    )
+    programs = ProgramSet(cfg, cache_dtype=jnp.bfloat16)
+    fn = programs.paged_decode(slots) if program == "step" else programs.paged_prefill(P)
+    while not hasattr(fn, "lower"):  # the profiler's wrapper
+        fn = fn.__wrapped__
+    last, table = arg((slots,), jnp.int32), arg((slots, pages), jnp.int32)
+    if program == "step":
+        rest = (arg((slots,), jnp.float32), arg((slots, 2), jnp.uint32))
+    else:
+        rest = (
+            arg((), jnp.int32), arg((P,), jnp.int32), arg((), jnp.int32),
+            arg((), jnp.int32), arg((), jnp.float32), arg((2,), jnp.uint32),
+        )
+    compiled = fn.lower(params, *cache, last, table, *rest).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    named = lambda name: sum(name in c for c in calls)  # noqa: E731
+    assert named("grouped_expert_ffn") == 2
+    assert named("flash_fwd") == (program == "prefill")
+    assert len(calls) == 2 + (program == "prefill")
+    assert not re.search(r"= bf16\[1,513,64,512\][^ ]* copy\(", text)
+    assert not re.search(rf"f32\[32,{P},{P}\]|f32\[8,4,{P},{P}\]", text)
+    # the taps are one tensor, updated where it lies
+    assert "bf16[2,2,64,2048]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024**3

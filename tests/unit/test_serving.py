@@ -615,7 +615,8 @@ def _family_attributes_used(path: Path) -> set[str]:
 
 
 @pytest.mark.parametrize(
-    "family", ["decode", "jamba", "sdar_moe", "solar_open2", "brumby"]
+    "family",
+    ["decode", "jamba", "sdar_moe", "solar_open2", "brumby", "lfm2_moe"],
 )
 def test_a_family_module_is_these_ten_names_and_nothing_else(family):
     """What a further family has to write, pinned: the module exposes the
