@@ -157,9 +157,11 @@ def kv_heads(cfg: Lfm2Config) -> int:
 
 
 def kv_kernel(cache_k: jax.Array, max_pages: int) -> bool:
-    """Decode attention gathers: the Pallas kernel wants as many cache
-    heads as query heads and heads of whole 128-lane rows."""
-    return False
+    """Whether decode attention over this pool reads live pages in place:
+    the wide kernel's rule (a TPU, a pool it tiles)."""
+    from pygrid_tpu.serving import paged_attention
+
+    return paged_attention.eligible_wide(cache_k, max_pages)
 
 
 def state_bytes_per_slot(cfg: Lfm2Config, dtype: Any) -> int:
@@ -400,6 +402,26 @@ def _attn_seq(x, lp, c, cfg, kv_dtype, flash: bool | None = None):
     return _mm(a, c(lp["wo"])), k, v
 
 
+def _widen(q, G):
+    """``q`` [w, H, dh] with each query head laid on its K/V head's ``dh``
+    lanes of a ``G * dh`` row, zeros on the others: [w, H, G * dh]."""
+    w, H, dh = q.shape
+    own = jnp.eye(G, dtype=q.dtype)  # [G, G]: a head's own lanes
+    return jnp.einsum(
+        "wgrd,gh->wgrhd", q.reshape(w, G, H // G, dh), own
+    ).reshape(w, H, G * dh)
+
+
+def _narrow(a_wide, G):
+    """A head's own ``dh`` lanes out of each ``G * dh`` row of ``a_wide``
+    [w, H, G * dh]: [w, H * dh]."""
+    w, H, C = a_wide.shape
+    own = jnp.eye(G, dtype=a_wide.dtype)
+    return jnp.einsum(
+        "wgrhd,gh->wgrd", a_wide.reshape(w, G, H // G, G, C // G), own
+    ).reshape(w, H * C // G)
+
+
 def _attend_wide(q, k_rows, v_rows, mask, G):
     """Decode attention over gathered rows that hold a position's ``G``
     K/V heads side by side on the lanes. ``q`` [w, H, dh] float32;
@@ -414,22 +436,17 @@ def _attend_wide(q, k_rows, v_rows, mask, G):
     splitting the gathered ``[w, rows, G * dh]`` into heads of 64 was a
     copy of every row a layer, a sixth of the device's time at the
     published widths (PERF.md §6, PR 47)."""
-    w, H, dh = q.shape
-    R = H // G
-    own = jnp.eye(G, dtype=q.dtype)  # [G, G]: a head's own lanes
-    q_wide = jnp.einsum(
-        "wgrd,gh->wgrhd", q.reshape(w, G, R, dh), own
-    ).reshape(w, H, G * dh)
+    dh = q.shape[-1]
     s = jnp.einsum(
-        "whc,wlc->whl", q_wide.astype(k_rows.dtype), k_rows,
+        "whc,wlc->whl", _widen(q, G).astype(k_rows.dtype), k_rows,
         preferred_element_type=jnp.float32,
     ) * dh**-0.5
     p = jax.nn.softmax(jnp.where(mask[:, None, :], s, -1e30), axis=-1)
     a_wide = jnp.einsum(
         "whl,wlc->whc", p.astype(v_rows.dtype), v_rows,
         preferred_element_type=jnp.float32,
-    ).reshape(w, G, R, G, dh)
-    return jnp.einsum("wgrhd,gh->wgrd", a_wide, own).reshape(w, H * dh)
+    )
+    return _narrow(a_wide, G)
 
 
 def _conv_gates(x, lp, c, taps_dtype):
@@ -565,9 +582,15 @@ def paged_decode_step(
 ) -> tuple[jax.Array, ConvCache, jax.Array]:
     """One decode step for the first ``w`` slots: each attention layer
     appends a K/V row through the slot's table and attends over the
-    gathered table; each conv layer convolves the slot's taps with the
+    slot's rows; each conv layer convolves the slot's taps with the
     step's input and shifts them by one. Logits [w, vocab] float32, the
     cache, and the forward's three counts.
+
+    Attention takes one of two paths, by one rule read when the program
+    is traced (:func:`kv_kernel`): on a TPU, over a pool it tiles, a
+    Pallas kernel reads each slot's live pages in place, several pages a
+    matmul; everywhere else the slot's whole table is gathered and
+    masked. The same mathematics either way (:func:`_attend_wide`).
 
     ``active`` ([w] bool) freezes rows as in the transformer step: a
     frozen row's K/V write goes to trash block 0 and its ``pos`` stays.
@@ -587,7 +610,17 @@ def paged_decode_step(
     off = t % block
     if active is not None:
         blk = jnp.where(active, blk, 0)
-    mask = jnp.arange(rows)[None, :] <= t[:, None]  # [w, rows]
+    use_kernel = kv_kernel(cache.k, max_pages)
+    if use_kernel:
+        from pygrid_tpu.serving import paged_attention
+
+        #: rows the kernel attends over: ``l <= t``. A free slot inside
+        #: the width (zeroed table row: block 0 is never a live row's
+        #: page) has a stale, growing ``pos``; it reads one page of
+        #: trash, not the table
+        lengths = jnp.where(tw[:, 0] != 0, jnp.minimum(t + 1, rows), 1)
+    else:
+        mask = jnp.arange(rows)[None, :] <= t[:, None]  # [w, rows]
     h = c(params["embed"][token]).astype(jnp.float32)
 
     new_k, new_v, conv = cache.k, cache.v, cache.conv
@@ -600,11 +633,24 @@ def paged_decode_step(
             with jax.named_scope("kv_write"):
                 new_k = new_k.at[ai, blk, off].set(k.reshape(w, -1))
                 new_v = new_v.at[ai, blk, off].set(v.reshape(w, -1))
-            with jax.named_scope("attn_gather"):
-                k_rows = _pages(new_k, ai, tw).reshape(w, rows, G * dh)
-                v_rows = _pages(new_v, ai, tw).reshape(w, rows, G * dh)
-            with jax.named_scope("paged_attention"):
-                a = _attend_wide(q, k_rows, v_rows, mask, G)
+            if use_kernel:
+                with jax.named_scope("paged_attention"):
+                    # off the TPU only a test that patched ``kv_kernel``
+                    # gets here: the same kernel, interpreted
+                    a = _narrow(
+                        paged_attention.paged_decode_attention_wide(
+                            _widen(q, G), new_k, new_v, jnp.int32(ai), tw,
+                            lengths, scale=dh**-0.5,
+                            interpret=jax.default_backend() != "tpu",
+                        ),
+                        G,
+                    )
+            else:
+                with jax.named_scope("attn_gather"):
+                    k_rows = _pages(new_k, ai, tw).reshape(w, rows, G * dh)
+                    v_rows = _pages(new_v, ai, tw).reshape(w, rows, G * dh)
+                with jax.named_scope("paged_attention"):
+                    a = _attend_wide(q, k_rows, v_rows, mask, G)
             out = _mm(a, c(lp["wo"]))
             ai += 1
         else:

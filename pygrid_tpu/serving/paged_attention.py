@@ -1,5 +1,9 @@
 """Pallas TPU paged decode attention: one query row per slot against the
-slot's LIVE pages, read in place from the block pool.
+slot's LIVE pages, read in place from the block pool. Two kernels: the
+multi-head one below, a page a matmul over a ``[.., H, dh]`` pool, and a
+head-agnostic one over a WIDE pool (a position's K/V heads side by side
+on the lanes), several pages a DMA wave and a matmul
+(:func:`paged_decode_attention_wide`, at the end of the module).
 
 The XLA path in :func:`pygrid_tpu.models.decode.paged_decode_step`
 slices one layer's whole pool out as the gather's operand, gathers every
@@ -241,4 +245,227 @@ def paged_decode_attention(
         table.astype(jnp.int32),
         lengths.astype(jnp.int32),
         q, k_pool, v_pool,
+    )
+
+
+# ── a wide pool: a position's heads side by side on the lanes ────────────
+
+#: bytes of K (or V) one DMA wave brings in and one matmul takes. A page
+#: alone is too little to hide a DMA's latency behind (a 64 KB page is
+#: 0.08 us of a v5e's HBM bandwidth); half a megabyte is eight such pages
+WAVE_BYTES = 512 * 1024
+
+#: query rows a slot the wide kernel's score scratch is planned for (the
+#: pool does not say how many query heads attend over it)
+WIDE_QUERY_ROWS = 64
+
+
+def wave_pages(block: int, width: int, itemsize: int, max_pages: int) -> int:
+    """Pages a wave: as many as fill :data:`WAVE_BYTES`, fixed from the
+    pool's shape when the program is traced."""
+    return max(1, min(max_pages, WAVE_BYTES // (block * width * itemsize)))
+
+
+def wide_vmem_bytes(
+    block: int, width: int, max_pages: int, itemsize: int
+) -> int:
+    """Scratch the wide kernel allocates: k and v wave double buffers plus
+    the float32 scores of one slot's whole table, :data:`WIDE_QUERY_ROWS`
+    query rows of it."""
+    wave = wave_pages(block, width, itemsize, max_pages)
+    buffers = 4 * wave * block * width * itemsize
+    scores = -(-max_pages // wave) * WIDE_QUERY_ROWS * wave * block * 4
+    return buffers + scores
+
+
+def eligible_wide(cache_k: jax.Array, max_pages: int) -> bool:
+    """True when decode attention over this WIDE pool ``[layers, blocks,
+    block, C]`` takes :func:`paged_decode_attention_wide`: on a TPU, rows
+    of whole 128-lane tiles, a page of whole sublane tiles of the cache
+    dtype (so a page lands in a wave's buffer with no relayout), and the
+    scratch within :data:`VMEM_BUDGET`. Decided from what the program can
+    observe: no switch, no model name."""
+    if jax.default_backend() != "tpu" or cache_k.ndim != 4:
+        return False
+    _, _, block, width = cache_k.shape
+    itemsize = jnp.dtype(cache_k.dtype).itemsize
+    if itemsize not in (2, 4) or width % 128:
+        return False
+    if block % (32 // itemsize):
+        return False
+    return wide_vmem_bytes(block, width, max_pages, itemsize) <= VMEM_BUDGET
+
+
+def _wide_kernel(
+    layer_ref, table_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+    kbuf, vbuf, s_scr, sem, *, scale, block, wave, max_pages,
+):
+    s = pl.program_id(0)
+    layer = layer_ref[0]
+    length = len_ref[s]
+    n_pages = jnp.clip(pl.cdiv(length, block), 1, max_pages)
+    n_waves = pl.cdiv(n_pages, wave)
+    rows = wave * block
+
+    def page_copy(pool, buf, which, wv, j):
+        """Page ``j`` of wave ``wv`` on its way to its place in the
+        wave's buffer."""
+        return pltpu.make_async_copy(
+            pool.at[layer, table_ref[s, wv * wave + j]],
+            buf.at[wv % 2, pl.ds(pl.multiple_of(j * block, block), block)],
+            sem.at[which, wv % 2],
+        )
+
+    def start(pool, buf, which, wv):
+        """All of wave ``wv``'s page DMAs at once. The places of a last
+        wave past the slot's last page are not read but zeroed: their
+        scores are masked, but a weight of ``0`` times whatever a value
+        buffer happened to hold need not be ``0``. A loop over the pages
+        and not ``wave`` unrolled branches: the kernel is lowered anew
+        in every process, whatever the compile cache holds, and unrolled
+        that was half a second a decode program (PERF.md §6, PR 48)."""
+        def page(j, _):
+            live = wv * wave + j < n_pages
+
+            @pl.when(live)
+            def _():
+                page_copy(pool, buf, which, wv, j).start()
+
+            @pl.when(jnp.logical_not(live))
+            def _():
+                at = pl.multiple_of(j * block, block)
+                buf[wv % 2, pl.ds(at, block), :] = jnp.zeros(
+                    (block, buf.shape[-1]), buf.dtype
+                )
+
+        lax.fori_loop(0, wave, page, None)
+
+    def fetch(pool, buf, which, wv):
+        """Wave ``wv`` of the slot as ``[wave * block, C]`` in VMEM, with
+        wave ``wv + 1`` on its way into the other buffer."""
+        @pl.when(wv + 1 < n_waves)
+        def _():
+            start(pool, buf, which, wv + 1)
+
+        def page(j, _):
+            @pl.when(wv * wave + j < n_pages)
+            def _():
+                page_copy(pool, buf, which, wv, j).wait()
+
+        lax.fori_loop(0, wave, page, None)
+        return buf[wv % 2]
+
+    start(k_hbm, kbuf, 0, 0)
+    start(v_hbm, vbuf, 1, 0)
+
+    q = q_ref[0]  # [R, C], in the cache dtype
+    tok = lax.broadcasted_iota(jnp.int32, (q.shape[0], rows), 1)
+
+    def scores(wv, m):
+        sc = lax.dot_general(
+            q, fetch(k_hbm, kbuf, 0, wv), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=_precision(q.dtype),
+        )
+        sc = jnp.where(tok + wv * rows < length, sc * scale, _NEG)
+        s_scr[wv] = sc
+        return jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+
+    m = lax.fori_loop(
+        0, n_waves, scores, jnp.full((q.shape[0], 1), _NEG, jnp.float32)
+    )
+
+    def exps(wv, total):
+        e = jnp.exp(s_scr[wv] - m)
+        s_scr[wv] = e
+        return total + jnp.sum(e, axis=-1, keepdims=True)
+
+    total = lax.fori_loop(
+        0, n_waves, exps, jnp.zeros((q.shape[0], 1), jnp.float32)
+    )
+
+    def values(wv, acc):
+        v2d = fetch(v_hbm, vbuf, 1, wv)
+        prob = (s_scr[wv] / total).astype(v2d.dtype)
+        return acc + jnp.dot(
+            prob, v2d, preferred_element_type=jnp.float32,
+            precision=_precision(v2d.dtype),
+        )
+
+    o_ref[0] = lax.fori_loop(
+        0, n_waves, values, jnp.zeros(o_ref.shape[1:], jnp.float32)
+    )
+
+
+@partial(jax.jit, static_argnames=("scale", "wave", "interpret"))
+def paged_decode_attention_wide(
+    q_wide: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    layer: jax.Array,
+    table: jax.Array,
+    lengths: jax.Array,
+    scale: float,
+    wave: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """``softmax(q_wide . K^T * scale, over rows l < lengths[s]) . V`` for
+    each slot, K and V the slot's cached rows read through ``table`` from
+    wide pools. The kernel knows nothing of heads: the caller lays each
+    query head on its K/V head's lanes of a ``C``-wide row (zeros on the
+    others) and picks a head's own lanes out of the result.
+
+    ``q_wide``: [w, R, C], multiplied in the pools' dtype; ``k_pool``/
+    ``v_pool``: [n_layers, num_blocks, block, C], left where they are;
+    ``layer``: int32 scalar; ``table``: [w, max_pages] int32 block ids;
+    ``lengths``: [w] int32 valid rows per slot, at least 1; ``wave``:
+    pages a wave, :func:`wave_pages` of the shapes unless a test or a
+    measurement gives another. Returns [w, R, C] float32.
+    ``interpret=True`` runs the same kernel on the CPU for tests."""
+    w, q_rows, width = q_wide.shape
+    if q_rows > WIDE_QUERY_ROWS:
+        raise ValueError(
+            f"{q_rows} query rows a slot: the score scratch that "
+            f"eligible_wide plans holds {WIDE_QUERY_ROWS}"
+        )
+    block = k_pool.shape[2]
+    max_pages = table.shape[1]
+    if wave is None:
+        wave = wave_pages(block, width, k_pool.dtype.itemsize, max_pages)
+    row_spec = pl.BlockSpec(
+        (1, q_rows, width), lambda s, *_: (s, 0, 0), memory_space=pltpu.VMEM,
+    )
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    buffers = (2, wave * block, width)
+    return pl.pallas_call(
+        partial(
+            _wide_kernel, scale=scale, block=block, wave=wave,
+            max_pages=max_pages,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(w,),
+            in_specs=[row_spec, pool_spec, pool_spec],
+            out_specs=row_spec,
+            scratch_shapes=[
+                pltpu.VMEM(buffers, k_pool.dtype),
+                pltpu.VMEM(buffers, v_pool.dtype),
+                pltpu.VMEM(
+                    (-(-max_pages // wave), q_rows, wave * block),
+                    jnp.float32,
+                ),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((w, q_rows, width), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="paged_decode_attention_wide",
+    )(
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        table.astype(jnp.int32),
+        lengths.astype(jnp.int32),
+        q_wide.astype(k_pool.dtype), k_pool, v_pool,
     )

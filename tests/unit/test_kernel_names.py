@@ -449,9 +449,9 @@ def test_agent_programs_hold_their_kernels_and_no_copy_of_the_pool(
     short): ``grouped_expert_ffn`` in the two expert layers and not in the
     dense one, one flash forward a prompt under the name it trains under,
     no ``[heads, P, P]`` scores, and the pool, whose heads of 64 lie side
-    by side on 512 lanes, written and gathered where it lies: with the
-    heads on an axis of their own XLA copied all of it before every write
-    and gather."""
+    by side on 512 lanes, written and read where it lies (by the wide
+    decode kernel in the step): with the heads on an axis of their own
+    XLA copied all of it before every write and gather."""
     import re
 
     from pygrid_tpu.models import lfm2_moe
@@ -496,9 +496,53 @@ def test_agent_programs_hold_their_kernels_and_no_copy_of_the_pool(
     named = lambda name: sum(name in c for c in calls)  # noqa: E731
     assert named("grouped_expert_ffn") == 2
     assert named("flash_fwd") == (program == "prefill")
-    assert len(calls) == 2 + (program == "prefill")
+    # the step's attention layer reads its rows' live pages in place:
+    # one kernel, and no gathered table beside the pool
+    assert named("paged_decode_attention_wide") == (program == "step")
+    assert len(calls) == 3
+    assert not re.search(rf"\[({slots * pages}|{slots},{pages}),64,512\]", text)
     assert not re.search(r"= bf16\[1,513,64,512\][^ ]* copy\(", text)
     assert not re.search(rf"f32\[32,{P},{P}\]|f32\[8,4,{P},{P}\]", text)
     # the taps are one tensor, updated where it lies
     assert "bf16[2,2,64,2048]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 1024**3
+
+
+def test_wide_decode_attention_at_the_agent_cells_shape_gathers_no_table(
+    one_chip, no_compile_cache
+):
+    """A step's attention as ``lfm2_moe.paged_decode_step`` runs it in
+    ``agent-saturate`` (64 slots, 32 query heads on 8 K/V heads of 64 laid
+    wide on 512 lanes, tables of 72 pages of 64, the bfloat16 pool of 1 +
+    64 x 72 blocks): one kernel under the name a trace reduction prints,
+    and no ``[4608, 64, 512]`` of gathered pages anywhere."""
+    import re
+
+    from pygrid_tpu.models import lfm2_moe
+    from pygrid_tpu.serving import paged_attention
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    w, H, G, dh, block, pages = 64, 32, 8, 64, 64, 72
+    pool = arg((2, 1 + w * pages, block, G * dh), jnp.bfloat16)
+    assert paged_attention.wave_pages(block, G * dh, 2, pages) == 8
+
+    def attend(q, k_pool, v_pool, layer, table, lengths):
+        return lfm2_moe._narrow(
+            paged_attention.paged_decode_attention_wide(
+                lfm2_moe._widen(q, G), k_pool, v_pool, layer, table, lengths,
+                scale=dh**-0.5,
+            ),
+            G,
+        )
+
+    compiled = jax.jit(attend).lower(
+        arg((w, H, dh), jnp.float32), pool, pool, arg((), jnp.int32),
+        arg((w, pages), jnp.int32), arg((w,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    assert len(calls) == 1 and "paged_decode_attention_wide" in calls[0]
+    assert not re.search(r"\[(4608|64,72),64,512\]", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 * 1024**2

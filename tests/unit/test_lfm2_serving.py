@@ -142,6 +142,20 @@ def _fresh_cache(lcfg, slots=4, poison=False):
     return cache, jnp.asarray(table)
 
 
+def _prefilled(lcfg, params, lengths, seqs):
+    """Slot ``i`` holds the first ``lengths[i]`` tokens of ``seqs[i]``,
+    prefilled over taps that were NaN."""
+    cache, table = _fresh_cache(lcfg, poison=True)
+    for slot, (n, seq) in enumerate(zip(lengths, seqs)):
+        chunk = np.zeros(32, np.int32)
+        chunk[:n] = seq[:n]
+        _, cache, _ = _prefill(
+            params, cache, table, jnp.int32(slot), jnp.asarray(chunk),
+            jnp.int32(n), lcfg,
+        )
+    return cache, table
+
+
 # ── the router: a sigmoid an expert, a bias that chooses and weighs nothing
 
 
@@ -411,13 +425,7 @@ def test_several_slots_of_unequal_lengths_decode_side_by_side(
     lengths = (1, 2, 13, 29)
     seqs = [_tokens(40 + i, n + 6) for i, n in enumerate(lengths)]
     wants = [_ref_logits(model, weights, cfg, s) for s in seqs]
-    cache, table = _fresh_cache(lcfg, poison=True)
-    for slot, (n, seq) in enumerate(zip(lengths, seqs)):
-        chunk = np.zeros(32, np.int32)
-        chunk[:n] = seq[:n]
-        _, cache, _ = _prefill(
-            params, cache, table, jnp.int32(slot), jnp.asarray(chunk), jnp.int32(n), lcfg
-        )
+    cache, table = _prefilled(lcfg, params, lengths, seqs)
     for step in range(6):
         tok = jnp.asarray([s[n + step] for n, s in zip(lengths, seqs)], jnp.int32)
         logits, cache, _ = _step(params, cache, table, tok, lcfg)
@@ -565,6 +573,108 @@ def test_engine_serves_the_references_tokens_staggered_and_reused(
         assert eng.ledger()["balanced"] and eng.ledger()["drained"]
     finally:
         eng.close()
+
+
+# ── decode attention by the wide kernel (the rule patched true) ─────────
+
+
+def _step_by(monkeypatch, kernel, lcfg):
+    """The decode step traced anew with the family's rule answering
+    ``kernel``: off the TPU the kernel path interprets the same kernel."""
+    monkeypatch.setattr(lfm2_moe, "kv_kernel", lambda *a: kernel)
+    return jax.jit(
+        lambda params, cache, table, token, active=None:
+        lfm2_moe.paged_decode_step(
+            params, cache, table, token, lcfg, active=active
+        )
+    )
+
+
+def test_the_kernel_path_decodes_the_gather_path_s_logits(
+    model, cfg, lcfg, params, weights, monkeypatch
+):
+    """Three prompts of unequal lengths prefilled through the cache, a
+    free slot (zeroed table row, a stale position) beside them, six steps
+    on either path: the same logits to float32 rounding, the reference's
+    to ``TOL``, and the same cache."""
+    lengths = (1, 13, 29)
+    seqs = [_tokens(60 + i, n + 6) for i, n in enumerate(lengths)]
+    wants = [_ref_logits(model, weights, cfg, s) for s in seqs]
+    start, table = _prefilled(lcfg, params, lengths, seqs)
+    table = table.at[3].set(0)
+    start = start._replace(pos=start.pos.at[3].set(41))
+    caches = {}
+    for kernel in (True, False):
+        step, cache, rows = _step_by(monkeypatch, kernel, lcfg), start, []
+        for t in range(6):
+            tok = [s[n + t] for n, s in zip(lengths, seqs)] + [0]
+            logits, cache, _ = step(params, cache, table, jnp.asarray(tok, jnp.int32))
+            rows.append(np.asarray(logits[:3]))
+        caches[kernel] = (np.stack(rows), cache)
+    got, want = caches[True][0], caches[False][0]
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    for slot, n in enumerate(lengths):
+        np.testing.assert_allclose(
+            got[:, slot], wants[slot][n : n + 6], atol=TOL, rtol=0
+        )
+    ours, theirs = caches[True][1], caches[False][1]
+    np.testing.assert_array_equal(ours.pos, theirs.pos)
+    live = np.asarray(table[:3]).ravel()
+    np.testing.assert_allclose(ours.k[:, live], theirs.k[:, live], rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(ours.v[:, live], theirs.v[:, live], rtol=2e-5, atol=2e-6)
+
+
+def test_a_frozen_row_on_the_kernel_path_keeps_its_pages_and_position(
+    lcfg, params, monkeypatch
+):
+    lengths = (5, 17, 30, 2)
+    seqs = [_tokens(70 + i, n + 1) for i, n in enumerate(lengths)]
+    cache, table = _prefilled(lcfg, params, lengths, seqs)
+    active = jnp.asarray([True, False, True, True])
+    tok = jnp.asarray([s[n] for n, s in zip(lengths, seqs)], jnp.int32)
+    logits, new, _ = _step_by(monkeypatch, True, lcfg)(params, cache, table, tok, active)
+    want, ref, _ = _step_by(monkeypatch, False, lcfg)(params, cache, table, tok, active)
+    live = np.array([0, 2, 3])
+    np.testing.assert_allclose(logits[live], want[live], rtol=2e-5, atol=2e-6)
+    np.testing.assert_array_equal(new.pos, [6, 17, 31, 3])
+    np.testing.assert_array_equal(new.pos, ref.pos)
+    frozen = np.asarray(table[1])
+    np.testing.assert_array_equal(new.k[:, frozen], cache.k[:, frozen])
+    np.testing.assert_array_equal(new.v[:, frozen], cache.v[:, frozen])
+    # an active row did append: its page changed at its old position
+    own = int(table[0, 0])
+    assert not np.array_equal(new.k[:, own, 5], cache.k[:, own, 5])
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["per-step", "fused"])
+def test_an_engine_on_the_kernel_path_counts_live_pages(
+    model, cfg, lcfg, params, weights, monkeypatch, fused
+):
+    """The engine reads the family's rule when it is built: the gauge's
+    row says 1, a dispatch counts the pages its rows' lengths span (fewer
+    than their tables hold), and the served tokens are the reference's."""
+    monkeypatch.setattr(lfm2_moe, "kv_kernel", lambda *a: True)
+    before = {k: _count("serving_kv_pages_total", kind=k) for k in ("read", "table")}
+    eng = _engine(lcfg, params, model_id="lfm2-wide", max_slots=2,
+                  slot_buckets=(1, 2), fused=fused)
+    shapes = [(3, 7), (40, 9), (13, 6)]
+    try:
+        prompts = [_tokens(80 + i, p) for i, (p, _) in enumerate(shapes)]
+        futures = [eng.enqueue(p[None], n) for p, (_, n) in zip(prompts, shapes)]
+        served = [f.result(300)[0] for f in futures]
+        stats = eng.stats()
+        assert eng.ledger()["balanced"] and eng.ledger()["drained"]
+    finally:
+        eng.close()
+    for prompt, toks, (_, n) in zip(prompts, served, shapes):
+        assert toks.shape == (n,)
+        assert _gaps(model, weights, cfg, prompt, toks).max() <= TOL
+    assert stats["kv_kernel"] == 1
+    read, held = (
+        _count("serving_kv_pages_total", kind=k) - before[k]
+        for k in ("read", "table")
+    )
+    assert 0 < read < held
 
 
 def test_the_counters_and_the_telemetry_row(lcfg, params):
